@@ -16,8 +16,9 @@ Public API (replaces the reference's stub pyigloo, pyigloo/src/lib.rs):
 """
 import jax
 
-# The engine's device lanes are int64/float64 (SQL semantics, TPC-H decimals); this
-# TPU target supports both (f64 via correct emulation — verified by probe).
+# The engine's device lanes are int64/float64 (SQL semantics, TPC-H decimals). The
+# TPU emulates both: TPC-H sums agree with a float64 oracle to ~1e-14 on the v5e,
+# but its f64 divide is not bit-exact (exec/codec.py's canary decides per backend).
 jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: join-heavy TPC-H stages cost minutes of

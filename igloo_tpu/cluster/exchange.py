@@ -156,6 +156,8 @@ def _partition_arrays(table: pa.Table, key_indices: list[int],
             try:
                 return dispatch.exchange_scatter(plan, lanes)
             except Exception:
+                if dispatch.compile_failure_raises():
+                    raise
                 # compile-failure rung: ban this shape class and take the
                 # numpy path (mirrors the executor's per-kernel rung)
                 _SCATTER_BANS.add((plan[1], plan[2]))

@@ -1,14 +1,16 @@
 """Persistent XLA compile cache: policy, telemetry, and cluster transfer.
 
-Join-heavy TPC-H stages cost 12-31 s of cold XLA compile per query against
-0.08-1.2 s warm (BENCH_r05) — for ad-hoc traffic, compilation IS the
-latency. This module owns the three pieces that turn JAX's persistent
-compilation cache into a *cluster-wide* one (docs/compile_cache.md):
+A cold TPC-H query spends far longer in the XLA compiler than on the
+device (PERF.md has the chip's numbers) — for ad-hoc traffic, compilation
+IS the latency. This module owns the three pieces that turn JAX's
+persistent compilation cache into a *cluster-wide* one
+(docs/compile_cache.md):
 
-- **policy** (`configure`): resolve the IGLOO_TPU_COMPILE_CACHE setting into
-  a cache directory and install it into jax.config. Imported-time entry
-  point for `igloo_tpu/__init__.py`; also applied by workers when the
-  coordinator propagates its setting at registration.
+- **policy** (`configure`): resolve JAX_COMPILATION_CACHE_DIR and the
+  IGLOO_TPU_COMPILE_CACHE setting into a cache directory and install it
+  into jax.config. Imported-time entry point for `igloo_tpu/__init__.py`;
+  also applied by workers when the coordinator propagates its setting at
+  registration.
 - **telemetry** (`install_metrics`): hook jax.monitoring's
   `/jax/compilation_cache/*` events into the MetricsRegistry as
   `compile_cache.hit` / `compile_cache.miss` counters and a
@@ -22,9 +24,16 @@ compilation cache into a *cluster-wide* one (docs/compile_cache.md):
   a query shape compiles once per *cluster*, ever.
 
 Env knobs:
-    IGLOO_TPU_COMPILE_CACHE      0/false/off disables; 1/true/on (or unset)
-                                 uses the default directory; anything else
-                                 is the directory to use.
+    JAX_COMPILATION_CACHE_DIR    JAX's own variable. Where it is set, that
+                                 directory IS the cache and this module
+                                 installs no other — whoever launches the
+                                 process places the cache.
+    IGLOO_TPU_COMPILE_CACHE      0/false/off disables (whatever the JAX
+                                 variable says); 1/true/on (or unset) uses
+                                 `<checkout>/.xla_cache`; anything else is
+                                 the directory to use. A directory here is
+                                 honoured only when the JAX variable is
+                                 unset.
     IGLOO_TPU_COMPILE_CACHE_MIN_SECS
                                  persist threshold override (default 1.0 —
                                  sub-second programs are cheaper to
@@ -76,21 +85,24 @@ _disabled_reason: Optional[str] = None
 
 
 def default_dir() -> str:
-    """Alongside the package tree when writable (repo checkouts), else the
-    user cache dir (pip installs into read-only site-packages)."""
+    """`.xla_cache` at the root of the checkout (git-ignored): a fixed path,
+    because the path is part of what keys a cache entry."""
     parent = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if os.access(parent, os.W_OK):
-        return os.path.join(parent, ".xla_cache")
-    return os.path.join(os.path.expanduser("~"), ".cache", "igloo_tpu_xla")
+    return os.path.join(parent, ".xla_cache")
 
 
 def resolve_setting(raw: Optional[str] = None) -> Optional[str]:
-    """IGLOO_TPU_COMPILE_CACHE value -> cache directory (None = disabled)."""
+    """IGLOO_TPU_COMPILE_CACHE value (+ JAX_COMPILATION_CACHE_DIR) -> cache
+    directory (None = disabled). Off wins; then the JAX variable; then the
+    igloo setting's own directory; then `default_dir()`."""
     if raw is None:
         raw = os.environ.get("IGLOO_TPU_COMPILE_CACHE", "1")
     flag = raw.strip().lower()
     if flag in ("0", "false", "off", "no", ""):
         return None
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
     if flag in ("1", "true", "on", "yes"):
         return default_dir()
     return raw
@@ -98,11 +110,11 @@ def resolve_setting(raw: Optional[str] = None) -> Optional[str]:
 
 def configure(raw: Optional[str] = None) -> Optional[str]:
     """Install the persistent-cache setting into jax.config. Returns the
-    active directory (None when disabled). A failure (ancient jax without
-    the knobs, unwritable config) downgrades to cold compiles only — but
+    active directory (None when disabled). A malformed
+    IGLOO_TPU_COMPILE_CACHE_MIN_SECS downgrades to cold compiles only — but
     LOUDLY: one warning plus a `compile_cache.disabled` counter, so a
     silently-dead cache shows up in system.metrics instead of as a
-    mysterious 30 s per query."""
+    mysterious minute per query."""
     global _disabled_reason
     cache_dir = resolve_setting(raw)
     import jax
@@ -110,25 +122,15 @@ def configure(raw: Optional[str] = None) -> Optional[str]:
         # an explicit "off" must also UNDO a previously-installed directory:
         # workers adopting the coordinator's disabled setting at registration
         # would otherwise keep persisting to their import-time default
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass  # ancient jax without the knob was never persisting anyway
+        jax.config.update("jax_compilation_cache_dir", None)
         return None
     try:
         # parse BEFORE touching jax.config so a failure can't leave the
         # cache half-enabled (dir installed, thresholds defaulted)
         min_secs = float(os.environ.get(
             "IGLOO_TPU_COMPILE_CACHE_MIN_SECS", "1.0"))
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as ex:
-        try:  # roll back a partially-installed dir: disabled means DISABLED
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
+    except ValueError as ex:
+        jax.config.update("jax_compilation_cache_dir", None)
         if _disabled_reason is None:
             _disabled_reason = f"{type(ex).__name__}: {ex}"
             import warnings
@@ -140,6 +142,9 @@ def configure(raw: Optional[str] = None) -> Optional[str]:
             from igloo_tpu.utils import tracing
             tracing.counter("compile_cache.disabled")
         return None
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return cache_dir
 
 
@@ -150,11 +155,7 @@ def disabled_reason() -> Optional[str]:
 def active_dir() -> Optional[str]:
     """The directory jax is currently configured to persist into."""
     import jax
-    try:
-        d = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return None
-    return d or None
+    return jax.config.jax_compilation_cache_dir or None
 
 
 # --- telemetry ---------------------------------------------------------------
@@ -184,14 +185,9 @@ def install_metrics() -> None:
             # exceeds the compile it replaced); record what was measured
             tracing.histogram("compile_cache.saved_s", duration)
 
-    try:
-        from jax import monitoring
-        monitoring.register_event_listener(on_event)
-        monitoring.register_event_duration_secs_listener(on_duration)
-    except Exception:
-        # jax without the monitoring API: the cache still works, only the
-        # hit/miss telemetry is absent — never fail `import igloo_tpu` on it
-        pass
+    from jax import monitoring
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
 
 
 # --- filename-keyed entry transfer ------------------------------------------

@@ -11,13 +11,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Optional
 
-try:
-    import tomllib  # Python >= 3.11
-except ImportError:  # pragma: no cover - depends on interpreter version
-    try:
-        import tomli as tomllib  # the 3.10 backport, same API
-    except ImportError:
-        tomllib = None  # Config.load reports it; everything else still works
+import tomllib
 
 from igloo_tpu.errors import IglooError
 
@@ -135,11 +129,6 @@ class Config:
 
     @staticmethod
     def load(path: str) -> "Config":
-        if tomllib is None:
-            raise IglooError(
-                "TOML config unavailable: this Python has neither tomllib "
-                "(3.11+) nor the tomli backport; install tomli or pass "
-                "settings programmatically")
         if not os.path.exists(path):
             raise IglooError(f"config file not found: {path}")
         with open(path, "rb") as fh:

@@ -10,20 +10,28 @@ callers of ``pallas_kernels`` (igloo-lint ``pallas-dispatch`` rule: the
 flag and the fallback ladder must not be bypassable).
 
 Knob: ``IGLOO_TPU_PALLAS``
-  - ``auto`` (default)  kernels on TPU backends only, compiled;
+  - ``auto`` (default)  on TPU backends only, and there only the kernels
+                        the chip's compiler accepts
+                        (``TPU_COMPILED_KERNELS`` — empty today, so ``auto``
+                        on a TPU plans exactly what ``0`` plans);
   - ``0``               kernels off everywhere — reproduces the sort-path
                         plans and results bit-identically;
-  - ``1``               kernels on; on non-TPU backends this implies the
-                        Pallas interpreter (a compiled Pallas call needs
-                        Mosaic/TPU);
+  - ``1``               every kernel on; on non-TPU backends this implies
+                        the Pallas interpreter (a compiled Pallas call needs
+                        Mosaic/TPU). On a TPU the kernels are compiled and a
+                        compile failure RAISES — forcing them is how a
+                        kernel outside the table is worked on;
   - ``interpret``       kernels on through the Pallas interpreter on any
                         backend — the CPU equivalence mode tier-1 uses.
 
 Fallback ladder (each rung attributable): mode off / non-TPU auto -> sort
-path silently; eligibility miss or an earlier failure's negative cache ->
+path silently; ``auto`` on a TPU and the kernel is not in
+``TPU_COMPILED_KERNELS`` -> sort path + ``pallas.fallback.not_compiled``;
+eligibility miss or an earlier failure's negative cache ->
 sort path + ``pallas.fallback.<reason>``; COMPILE failure (a program the
 backend cannot lower) -> caught at the executor's call sites, negative
-cache + sort-path re-run (``pallas.compile_fallback``); runtime overflow
+cache + sort-path re-run (``pallas.compile_fallback``; never under
+``IGLOO_TPU_PALLAS=1`` on a TPU, see ``compile_failure_raises``); runtime overflow
 (probe window / agg table) -> deferred flag -> sort-path re-run +
 negative cache (``pallas.probe_overflow`` / ``pallas.agg_overflow``).
 
@@ -118,6 +126,18 @@ SCATTER_MAX_ROWS_COMPILED = 1 << 20
 SCATTER_MAX_BUCKETS = 1 << 16
 SCATTER_MAX_COLS = 8
 
+#: Kernels the TPU's compiler accepts at the shapes planned here — the ONLY
+#: kernels ``auto`` plans on a TPU backend. tests/test_tpu_compile.py holds
+#: the table to the compiler both ways (a member must compile to a
+#: ``tpu_custom_call``, a non-member must still be refused), so a PR that
+#: repairs a kernel has to move it in here. Empty: with jax 0.9.0 every one
+#: of probe / segagg / gather / scatter / topk is refused while lowering
+#: (`_check_block_mappings`: "integer modulo by zero" — the tiling of a 1-D
+#: block is 128 * (32 // bitwidth) and the lanes are 64-bit), and match
+#: (int32 lanes) dies in a RecursionError lowering its in-kernel int32
+#: scatter under x64 (docs/kernels.md "Compiled mode on the chip").
+TPU_COMPILED_KERNELS: frozenset = frozenset()
+
 
 def mode() -> str:
     """Normalized ``IGLOO_TPU_PALLAS``: auto | 0 | 1 | interpret."""
@@ -149,6 +169,24 @@ def kernel_state() -> tuple:
 
 def enabled() -> bool:
     return kernel_state()[0]
+
+
+def _compiles(kernel: str, interp: bool) -> bool:
+    """False (and ``pallas.fallback.not_compiled``) when `kernel` would be
+    planned COMPILED under ``auto`` but the chip's compiler refuses it. The
+    interpreter and a forced ``IGLOO_TPU_PALLAS=1`` are not held to the
+    table."""
+    if interp or mode() != "auto" or kernel in TPU_COMPILED_KERNELS:
+        return True
+    _fallback(kernel, "not_compiled")
+    return False
+
+
+def compile_failure_raises() -> bool:
+    """True under ``IGLOO_TPU_PALLAS=1`` on a TPU: the user forced compiled
+    kernels, so the executor's compile-failure rungs re-raise instead of
+    quietly re-running the sort path."""
+    return mode() == "1" and _backend() == "tpu"
 
 
 def cache_token() -> tuple:
@@ -187,6 +225,8 @@ def plan_probe(build_cap: int, probe_cap: int,
         return _fallback("probe", "banned")
     if build_cap > (PROBE_MAX_BUILD if interp else PROBE_MAX_BUILD_COMPILED):
         return _fallback("probe", "too_big")
+    if not _compiles("probe", interp):
+        return None
     tuned = _tuned("probe", canonical_capacity(build_cap))
     shift = int(tuned.get("bucket_shift", PROBE_BUCKET_SHIFT))
     nbuckets = min(max(canonical_capacity(build_cap) >> shift, 8),
@@ -210,6 +250,8 @@ def plan_segagg(pack_spec, n_keys: int, input_cap: int,
         return _fallback("segagg", "banned")
     if pack_spec is None or len(pack_spec[1]) != n_keys:
         return _fallback("segagg", "unpackable")
+    if not _compiles("segagg", interp):
+        return None
     # 8x headroom over the input capacity keeps the per-bucket occupancy
     # low enough that `ways` slots rarely exhaust (overflow falls back)
     tuned = _tuned("segagg", canonical_capacity(input_cap))
@@ -246,6 +288,8 @@ def _plan_gather(arrays: list, idx) -> Optional[tuple]:
         return None
     budget = GATHER_MAX_BYTES if interp else GATHER_MAX_BYTES_COMPILED
     if sum(a.size * a.dtype.itemsize for a in arrays) > budget:
+        return None
+    if not _compiles("gather", interp):
         return None
     tracing.counter("pallas.gather")
     return ("gather", block, interp)
@@ -293,14 +337,15 @@ def plan_match(probe_cap: int, match_cap: int,
     the kernel route to "search", never all the way to the scan."""
     on, interp = kernel_state()
     if on and not banned:
-        if match_cap <= (MATCH_MAX_CAP if interp else MATCH_MAX_CAP_COMPILED):
+        if match_cap > (MATCH_MAX_CAP if interp else MATCH_MAX_CAP_COMPILED):
+            _fallback("match", "too_big")
+        elif _compiles("match", interp):
             tuned = _tuned("match", canonical_capacity(match_cap))
             block = pow2_block(probe_cap,
                                int(tuned.get("block", MATCH_BLOCK)))
             tracing.counter("pallas.match")
             return ("match", "kernel",
                     int(tuned.get("window", MATCH_WINDOW)), block, interp)
-        _fallback("match", "too_big")
     elif on and banned:
         _fallback("match", "banned")
     if _backend() == "tpu" and not interp:
@@ -330,7 +375,8 @@ def plan_topk(cap: int, k: int, full_pack: bool,
         return _fallback("topk", "large_limit")
     on, interp = kernel_state()
     if on and not banned and k <= TOPK_MAX_K and \
-            cap <= (TOPK_MAX_ROWS if interp else TOPK_MAX_ROWS_COMPILED):
+            cap <= (TOPK_MAX_ROWS if interp else TOPK_MAX_ROWS_COMPILED) \
+            and _compiles("topk", interp):
         tuned = _tuned("topk", canonical_capacity(cap))
         block = pow2_block(cap, int(tuned.get("block", TOPK_BLOCK)))
         if k <= block:
@@ -355,6 +401,8 @@ def plan_scatter(nrows: int, ncols: int, nbuckets: int,
     if ncols > SCATTER_MAX_COLS or nbuckets > SCATTER_MAX_BUCKETS or \
             npad > (SCATTER_MAX_ROWS if interp else SCATTER_MAX_ROWS_COMPILED):
         return _fallback("scatter", "too_big")
+    if not _compiles("scatter", interp):
+        return None
     tuned = _tuned("scatter", npad)
     block = pow2_block(npad, int(tuned.get("block", SCATTER_BLOCK)))
     tracing.counter("pallas.scatter")

@@ -923,9 +923,8 @@ class ShardedExecutor(Executor):
     def _jitted_shard_map(self, kind: str, fingerprint, local_fn,
                           out_specs, n_batch_args: int = 1):
         def build():
-            from igloo_tpu.parallel.mesh import shard_map
             in_specs = tuple([P(ROWS)] * n_batch_args + [P()])
-            return shard_map(local_fn, mesh=self.mesh,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
+            return jax.shard_map(local_fn, mesh=self.mesh,
+                                 in_specs=in_specs, out_specs=out_specs,
+                                 check_vma=False)
         return self._jitted(kind, fingerprint, build)

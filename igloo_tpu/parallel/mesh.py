@@ -26,20 +26,6 @@ from igloo_tpu.utils import tracing
 ROWS = "rows"  # the one mesh axis: row-partitioned data parallelism
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant `shard_map`: `jax.shard_map` where it exists (JAX >=
-    0.6), else `jax.experimental.shard_map.shard_map` — whose equivalent of
-    `check_vma` is spelled `check_rep`. Every mesh program in parallel/ goes
-    through this one call site, so a JAX upgrade (either direction) cannot
-    reintroduce the AttributeError class of breakage."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def resolve_mesh(setting) -> Optional[Mesh]:
     """Shared mesh-resolution rule (QueryEngine, worker daemon): None =
     single-device; "auto" = row-shard across all local devices when more than

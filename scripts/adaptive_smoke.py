@@ -26,6 +26,10 @@ ship more bytes than the exchange) while per-bucket build work stays
 negligible — the timed A/B isolates exactly the skew the salt fixes.
 
 ~2 min on the virtual CPU mesh (worker subprocesses jit-compile cold).
+
+This is a CPU drive: the parent (which runs a coordinator engine) and both
+worker subprocesses run with JAX_PLATFORMS=cpu — on a host with a chip they
+would otherwise fight over it (a chip belongs to one process).
 """
 import os
 import subprocess
@@ -40,9 +44,7 @@ os.environ["IGLOO_TPU_COMPILE_CACHE"] = "0"
 # repeated identical SQL must EXECUTE (this smoke asserts what execution
 # did), not serve from the front-door result cache (docs/serving.md)
 os.environ["IGLOO_SERVING_RESULT_CACHE"] = "0"
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # before jax starts; children get it too
 import numpy as np  # noqa: E402
 import pyarrow as pa  # noqa: E402
 import pyarrow.parquet as pq  # noqa: E402
@@ -129,8 +131,8 @@ def main() -> int:
                               use_jit=False)
     caddr = f"127.0.0.1:{coord.port}"
     # single-device workers: the cross-worker parallelism under test is the
-    # two PROCESSES (and the env's jax lacks shard_map — the known mesh gap)
-    wenv = dict(os.environ,
+    # two PROCESSES
+    wenv = dict(os.environ, JAX_PLATFORMS="cpu",
                 XLA_FLAGS="--xla_force_host_platform_device_count=1")
     procs = [subprocess.Popen(
         [sys.executable, "-m", "igloo_tpu.cluster.worker", caddr],

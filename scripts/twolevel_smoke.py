@@ -14,9 +14,9 @@ distributed join and asserts via `last_metrics` that BOTH levels engaged:
   local 2-device mesh), with rows identical to single-device execution.
 
 `--scaling` measures the same join at 1x1 / 1x2 / 2x1 / 2x2
-(workers x per-worker devices) and emits one JSON line (consumed by bench.py
-into BENCH_DETAIL.json's `twolevel_scaling` block; without `--json` it also
-merges the block into BENCH_DETAIL.json directly). Wall times on virtual CPU
+(workers x per-worker devices) and emits one JSON line (without `--json` it
+also merges the block into BENCH_DETAIL.json's `twolevel_scaling`). Wall
+times on virtual CPU
 devices measure PLUMBING (dispatch, exchange, H2D resharding), not compute
 scaling — the block's value is the per-topology `mesh_devices`/fragment
 attribution that proves W x D composition, plus a trend line for regressions.
@@ -24,6 +24,11 @@ attribution that proves W x D composition, plus a trend line for regressions.
 `--worker` is the subprocess entry: it must set the device count BEFORE jax
 initializes, which is why workers cannot be in-process threads here (one
 process = one backend = one device count).
+
+This is a CPU drive: the parent (which runs a coordinator engine) and every
+worker it starts run with JAX_PLATFORMS=cpu. On a host with a chip, a parent
+and children that all reached for it would fight over it — a chip belongs
+to one process.
 """
 from __future__ import annotations
 
@@ -39,6 +44,7 @@ sys.path.insert(0, REPO)
 
 
 def _force_cpu(devices: int) -> None:
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before jax starts; children inherit
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
     os.environ["IGLOO_TPU_COMPILE_CACHE"] = "0"
@@ -47,8 +53,6 @@ def _force_cpu(devices: int) -> None:
     # the warm plan flips to a broadcast join (the cold run's observed build
     # bytes say so) and the shuffle/scaling assertions would race that flip
     os.environ["IGLOO_ADAPTIVE"] = "0"
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 
 def worker_main(coordinator: str, devices: int) -> int:
@@ -112,7 +116,7 @@ class Cluster:
         self.coord = CoordinatorServer("grpc+tcp://127.0.0.1:0",
                                        worker_timeout_s=60.0, use_jit=False)
         self.addr = f"127.0.0.1:{self.coord.port}"
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices}")
         env["IGLOO_TPU_COMPILE_CACHE"] = "0"

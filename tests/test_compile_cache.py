@@ -173,15 +173,22 @@ eng.execute("SELECT g, SUM(a) AS s FROM t WHERE a >= 3 GROUP BY g ORDER BY g")
 from igloo_tpu.utils import tracing
 c = tracing.counters()
 print(json.dumps({"hit": c.get("compile_cache.hit", 0),
-                  "miss": c.get("compile_cache.miss", 0)}))
+                  "miss": c.get("compile_cache.miss", 0),
+                  "dir": jax.config.jax_compilation_cache_dir}))
 """
 
 
-def _run_cache_subprocess(cache_dir: str) -> dict:
+def _run_cache_subprocess(cache_dir: str, placed_by: str = "igloo") -> dict:
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(JAX_PLATFORMS="cpu",
                IGLOO_TPU_COMPILE_CACHE=cache_dir,
                IGLOO_TPU_COMPILE_CACHE_MIN_SECS="0")
+    if placed_by == "jax":
+        # whoever launches the process places the cache: JAX's own variable
+        # wins over a directory in the igloo setting
+        env.update(JAX_COMPILATION_CACHE_DIR=cache_dir,
+                   IGLOO_TPU_COMPILE_CACHE=cache_dir + "-not-this-one")
     out = subprocess.run([sys.executable, "-c", _SUBPROC_SCRIPT], env=env,
                          cwd=REPO, capture_output=True, text=True,
                          timeout=240)
@@ -189,14 +196,40 @@ def _run_cache_subprocess(cache_dir: str) -> dict:
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_second_process_hits_persistent_cache(tmp_path):
+@pytest.mark.parametrize("placed_by", ["igloo", "jax"])
+def test_second_process_hits_persistent_cache(tmp_path, placed_by):
     from igloo_tpu import compile_cache
     d = str(tmp_path / "xla")
-    cold = _run_cache_subprocess(d)
+    cold = _run_cache_subprocess(d, placed_by)
+    assert cold["dir"] == d
     assert cold["miss"] > 0
     assert compile_cache.entry_names(d), "no persistent entries written"
-    warm = _run_cache_subprocess(d)
+    assert not os.path.exists(d + "-not-this-one")
+    warm = _run_cache_subprocess(d, placed_by)
     assert warm["hit"] > 0, warm
+
+
+@pytest.mark.parametrize("jax_dir,setting,want", [
+    (None, None, "default"),        # nothing set: <checkout>/.xla_cache
+    (None, "1", "default"),
+    (None, "/some/dir", "/some/dir"),
+    ("/placed", None, "/placed"),   # JAX's variable IS the cache
+    ("/placed", "/some/dir", "/placed"),
+    ("/placed", "0", None),         # off still wins
+    (None, "off", None),
+])
+def test_cache_directory_resolution(monkeypatch, jax_dir, setting, want):
+    from igloo_tpu import compile_cache as cc
+    for name, val in (("JAX_COMPILATION_CACHE_DIR", jax_dir),
+                      ("IGLOO_TPU_COMPILE_CACHE", setting)):
+        if val is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, val)
+    if want == "default":
+        want = os.path.join(REPO, ".xla_cache")
+    assert cc.resolve_setting() == want
+    assert cc.default_dir() == os.path.join(REPO, ".xla_cache")
 
 
 def test_entry_helpers_sanitize_and_round_trip(tmp_path):
