@@ -133,20 +133,26 @@ class DistributedClient:
         fatal errors (the query itself failed) surface immediately.
         Retrying from scratch is safe: results materialize via read_all(),
         so no partial batches were consumed."""
-        # the registry coerces HERE, so a mistyped field fails client-side
-        # with a ProtocolError naming it instead of round-tripping to an
-        # opaque server error; unset fields are omitted and a bare ticket
-        # collapses to the SQL itself (stock-client wire compatibility)
-        body = protocol.QUERY_TICKET.build(sql=sql, deadline_s=deadline_s,
-                                           qid=qid, priority=priority,
-                                           session=session,
-                                           trace_id=trace_id)
-        ticket = protocol.encode_query_ticket(body, sql)
-        timeout = self._policy.stream_timeout_s if deadline_s is None \
-            else deadline_s + min(5.0, self._policy.connect_timeout_s)
-        if busy_wait_s is None:
-            busy_wait_s = deadline_s if deadline_s is not None else 60.0
-        busy_deadline = time.time() + busy_wait_s
+        with tracing.span("client.execute"):
+            # the registry coerces HERE, so a mistyped field fails
+            # client-side with a ProtocolError naming it instead of
+            # round-tripping to an opaque server error; unset fields are
+            # omitted and a bare ticket collapses to the SQL itself
+            # (stock-client wire compatibility)
+            body = protocol.QUERY_TICKET.build(
+                sql=sql, deadline_s=deadline_s, qid=qid, priority=priority,
+                session=session, trace_id=trace_id)
+            ticket = protocol.encode_query_ticket(body, sql)
+            timeout = self._policy.stream_timeout_s if deadline_s is None \
+                else deadline_s + min(5.0, self._policy.connect_timeout_s)
+            if busy_wait_s is None:
+                busy_wait_s = deadline_s if deadline_s is not None else 60.0
+            return self._read_all(ticket, timeout,
+                                  time.time() + busy_wait_s)
+
+    def _read_all(self, ticket: str, timeout: float,
+                  busy_deadline: float) -> pa.Table:
+        """`execute`'s do_get under its retry model."""
         # SEPARATE budgets: sheds are bounded by busy_deadline only and must
         # not consume the transport retry budget — a client shed twice under
         # load still deserves its full policy budget for an unrelated
@@ -155,10 +161,11 @@ class DistributedClient:
         attempt = 0
         while True:
             try:
-                reader = self._client.do_get(
-                    flight.Ticket(ticket.encode()),
-                    _call_options(timeout_s=timeout))
-                return reader.read_all()
+                with tracing.span("client.wait"):
+                    reader = self._client.do_get(
+                        flight.Ticket(ticket.encode()),
+                        _call_options(timeout_s=timeout))
+                    return reader.read_all()
             except flight.FlightError as ex:
                 msg = str(ex)
                 if serving.BUSY_MARKER in msg:

@@ -399,21 +399,24 @@ class DistributedExecutor:
                     dead: set[str] = set()
                     lost_deps: set[str] = set()
                     busy: list = []
-                    for fut in cf.as_completed(futs):
-                        f = futs[fut]
-                        try:
-                            fut.result()
-                        except _WorkerBusy as ex:
-                            busy.append((f.id, ex.addr))
-                            continue
-                        except _WorkerDied as ex:
-                            dead.add(ex.addr)
-                            continue
-                        except _DepLost as ex:
-                            lost_deps.add(ex.frag_id)
-                            continue
-                        completed[f.id] = f.worker
-                        pending.discard(f.id)
+                    # this thread blocked on the dispatch pool: the
+                    # fragments' own time is the workers' spans
+                    with tracing.span("coordinator.await_fragments"):
+                        for fut in cf.as_completed(futs):
+                            f = futs[fut]
+                            try:
+                                fut.result()
+                            except _WorkerBusy as ex:
+                                busy.append((f.id, ex.addr))
+                                continue
+                            except _WorkerDied as ex:
+                                dead.add(ex.addr)
+                                continue
+                            except _DepLost as ex:
+                                lost_deps.add(ex.frag_id)
+                                continue
+                            completed[f.id] = f.worker
+                            pending.discard(f.id)
                     if busy:
                         # saturated-but-ALIVE workers (WORKER_BUSY, all
                         # execution slots occupied): requeue elsewhere
@@ -1089,37 +1092,11 @@ class CoordinatorServer(flight.FlightServerBase):
             if out is not None:
                 return out
             return self._run_demoted(sql, stream, deadline, t_start, permit)
-        live = self.membership.live()
-        if not live:
-            # a coordinator with no workers is still a working single-node
-            # engine (the reference coordinator main is exactly that)
+        with tracing.span("coordinator.plan"):
+            planned = self._plan_fragments(plan)
+        if planned is None:
             return self._run_local(sql, stream, deadline, t_start, permit)
-        synced = []
-        for w in live:
-            try:
-                self._sync_worker_tables(w)
-                synced.append(w)
-            except Exception:
-                # unreachable mid-sweep: evict now instead of failing every
-                # query until the sweeper notices
-                self.membership.evict(w.worker_id)
-        live = synced
-        if not live or not self._distributable(plan):
-            # only distribute plans whose base tables every worker resolves
-            return self._run_local(sql, stream, deadline, t_start, permit)
-        # per-worker device counts ride into planning: bucket counts scale
-        # with hosts, per-worker shard counts with chips, and heterogeneous
-        # clusters get device-weighted bucket placement (two-level
-        # parallelism, docs/distributed.md)
-        topo = {w.addr: w.devices for w in live}
-        planner = DistributedPlanner([w.addr for w in live], topology=topo)
-        # watchtower baseline key, captured BEFORE fragmenting: the planner
-        # rewrites the tree in place (partial-agg Union merge has no stable
-        # key), and the baseline must describe the user's logical plan — the
-        # same key the local tier would observe under
-        from igloo_tpu.exec import hints
-        plan_key = hints.plan_fp(plan)
-        frags = planner.plan(plan)
+        topo, planner, plan_key, frags = planned
         tracing.counter("coordinator.distributed_queries")
         # reorder decisions from engine.plan's optimize() above ride beside
         # the fragment-tier broadcast/salt records (docs/adaptive.md)
@@ -1132,7 +1109,7 @@ class CoordinatorServer(flight.FlightServerBase):
                  "_plan_fp": plan_key,
                  # the topology this query was planned against, published in
                  # last_metrics beside the per-fragment mesh_devices reports
-                 "topology": {"workers": len(live),
+                 "topology": {"workers": len(topo),
                               "devices": topo,
                               "total_shards": sum(topo.values())}}
         try:
@@ -1154,6 +1131,42 @@ class CoordinatorServer(flight.FlightServerBase):
             return self._run_demoted(sql, stream, deadline, t_start, permit)
         self._result_cache_put(rkey, table)
         return table
+
+    def _plan_fragments(self, plan):
+        """From the optimized plan to the fragment DAG ready to dispatch:
+        -> (topology, planner, watchtower plan key, fragments), or None
+        where the query has to run locally."""
+        live = self.membership.live()
+        if not live:
+            # a coordinator with no workers is still a working single-node
+            # engine (the reference coordinator main is exactly that)
+            return None
+        synced = []
+        for w in live:
+            try:
+                self._sync_worker_tables(w)
+                synced.append(w)
+            except Exception:
+                # unreachable mid-sweep: evict now instead of failing every
+                # query until the sweeper notices
+                self.membership.evict(w.worker_id)
+        live = synced
+        if not live or not self._distributable(plan):
+            # only distribute plans whose base tables every worker resolves
+            return None
+        # per-worker device counts ride into planning: bucket counts scale
+        # with hosts, per-worker shard counts with chips, and heterogeneous
+        # clusters get device-weighted bucket placement (two-level
+        # parallelism, docs/distributed.md)
+        topo = {w.addr: w.devices for w in live}
+        planner = DistributedPlanner([w.addr for w in live], topology=topo)
+        # watchtower baseline key, captured BEFORE fragmenting: the planner
+        # rewrites the tree in place (partial-agg Union merge has no stable
+        # key), and the baseline must describe the user's logical plan — the
+        # same key the local tier would observe under
+        from igloo_tpu.exec import hints
+        plan_key = hints.plan_fp(plan)
+        return topo, planner, plan_key, planner.plan(plan)
 
     # --- serving helpers (docs/serving.md) ---
 

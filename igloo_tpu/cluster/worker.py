@@ -262,20 +262,21 @@ class WorkerServer(flight.FlightServerBase):
         # per-fragment counter delta: thread-isolated, so concurrent
         # fragments on this worker report only their own transfers/compiles
         with tracing.counter_delta() as delta:
-            t_dep0 = time.perf_counter()
-            for ref in _frag_refs(plan_json):
-                dep_id = ref["table"][len(FRAG_PREFIX):]
-                name = ref["table"].lower()
-                if name in overlay:
-                    continue
-                t = self._fetch_dep(dep_id, addr_of.get(dep_id, ""),
-                                    ref.get("bucket"), ref.get("buckets"),
-                                    deadline=deadline)
-                input_rows += t.num_rows
-                overlay[name] = MemTable(t)
-            dep_s = time.perf_counter() - t_dep0
-            catalog = _OverlayCatalog(self._catalog, overlay)
-            plan = serde.plan_from_json(plan_json, catalog)
+            with tracing.span("fragment.plan"):
+                t_dep0 = time.perf_counter()
+                for ref in _frag_refs(plan_json):
+                    dep_id = ref["table"][len(FRAG_PREFIX):]
+                    name = ref["table"].lower()
+                    if name in overlay:
+                        continue
+                    t = self._fetch_dep(dep_id, addr_of.get(dep_id, ""),
+                                        ref.get("bucket"), ref.get("buckets"),
+                                        deadline=deadline)
+                    input_rows += t.num_rows
+                    overlay[name] = MemTable(t)
+                dep_s = time.perf_counter() - t_dep0
+                catalog = _OverlayCatalog(self._catalog, overlay)
+                plan = serde.plan_from_json(plan_json, catalog)
             partition = salt = None
             if isinstance(plan, L.Exchange):
                 # fragment-root exchange: execute the input, hash-partition

@@ -153,11 +153,13 @@ class QueryEngine:
     # --- execution ---
 
     def plan(self, sql: str) -> L.LogicalPlan:
-        stmt = parse_sql(sql)
+        with span("parse"):
+            stmt = parse_sql(sql)
         if not isinstance(stmt, A.SelectStmt):
             raise PlanError("plan() requires a SELECT statement")
-        bound = Binder(self.catalog, udfs=self.udfs).bind(stmt)
-        return optimize(bound)
+        with span("bind+optimize"):
+            bound = Binder(self.catalog, udfs=self.udfs).bind(stmt)
+            return optimize(bound)
 
     def execute(self, sql: str) -> pa.Table:
         return self.query(sql).table
@@ -168,7 +170,10 @@ class QueryEngine:
 
     def query(self, sql: str) -> QueryResult:
         t0 = time.perf_counter()
-        stmt = parse_sql(sql)
+        # before the statement's kind is known, so ahead of the `query` root
+        # that only a SELECT opens (stats.collect)
+        with span("parse"):
+            stmt = parse_sql(sql)
         if isinstance(stmt, A.ShowTablesStmt):
             return QueryResult(pa.table({"table_name": self.catalog.names()}),
                                elapsed_s=time.perf_counter() - t0)

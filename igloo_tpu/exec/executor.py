@@ -17,7 +17,6 @@ reuse executables across QueryEngine.execute calls.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Optional
 
 import jax
@@ -165,41 +164,32 @@ def _note_carrier_ratio(provider, batch: DeviceBatch) -> None:
 record_fetch = stats.record_fetch
 
 
-class _CompileTimed:
-    """One-shot wrapper returned by `_jitted` on a cache miss when a query is
-    being collected: times the FIRST call (where jax traces, lowers and
-    compiles synchronously before dispatch) and attributes it to the current
-    operator as compile time. Never cached — later calls get the raw fn.
-    Under IGLOO_TRACE_DEVICE=1 the first call is bracketed in a named
-    TraceAnnotation so the compile lands attributably in the jax profiler's
-    Perfetto timeline."""
-    __slots__ = ("fn", "kind")
+class _Program:
+    """What `_jitted` hands out: the program, each call inside a span.
+    After a miss of the in-memory jit cache the first call is
+    `program.first_call` — where jax traces, lowers and loads from the
+    persistent cache or compiles, synchronously, before it dispatches —
+    and is booked as the current operator's compile cost (EXPLAIN ANALYZE);
+    every other call is `program.dispatch`, the asynchronous dispatch of a
+    program jax already holds. Never cached: the cache keeps the raw fn."""
+    __slots__ = ("fn", "kind", "first")
 
-    def __init__(self, fn, kind: str = ""):
+    def __init__(self, fn, kind: str, first: bool):
         self.fn = fn
         self.kind = kind
+        self.first = first
 
     def __call__(self, *args, **kw):
-        t0 = time.perf_counter()
+        if not self.first:
+            with tracing.span("program.dispatch", kind=self.kind):
+                return self.fn(*args, **kw)
+        self.first = False
+        cm = tracing.span("program.first_call", kind=self.kind)
         try:
-            with tracing.device_annotation(f"igloo:compile:{self.kind}"):
+            with cm:
                 return self.fn(*args, **kw)
         finally:
-            dt = time.perf_counter() - t0
-            stats.record_compile(dt)
-            tracing.histogram("compile.first_call_s", dt)
-
-
-def _device_annotated(fn, kind: str):
-    """Execute-side half of the IGLOO_TRACE_DEVICE bridge: every dispatch of
-    this program runs inside a named TraceAnnotation. Only built when the
-    bridge is on — the off path returns the raw fn untouched."""
-    name = f"igloo:execute:{kind}"
-
-    def run(*args, **kw):
-        with tracing.device_annotation(name):
-            return fn(*args, **kw)
-    return run
+            stats.record_compile(cm.span.elapsed_s)
 
 
 class Executor:
@@ -257,19 +247,10 @@ class Executor:
             if self._use_jit:
                 fn = jax.jit(fn, static_argnums=static_argnums)
             self._cache[key] = fn
-            if stats.current() is not None:
-                # the raw fn is what got cached; the wrapper lives for this
-                # one first call and books it as the node's compile cost
-                fn = _CompileTimed(fn, kind)
-                if tracing.device_trace_enabled():
-                    fn = _device_annotated(fn, kind)
-                return fn
-        else:
-            tracing.counter("jit.hit")
-            stats.bump_attr("jit_hit")
-        if tracing.device_trace_enabled():
-            return _device_annotated(fn, kind)
-        return fn
+            return _Program(fn, kind, first=True)
+        tracing.counter("jit.hit")
+        stats.bump_attr("jit_hit")
+        return _Program(fn, kind, first=False)
 
     # --- entry ---
 
@@ -390,7 +371,10 @@ class Executor:
             except FusionUnsupported as e:
                 tracing.counter("fused.unsupported")
                 tracing.counter(f"fused.unsupported.{e.args[0] if e.args else ''}")
-        return self._staged_to_arrow(plan)
+        # ONE span for the whole staged fallback (its programs' spans nest
+        # inside): a query that leaves the fused path is still attributed
+        with tracing.span("staged.execute"):
+            return self._staged_to_arrow(plan)
 
     def _fused_to_arrow(self, plan: L.LogicalPlan, _retry: bool = True) -> pa.Table:
         """Execute via the fused whole-plan program: one dispatch, one fetch
@@ -405,7 +389,8 @@ class Executor:
     def _fused_run(self, plan: L.LogicalPlan, _retry: bool) -> pa.Table:
         from igloo_tpu.exec.batch import arrow_from_host
         comp = FusedCompiler(self)
-        run, key, meta = comp.compile(plan)
+        with tracing.span("fused.plan"):
+            run, key, meta = comp.compile(plan)
         stats.annotate(nodes=len(comp.fps), leaves=len(comp.leaves))
         # `nofuse` sentinel: armed in the persistent store before a
         # first-in-process fused compile, cleared on success. A process killed
@@ -453,33 +438,37 @@ class Executor:
         if first and self._hints is not None:
             self._hints.remove(sentinel)
             self._hints.flush()
-        flags_h, stats_h, n, host_live, host_vals, host_nulls, host_cargs = \
-            jax.device_get(
-                (flags, stats_dev, n_dev, spec.live,
-                 [c.values for c in spec.columns],
-                 [c.nulls for c in spec.columns],
-                 [c.carrier_arg for c in spec.columns]))
-        record_fetch((host_live, host_vals, host_nulls))
-        stats.set_rows(int(n))
-        for sid, v in stats_h.items():
-            self._cache[("nhint", comp.stat_keys[sid])] = int(v)
+        with tracing.span("fused.fetch"):
+            # blocks until the device is done, then copies D2H
+            flags_h, stats_h, n, host_live, host_vals, host_nulls, \
+                host_cargs = jax.device_get(
+                    (flags, stats_dev, n_dev, spec.live,
+                     [c.values for c in spec.columns],
+                     [c.nulls for c in spec.columns],
+                     [c.carrier_arg for c in spec.columns]))
+        with tracing.span("fused.result"):
+            record_fetch((host_live, host_vals, host_nulls))
+            stats.set_rows(int(n))
+            for sid, v in stats_h.items():
+                self._cache[("nhint", comp.stat_keys[sid])] = int(v)
+                if self._hints is not None:
+                    self._hints.put(comp.stat_keys[sid], int(v))
             if self._hints is not None:
-                self._hints.put(comp.stat_keys[sid], int(v))
-        if self._hints is not None:
-            self._hints.flush()
-        fired = [comp.flag_tags[fid] for fid, v in flags_h.items() if bool(v)]
-        if fired:
+                self._hints.flush()
+            fired = [comp.flag_tags[fid] for fid, v in flags_h.items()
+                     if bool(v)]
             for tag in fired:
                 self._record_fired_tag(tag)
+            if not fired and int(n) <= spec.capacity:
+                return arrow_from_host(
+                    attach_dicts(spec, meta.dicts, meta.bounds), host_live,
+                    host_vals, host_nulls, host_cargs)
+        if fired:
             if _retry and all(t[0] == "compact" for t in fired):
                 # stale cardinality hints only: repair with the fresh ones
                 tracing.counter("fused.compact_repair")
                 return self._fused_to_arrow(plan, _retry=False)
             return self._retry_copy(fired).execute_to_arrow(plan)
-        spec = attach_dicts(spec, meta.dicts, meta.bounds)
-        if int(n) <= spec.capacity:
-            return arrow_from_host(spec, host_live, host_vals, host_nulls,
-                                   host_cargs)
         # result larger than the fetch window: exact compact + full fetch.
         # Clamp to the batch's own capacity (already a family member): the
         # live count can sit in the hysteresis band just under it, and an

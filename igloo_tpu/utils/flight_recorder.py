@@ -22,11 +22,12 @@ Consumption paths (docs/observability.md#distributed-tracing):
 
 Knobs: ``IGLOO_TRACE=0`` kills the recorder (spans still exist thread-local,
 nothing is stitched or retained); ``IGLOO_TRACE_RING`` sizes the ring
-(default 32 traces); ``IGLOO_TRACE_DEVICE=1`` turns on the jax.profiler
-bridge (tracing.device_annotation). Overhead with the recorder ON is a few
-tens of microseconds per query (id generation + one flatten + a ring
-append) — under the same <1%-of-a-5ms-query budget the stats layer holds;
-scripts/trace_smoke.py measures it.
+(default 32 traces). Neither touches the profiler bridge or the
+``span_us.*`` self-time counters: every span made here leaves through
+``tracing.close_span`` like a thread-local one. Overhead with the recorder
+ON is a few tens of microseconds per query (id generation + one flatten + a
+ring append) plus ~5 us per span — scripts/trace_smoke.py holds the whole to
+<2% of a 5 ms query on the CPU; PERF.md §6 has what it costs on the chip.
 
 Cross-host caveat: spans are anchored to each process's own wall clock, so
 timelines from different HOSTS carry that clock skew (same-host worker
@@ -122,7 +123,9 @@ class Trace:
                  tid: Optional[int] = None, **attrs) -> str:
         """Record one completed span by wall-clock epoch bounds — the hook
         for durations measured outside any thread-local scope (the serving
-        permit's HBM hold, the coordinator's root-result relay)."""
+        permit's HBM hold, the coordinator's root-result relay). No child
+        is tracked for such a span: its self time is its duration."""
+        tracing.close_span(name, None, t1 - t0)
         return self._append(name, t0, t1, tracing.new_span_id(), parent_id,
                             proc, tid, attrs or None)
 
@@ -131,14 +134,18 @@ class Trace:
              proc: Optional[str] = None, **attrs):
         """Explicit cross-thread span: yields its span_id BEFORE the body
         runs so callers can ship it as the parent of remote work (the
-        coordinator's dispatch span does exactly that)."""
+        coordinator's dispatch span does exactly that). It sits on no
+        thread-local stack, so its self time is its duration."""
         sid = tracing.new_span_id()
-        t0 = time.time()
+        annotation = tracing.open_span(name)
+        p0 = time.perf_counter()
         try:
             yield sid
         finally:
-            self._append(name, t0, time.time(), sid, parent_id, proc,
-                         None, attrs or None)
+            p1 = time.perf_counter()
+            tracing.close_span(name, annotation, p1 - p0)
+            self._append(name, tracing.epoch(p0), tracing.epoch(p1), sid,
+                         parent_id, proc, None, attrs or None)
 
     def add_tree(self, span: tracing.Span, parent_id: Optional[str] = None,
                  proc: Optional[str] = None,
@@ -200,6 +207,12 @@ def current() -> Optional[Trace]:
     return getattr(_tls, "trace", None)
 
 
+def in_request_scope() -> bool:
+    """Whether a request scope is open on this thread (it may record into no
+    trace: `IGLOO_TRACE=0`)."""
+    return getattr(_tls, "scopes", 0) > 0
+
+
 def current_root() -> Optional[str]:
     """The active request scope's root span id (allocated up front so
     cross-thread spans can parent under it while the request runs)."""
@@ -213,11 +226,13 @@ class _RequestScope:
     exit the scope's span roots flush into the trace under a root span whose
     id was allocated up front (yielded, and readable via `current_root()`).
     `trace=None` still resets the thread-local state — the hygiene applies
-    whether or not anything is recorded. Class-based: this sits on the
+    whether or not anything is recorded — and the root still has its
+    profiler event and its `span_us.<name>` (self time: the scope's duration
+    minus the span roots opened inside it). Class-based: this sits on the
     per-query hot path."""
 
     __slots__ = ("trace", "name", "proc", "parent_id", "keep_roots",
-                 "attrs", "_tok", "_prev", "_root_id", "_t0")
+                 "attrs", "_tok", "_prev", "_root_id", "_p0", "_annotation")
 
     def __init__(self, trace: Optional[Trace], name: str,
                  proc: Optional[str], parent_id: Optional[str],
@@ -239,16 +254,22 @@ class _RequestScope:
         _tls.trace = self.trace
         _tls.root_id = self._root_id
         _tls.proc = self.proc
-        self._t0 = time.time()
+        _tls.scopes = getattr(_tls, "scopes", 0) + 1
+        self._annotation = tracing.open_span(self.name)
+        self._p0 = time.perf_counter()
         return self._root_id
 
     def __exit__(self, *exc):
+        p1 = time.perf_counter()
         roots = tracing.pop_scope(self._tok, keep_roots=self.keep_roots)
         _tls.trace, _tls.root_id, _tls.proc = self._prev
+        _tls.scopes -= 1
+        tracing.close_span(self.name, self._annotation, p1 - self._p0, roots)
         trace = self.trace
         if trace is not None:
             tid = _tid()
-            trace._append(self.name, self._t0, time.time(), self._root_id,
+            trace._append(self.name, tracing.epoch(self._p0),
+                          tracing.epoch(p1), self._root_id,
                           self.parent_id, self.proc, tid, self.attrs)
             for s in roots:
                 trace.add_tree(s, parent_id=self._root_id, proc=self.proc,

@@ -178,8 +178,11 @@ def collect(sql: str = "", detail: bool = False, log: bool = True):
     # keep_roots so same-thread span consumers (CLI --timing) still work
     trace = flight_recorder.current()
     own_scope = None
-    if trace is None and flight_recorder.enabled():
-        trace = flight_recorder.Trace(qid=qid, sql=sql)
+    if trace is None and not flight_recorder.in_request_scope():
+        # IGLOO_TRACE=0 still gets the root (its profiler event and
+        # self-time counter), with nothing stitched or retained
+        if flight_recorder.enabled():
+            trace = flight_recorder.Trace(qid=qid, sql=sql)
         own_scope = flight_recorder.request_scope(trace, "query",
                                                   keep_roots=True)
         own_scope.__enter__()

@@ -12,7 +12,11 @@ ever installed (SURVEY.md §5.1); here the layer is real and has three parts:
   context manager, so concurrent queries can never pollute each other's
   deltas. `prometheus_text()` renders the registry for the cluster's
   `metrics` Flight action.
-- `profile_trace()` wraps `jax.profiler.trace` for device-level profiles.
+- one clock: every span, however it is made, enters `open_span` and leaves
+  through `close_span` — a `jax.profiler.TraceAnnotation` named
+  `igloo:<name>` for its lifetime (a no-op until a profiler session runs;
+  then the span lands on `/host:CPU`, on the profiler's clock, beside the
+  device's ops) and its SELF time added to the counter `span_us.<name>`.
 
 Every counter/histogram name used in the codebase is cataloged in
 docs/observability.md; igloo-lint's metric-names checker (`python -m
@@ -23,7 +27,6 @@ from __future__ import annotations
 import contextlib
 import itertools
 import logging
-import os
 import re
 import threading
 import time
@@ -31,6 +34,9 @@ import uuid
 from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Optional
+
+# the package's __init__ imports jax before anything of igloo_tpu loads
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 log = logging.getLogger("igloo_tpu")
 
@@ -355,14 +361,6 @@ def adopt_collectors(cols: tuple):
             _remove_by_identity(own, c)
 
 
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """Capture a jax.profiler trace (TensorBoard format) around a block."""
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield
-
-
 @dataclass
 class Span:
     name: str
@@ -443,21 +441,25 @@ def current_span_id() -> Optional[str]:
 class _SpanCtx:
     """Class-based span context (a @contextmanager generator costs ~2x as
     much, and spans sit on per-operator and per-RPC paths)."""
-    __slots__ = ("span",)
+    __slots__ = ("span", "annotation")
 
-    def __init__(self, s: Span):
+    def __init__(self, s: Span, annotation):
         self.span = s
+        self.annotation = annotation
 
     def __enter__(self) -> Span:
         return self.span
 
     def __exit__(self, *exc):
-        self.span.end = time.perf_counter()
+        s = self.span
+        s.end = time.perf_counter()
         _tls.stack.pop()
+        close_span(s.name, self.annotation, s.end - s.start, s.children)
         return False
 
 
 def span(name: str, **attrs) -> _SpanCtx:
+    annotation = open_span(name)
     s = Span(name, time.perf_counter(), span_id=new_span_id(),
              attrs=attrs or None)
     stack = _stack()
@@ -467,41 +469,41 @@ def span(name: str, **attrs) -> _SpanCtx:
     else:
         _tls.roots.append(s)
     stack.append(s)
-    return _SpanCtx(s)
+    return _SpanCtx(s, annotation)
 
 
-# --- device-trace bridge (IGLOO_TRACE_DEVICE=1) ------------------------------
+# --- one clock: the enter/exit every span shares ------------------------------
+#
+# tracing.span, flight_recorder.Trace.span / add_span and
+# flight_recorder.request_scope all open with `open_span` and close with
+# `close_span`, so a span cannot exist without its profiler event and its
+# self-time counter (benchmark/span_layers.json groups the counters by
+# layer; PERF.md §3 names the metric each feeds).
 
 
-_device_trace: Optional[bool] = None
+def open_span(name: str):
+    """Enter the profiler event of a span that starts now; the result goes
+    to `close_span`. A TraceMe costs a few tenths of a microsecond while no
+    profiler session is active; during one (`jax.profiler.start_trace`) the
+    event lands on the opening thread's `/host:CPU` line."""
+    annotation = _TraceAnnotation("igloo:" + name)
+    annotation.__enter__()
+    return annotation
 
 
-def device_trace_enabled() -> bool:
-    """Opt-in jax.profiler bridge: when IGLOO_TRACE_DEVICE=1 the executor
-    brackets compile/execute in named TraceAnnotations so device time lands
-    in the same Perfetto UI as the flight-recorder spans. Read once (the
-    check sits on the jit dispatch path)."""
-    global _device_trace
-    if _device_trace is None:
-        _device_trace = os.environ.get("IGLOO_TRACE_DEVICE", "0") == "1"
-    return _device_trace
-
-
-@contextlib.contextmanager
-def device_annotation(name: str):
-    """A named `jax.profiler.TraceAnnotation` around a block (no-op when the
-    device bridge is off or the profiler is unavailable)."""
-    if not device_trace_enabled():
-        yield
-        return
-    import jax
-    try:
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        yield
-        return
-    with cm:
-        yield
+def close_span(name: str, annotation, duration_s: float,
+               children=()) -> None:
+    """The one exit of every span: leave its profiler event (`None` for a
+    span recorded after the fact by its bounds) and add its SELF time —
+    `duration_s` minus its direct children's durations, i.e. the part of
+    the interval no child span covers — to `span_us.<name>`, in integer
+    microseconds."""
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    for c in children:
+        if c.end:       # a child still open (a span held by a generator)
+            duration_s -= c.end - c.start
+    counter(f"span_us.{name}", max(round(duration_s * 1e6), 0))
 
 
 def last_trace(n: int = 2) -> str:

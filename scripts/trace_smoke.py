@@ -12,8 +12,10 @@ stitched timeline is real:
 - parent/child nesting is monotonic (children inside their parents);
 - the trace covers >= 95% of the query's coordinator-reported wall time;
 - recorder overhead (trace + request scope + a realistic span tree +
-  publish) stays under 1% of a 5 ms warm query (<50 us per query) — the
-  same class of budget the stats layer holds.
+  publish) stays under 2% of a 5 ms warm query (<100 us per query): every
+  span is also an inactive profiler TraceMe and a `span_us.*` counter bump
+  (~3 us a span on top of the recorder's own cost). What it costs against
+  the benchmark's queries is measured on the chip (PERF.md section 6).
 
 ~15 s on the virtual CPU mesh (use_jit=False keeps fragments compile-free).
 """
@@ -176,12 +178,12 @@ def main() -> int:
         assert TRACE_ID in log["trace_id"], \
             "query_log row must carry the trace_id"
 
-        # --- overhead budget: <1% of a 5ms warm query ----------------------
+        # --- overhead budget: <2% of a 5ms warm query ----------------------
         per_query = measure_overhead()
-        budget = 0.005 * 0.01
+        budget = 0.005 * 0.02
         assert per_query < budget, \
             f"recorder overhead {per_query * 1e6:.1f}us/query >= " \
-            f"{budget * 1e6:.0f}us (1% of a 5ms warm query)"
+            f"{budget * 1e6:.0f}us (2% of a 5ms warm query)"
 
         print(f"trace smoke OK: {len(spans)} spans, "
               f"{len(procs)} processes, coverage {cover:.1%}, "

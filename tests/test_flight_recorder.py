@@ -149,19 +149,50 @@ def test_explain_analyze_trace_pointer():
 
 
 def test_device_trace_bridge(monkeypatch):
-    """IGLOO_TRACE_DEVICE=1: Executor._jitted brackets compile/execute in
-    named TraceAnnotations; results are bit-identical to the plain path."""
-    monkeypatch.setattr(tracing, "_device_trace", True)
-    try:
-        e = QueryEngine(use_jit=False)
-        e.register_table("t", pa.table({"a": [3, 1, 2], "v": [1.0, 2.0, 3.0]}))
-        sql = "SELECT a, sum(v) AS s FROM t GROUP BY a ORDER BY a"
-        got = e.execute(sql)
-    finally:
-        monkeypatch.setattr(tracing, "_device_trace", None)
+    """The profiler bridge is always on: every span a query opens — the
+    request-scope root and the programs' first calls and dispatches
+    included — enters one `igloo:<name>` TraceAnnotation and leaves it, in
+    order, whether or not a profiler session is running; results are what
+    they are without it."""
+    log = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    sql = "SELECT a, sum(v) AS s FROM t GROUP BY a ORDER BY a"
     plain = QueryEngine(use_jit=False)
     plain.register_table("t", pa.table({"a": [3, 1, 2], "v": [1.0, 2.0, 3.0]}))
-    assert got.to_pydict() == plain.execute(sql).to_pydict()
+    want = plain.execute(sql).to_pydict()
+    e = QueryEngine(use_jit=False)
+    e.register_table("t", pa.table({"a": [3, 1, 2], "v": [1.0, 2.0, 3.0]}))
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Recording)
+    assert e.execute(sql).to_pydict() == want
+    e.result_cache.clear()
+    assert e.execute(sql).to_pydict() == want
+    open_now = []
+    for what, name in log:            # properly nested, all closed
+        if what == "enter":
+            open_now.append(name)
+        else:
+            assert open_now.pop() == name
+    assert not open_now
+    entered = [name for what, name in log if what == "enter"]
+    assert entered.count("igloo:query") == 2
+    assert {"igloo:parse", "igloo:bind+optimize", "igloo:execute",
+            "igloo:program.first_call", "igloo:program.dispatch"} \
+        <= set(entered)
+    # the first execution traces every program, the second none
+    second = entered[len(entered) - entered[::-1].index("igloo:parse") - 1:]
+    assert "igloo:program.first_call" not in second
 
 
 # --- cross-process stitching (2-worker in-process cluster) -------------------
