@@ -107,6 +107,15 @@ def _dep_key(frag_id: str, bucket) -> str:
     return base if bucket is None else f"{base}{bucket}"
 
 
+class _DependencyTable(MemTable):
+    """A dependency's result as the input of ONE fragment execution. Its
+    table name carries the dependency's per-query id; `ephemeral` tells the
+    fused compiler to key a scan of it by position instead (exec/fused.py
+    `_c_scan`), so the consumer's program is built once, not per query."""
+
+    ephemeral = True
+
+
 class _OverlayCatalog:
     """Base catalog + per-fragment `__frag_*` dependency tables."""
 
@@ -273,7 +282,7 @@ class WorkerServer(flight.FlightServerBase):
                                         ref.get("bucket"), ref.get("buckets"),
                                         deadline=deadline)
                     input_rows += t.num_rows
-                    overlay[name] = MemTable(t)
+                    overlay[name] = _DependencyTable(t)
                 dep_s = time.perf_counter() - t_dep0
                 catalog = _OverlayCatalog(self._catalog, overlay)
                 plan = serde.plan_from_json(plan_json, catalog)

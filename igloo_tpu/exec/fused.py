@@ -241,7 +241,15 @@ class FusedCompiler:
         # is pushed into the key by its own node, so coarsening here is sound
         # and lets near scale factors share one fused program.
         from igloo_tpu.exec.capacity import canonical_direct_table
-        self._push(("scan", plan.table, tuple(plan.projection or ()),
+        # a provider that lives for ONE execution (a fragment's dependency
+        # result: its table name is a per-query id) says so; its scan is
+        # keyed by its position among the program's leaves, so the program
+        # and its hints are found again by the next query. Its data never
+        # enters here: the leaf is an argument, and the BatchCache keeps the
+        # real name.
+        ident = idx if getattr(plan.provider, "ephemeral", False) \
+            else plan.table
+        self._push(("scan", ident, tuple(plan.projection or ()),
                     repr(plan.pushed_filters), plan.partition,
                     plan.schema, batch.capacity,
                     tuple(c.nulls is not None for c in batch.columns),

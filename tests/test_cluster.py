@@ -172,6 +172,83 @@ def test_query_metrics_surface(cluster):
     client.close()
 
 
+# --- a dependency scan is keyed by position, its data cached by id ---
+# These statements read `cust`, one scan partition: one partial fragment and
+# a merge fragment that is ONE fused program over its `__frag_<id>` scan,
+# as on a one-worker deployment. (Over `orders` the merge unions one
+# dependency per worker and runs staged, whose keys never held a name.)
+
+def _run_uncached(cluster, client, sql):
+    """Execute with the coordinator's result cache cleared, so the fragments
+    run; return (answer, the fragments of last_metrics())."""
+    cluster["coord"].engine.result_cache.clear()
+    got = client.execute(sql)
+    m = client.last_metrics()
+    assert not m.get("result_cache_hit") and m["fragments"]
+    return got, m["fragments"]
+
+
+def _fused_programs(cluster) -> int:
+    return sum(1 for w in cluster["workers"] for k in w.server._jit_cache
+               if isinstance(k, tuple) and k and k[0] == "fused")
+
+
+def _merge_fragments(frags: list) -> list:
+    """The fragments that read other fragments' results."""
+    return [f for f in frags if f.get("input_rows")]
+
+
+@pytest.mark.parametrize("sql", [
+    # q1-shaped: string group key, float sums, ORDER BY
+    "SELECT c_tier, SUM(c_id * 0.5) AS h, AVG(c_id * 1.5) AS a, "
+    "COUNT(*) AS c FROM cust WHERE c_id < 190 GROUP BY c_tier "
+    "ORDER BY c_tier",
+    # q6-shaped: one scalar aggregate
+    "SELECT SUM(c_id * 0.5) AS rev FROM cust "
+    "WHERE c_id > 10 AND c_id < 150 AND c_tier = 'gold'",
+], ids=["q1_shaped", "q6_shaped"])
+def test_merge_program_is_built_once(cluster, sql):
+    """The merge fragment reads its dependency through a `__frag_<id>` scan
+    and the id is new in every query: the program that merges it must not
+    be (ISSUE 29)."""
+    client = DistributedClient(cluster["addr"])
+    want = cluster["local"].execute(sql)
+    _run_uncached(cluster, client, sql)
+    got, _ = _run_uncached(cluster, client, sql)
+    _assert_same(got, want)
+    programs = _fused_programs(cluster)
+    got, frags = _run_uncached(cluster, client, sql)
+    _assert_same(got, want)
+    assert _merge_fragments(frags)
+    assert [f["jit_misses"] for f in frags] == [0] * len(frags)
+    # the leak is closed: a worker holds one program per plan, not per query
+    assert _fused_programs(cluster) == programs
+    client.close()
+
+
+def test_shared_merge_program_never_aliases_data(cluster):
+    """Two queries of one shape share the merge PROGRAM from B's first
+    execution on; their dependency tables (other rows, another dictionary
+    under the string key) stay apart."""
+    shape = ("SELECT c_name, c_tier, SUM(c_id * 0.5) AS h, COUNT(*) AS c "
+             "FROM cust WHERE {} GROUP BY c_name, c_tier ORDER BY c_name")
+    a, b = shape.format("c_id < 5"), shape.format("c_id >= 195")
+    local = cluster["local"]
+    want_a, want_b = local.execute(a), local.execute(b)
+    assert want_a.num_rows == want_b.num_rows == 5
+    assert not set(want_a.column("c_name").to_pylist()) \
+        & set(want_b.column("c_name").to_pylist())
+    client = DistributedClient(cluster["addr"])
+    for sql, want in ((a, want_a), (b, want_b), (a, want_a), (b, want_b)):
+        got, frags = _run_uncached(cluster, client, sql)
+        _assert_same(got, want)
+        merge = _merge_fragments(frags)
+        assert merge
+        if sql is b:
+            assert [f["jit_misses"] for f in merge] == [0] * len(merge)
+    client.close()
+
+
 def test_metrics_flight_action(cluster):
     """Both servers serve Prometheus text via the `metrics` action; the
     coordinator's includes worker-aggregated fragment stats."""

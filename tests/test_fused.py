@@ -8,6 +8,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from igloo_tpu.catalog import MemTable
 from igloo_tpu.engine import QueryEngine
 from igloo_tpu.exec import fused as F
 from igloo_tpu.utils import tracing
@@ -144,3 +145,75 @@ def test_fused_matches_staged(jointype, exp):
     ex = Executor(e._jit_cache, batch_cache=e.batch_cache)
     t2 = ex._staged_to_arrow(e.plan(sql))
     assert t2.to_pydict() == exp
+
+
+# --- what a scan contributes to the program key (ISSUE 29) ---
+
+class _OneExecutionTable(MemTable):
+    """What the worker hands a fragment for each dependency: a provider that
+    declares itself the input of one execution."""
+    ephemeral = True
+
+
+_KEY_SQL = ("SELECT k, SUM(v) AS s, COUNT(*) AS c FROM {0} "
+            "WHERE n < 4 GROUP BY k ORDER BY k")
+# aliased, as a fragment's expressions keep the statement's qualifiers
+_KEY_JOIN_SQL = ("SELECT a.k, SUM(a.v + b.v) AS s FROM {0} a "
+                 "JOIN {1} b ON a.n = b.n GROUP BY a.k ORDER BY 1")
+# repr of key[1] (the node fingerprints) of _KEY_SQL over an ordinary
+# MemTable named `facts`, taken on the parent commit (2149ae4)
+_PARENT_FPS = (
+    "(('scan', 'facts', (), '[(col(n) < lit(4))]', None, "
+    "Schema(k: string, v: float64, n: int64), 8, (True, False, False), "
+    "(None, None, (0, 8)), (('int8', ('int32', False, 1.0, False)), "
+    "('int8', ('float64', False, 1.0, False)), "
+    "('int8', ('int64', False, 1.0, False)))), "
+    "('filter', '(col(n) < lit(4))'), "
+    "('agg', ('col(k)', 'col(v)'), ((<AggFunc.SUM: 'sum'>, float64), "
+    "(<AggFunc.COUNT_STAR: 'count_star'>, int64)), "
+    "Schema(k: string, __agg_0: float64, __agg_1: int64), ((3, 0),), "
+    "None, None), "
+    "('project', ('col(k)', 'col(__agg_0)', 'col(__agg_1)'), "
+    "Schema(k: string, s: float64, c: int64)), "
+    "('sort', ('col(k)',), (True,), (False,), "
+    "(('i32', 0, ((4, True, False),)), 1)))")
+
+
+def _compile_over(provider_cls, sql: str, names: tuple):
+    """Compile `sql` over one `provider_cls` table per name (same schema and
+    capacity, different content); returns the FusedCompiler and its key."""
+    from igloo_tpu.exec.executor import Executor
+    e = QueryEngine()
+    for i, name in enumerate(names):
+        e.register_table(name, provider_cls(pa.table({
+            "k": pa.array([name, "b", name, None]),
+            "v": [1.0 + i, 2.0, 3.0, 4.0],
+            "n": pa.array([1, 2, 3, 4], pa.int64())})))
+    comp = F.FusedCompiler(Executor())
+    _run, key, _meta = comp.compile(e.plan(sql.format(*names)))
+    return comp, key
+
+
+@pytest.mark.parametrize("sql,first,second", [
+    (_KEY_SQL, ("dep_3f9a",), ("dep_c071",)),
+    (_KEY_JOIN_SQL, ("dep_3f9a", "dep_77e2"), ("dep_c071", "dep_0b1d")),
+], ids=["one_leaf", "two_leaves"])
+def test_ephemeral_scan_is_keyed_by_position(sql, first, second):
+    c1, k1 = _compile_over(_OneExecutionTable, sql, first)
+    c2, k2 = _compile_over(_OneExecutionTable, sql, second)
+    assert k1 == k2 and c1.hfps == c2.hfps
+    scans = [fp for fp in c1.fps if fp[0] == "scan"]
+    assert [fp[1] for fp in scans] == list(range(len(first)))
+
+
+def test_ordinary_scan_is_keyed_by_name():
+    _, k1 = _compile_over(MemTable, _KEY_SQL, ("facts",))
+    _, k2 = _compile_over(MemTable, _KEY_SQL, ("fakts",))
+    assert k1 != k2
+
+
+def test_ordinary_scan_fingerprint_did_not_move():
+    # the "k" column's content differs from the parent's sample ("a"): the
+    # key is content-light, so the literal still holds
+    _, key = _compile_over(MemTable, _KEY_SQL, ("facts",))
+    assert repr(key[1]) == _PARENT_FPS
