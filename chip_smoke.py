@@ -95,19 +95,24 @@ def cache_state() -> dict:
     return state
 
 
-def stage(sf: float, seed: int, root: str) -> tuple:
-    """Write the eight tables as Parquet under `root`; -> (pandas frames for
-    the oracle, row counts)."""
+def stage(sf: float, seed: int, root: str, only: list) -> tuple:
+    """Write the eight tables as Parquet under `root`, or with --tables just
+    those, by the benchmark's vectorised generator; -> (pandas frames for the
+    oracle, row counts)."""
     import pyarrow.parquet as pq
-
-    from igloo_tpu.bench.tpch import gen_tables
-    tables = gen_tables(sf=sf, seed=seed)
+    if only:
+        sys.path.insert(0, os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmark"))
+        from datagen import gen_tables
+        tables = gen_tables(sf=sf, seed=seed, tables=only)
+    else:
+        from igloo_tpu.bench.tpch import gen_tables
+        tables = gen_tables(sf=sf, seed=seed)
     frames = {}
-    for name in TABLES:
-        tbl = tables[name]
+    for name, tbl in tables.items():
         pq.write_table(tbl, os.path.join(root, f"{name}.parquet"))
         frames[name] = frame(tbl)
-    return frames, {n: tables[n].num_rows for n in TABLES}
+    return frames, {n: t.num_rows for n, t in tables.items()}
 
 
 # --- the oracle comparison ---------------------------------------------------
@@ -287,7 +292,7 @@ def run_served(stage_dir: str, frames: dict, queries: list) -> None:
             time.sleep(0.05)
         if not coord.membership.live():
             raise RuntimeError("worker never registered with the coordinator")
-        for name in TABLES:
+        for name in frames:
             coord.register_table(name, ParquetTable(
                 os.path.join(stage_dir, f"{name}.parquet")))
         wid = worker.server.worker_id
@@ -449,6 +454,11 @@ def main(argv=None) -> int:
                     help="in-process session queries, in this order")
     ap.add_argument("--served", default="q1,q3",
                     help="queries through coordinator + worker ('' skips)")
+    ap.add_argument("--tables", default="",
+                    help="stage only these, by benchmark/datagen.py (--sf 10 "
+                    "in minutes, not in Python lists): for a served probe at "
+                    "a large --sf, with --queries '' (the session registers "
+                    "all eight)")
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run only the mesh tier against one device")
     args = ap.parse_args(argv)
@@ -472,14 +482,16 @@ def main(argv=None) -> int:
              igloo_env={k: v for k, v in os.environ.items()
                         if k.startswith(("IGLOO_", "JAX_", "XLA_"))})
         t0 = time.perf_counter()
-        frames, nrows = stage(args.sf, args.seed, tmp)
+        frames, nrows = stage(args.sf, args.seed, tmp,
+                              [t for t in args.tables.split(",") if t])
         emit(phase="stage", sf=args.sf, seed=args.seed, rows=nrows,
              seconds=time.perf_counter() - t0)
         if args.chips > 1:
             run_mesh(tmp, frames, nrows, args.chips, dump_dir)
         else:
-            run_session(tmp, frames,
-                        [q for q in args.queries.split(",") if q])
+            session = [q for q in args.queries.split(",") if q]
+            if session:
+                run_session(tmp, frames, session)
             served = [q for q in args.served.split(",") if q]
             if served:
                 run_served(tmp, frames, served)

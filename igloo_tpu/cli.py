@@ -36,7 +36,8 @@ def build_engine(cfg, use_jit: bool = True):
         init_distributed(cfg)
         # [storage] policy + prefetch twins (env wins per-field)
         apply_storage(cfg)
-        kw["cache_budget_bytes"] = cfg.cache_budget_bytes
+        if cfg.cache_budget_bytes is not None:
+            kw["cache_budget_bytes"] = cfg.cache_budget_bytes
         if cfg.mesh_shape:
             import math
             from igloo_tpu.parallel.mesh import make_mesh

@@ -961,7 +961,13 @@ class CoordinatorServer(flight.FlightServerBase):
             host = location.split("://")[-1].rsplit(":", 1)[0]
             advertise_host = host if host and host != "0.0.0.0" else "127.0.0.1"
         self.advertise_host = advertise_host
-        self.engine = QueryEngine(use_jit=use_jit)
+        # this engine runs only the local fallback and the demotion ladder.
+        # The resident share of a chip belongs to the worker that serves it
+        # (in the same process or beside it), so the fallback's scan cache
+        # keeps the 1 GiB it always had and the two stay under the device
+        from igloo_tpu.exec.cache import UNLIMITED_BUDGETS
+        self.engine = QueryEngine(use_jit=use_jit,
+                                  cache_budget_bytes=UNLIMITED_BUDGETS[0])
         self.membership = Membership(worker_timeout_s)
         self.executor = DistributedExecutor(self.membership)
         # multi-tenant front door (docs/serving.md): bounded per-priority

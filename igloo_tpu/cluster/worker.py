@@ -168,8 +168,10 @@ class WorkerServer(flight.FlightServerBase):
         # SETTING — the lazily resolved mesh spans the same devices.
         from igloo_tpu.parallel.mesh import mesh_device_count
         self.mesh_devices = mesh_device_count(mesh)
-        from igloo_tpu.exec.cache import BatchCache
-        self._batch_cache = BatchCache(1 << 30)
+        # the HBM scan cache: under the resident share of this worker's
+        # device, as a QueryEngine's is (docs/out_of_core.md)
+        from igloo_tpu.exec.cache import ResidentCache
+        self._batch_cache = ResidentCache()
         # fragment-execution slot bound (env > constructor > device-derived
         # default): concurrent execute_fragment RPCs queue on the semaphore
         # instead of racing the device into OOM (docs/serving.md)

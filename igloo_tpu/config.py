@@ -119,7 +119,9 @@ class Config:
     device: str = "auto"           # auto | tpu | cpu
     mesh_shape: Optional[list[int]] = None
     mesh_axes: list[str] = field(default_factory=lambda: ["data"])
-    cache_budget_bytes: int = 1 << 30
+    # None: the resident share of the device's memory (exec/cache.py
+    # hbm_budgets); a number overrides it
+    cache_budget_bytes: Optional[int] = None
     cluster: ClusterConfig = field(default_factory=ClusterConfig)
     rpc: RpcConfig = field(default_factory=RpcConfig)
     serving: ServingConfig = field(default_factory=ServingConfig)

@@ -69,12 +69,12 @@ DEFAULT_MESH: object = "auto"
 
 class QueryEngine:
     def __init__(self, catalog: Optional[Catalog] = None, use_jit: bool = True,
-                 cache_budget_bytes: int = 1 << 30,
-                 chunk_budget_bytes: int = 2 << 30,
+                 cache_budget_bytes: Optional[int] = None,
+                 chunk_budget_bytes: Optional[int] = None,
                  mesh: object = "default"):
         if mesh == "default":
             mesh = DEFAULT_MESH
-        from igloo_tpu.exec.cache import BatchCache
+        from igloo_tpu.exec.cache import BatchCache, ResidentCache
         self.catalog = catalog if catalog is not None else Catalog()
         self.udfs: dict[str, UdfDef] = {}
         self._jit_cache: dict = {}
@@ -86,8 +86,11 @@ class QueryEngine:
         # x the provider's bytes_expansion): SF10's 1.2 GB parquet lineitem
         # decodes to ~4 GB of int64/float64 lanes, and its full-width join
         # intermediates at 67M lanes crash a 16 GB-HBM chip if run
-        # monolithically
-        self.chunk_budget_bytes = chunk_budget_bytes
+        # monolithically. None: the monolithic share of the device's memory
+        # (exec/cache.py hbm_budgets: 1/8, 2.1 GB on the v5e; 2 GiB on
+        # XLA:CPU, which reports no limit), read at the first routing
+        # decision so that constructing an engine touches no device
+        self._chunk_budget_bytes = chunk_budget_bytes
         # multi-chip execution: "auto" = row-shard across all local devices
         # when more than one is visible (parallel/ShardedExecutor); None =
         # single-device; or an explicit jax.sharding.Mesh
@@ -101,7 +104,9 @@ class QueryEngine:
         self._demote_tls = threading.local()
         # HBM batch cache: scan results stay device-resident across queries
         # (the real version of the reference's unenforced CacheConfig, gap G7)
-        self.batch_cache = BatchCache(cache_budget_bytes)
+        # under the device's resident share unless the argument overrides it
+        self.batch_cache = ResidentCache() if cache_budget_bytes is None \
+            else BatchCache(cache_budget_bytes)
         # host-side query-result cache (the reference cache's actual shape:
         # query -> batches, crates/cache/src/lib.rs:20-56), snapshot-validated
         from igloo_tpu.exec.result_cache import ResultCache
@@ -121,7 +126,7 @@ class QueryEngine:
         self.host_route_bytes = int(os.environ.get(
             "IGLOO_HOST_ROUTE_BYTES", str(64 << 20)))
         # decoded-column cache for the host tier (plain RAM, not HBM)
-        self.host_cache = BatchCache(cache_budget_bytes)
+        self.host_cache = BatchCache()
         # reference parity: capitalize registered at construction (lib.rs:41-42)
         self.register_udf(UdfDef("capitalize", T.STRING))
         # SQL-queryable telemetry: SELECT * FROM system.metrics /
@@ -379,6 +384,13 @@ class QueryEngine:
             yield
         finally:
             self._demote_tls.budget, self._demote_tls.force_host = prev
+
+    @property
+    def chunk_budget_bytes(self) -> int:
+        if self._chunk_budget_bytes is None:
+            from igloo_tpu.exec.cache import hbm_budgets
+            self._chunk_budget_bytes = hbm_budgets()[1]
+        return self._chunk_budget_bytes
 
     def _chunk_budget(self) -> int:
         override = getattr(self._demote_tls, "budget", None)

@@ -23,6 +23,35 @@ from igloo_tpu.utils import stats
 from igloo_tpu.utils.tracing import counter
 
 
+# Shares of ONE device's `memory_stats()["bytes_limit"]` (docs/out_of_core.md
+# "The two budgets"): what the scan cache may keep resident, and the largest
+# source one monolithic program may scan before the chunked / GRACE tiers are
+# chosen. A program's inputs ARE resident columns, so the other half of the
+# device is for its temporaries. The monolithic share keeps on a 16 GB chip
+# the 2 GiB that every chip run so far was routed under: a larger one takes a
+# chip run of an SF10 join first.
+RESIDENT_SHARE = 1 / 2
+MONOLITHIC_SHARE = 1 / 8
+# where the backend reports no limit (XLA:CPU): the constants the engine had
+# before the budgets were derived, so that no CPU route changes
+UNLIMITED_BUDGETS = (1 << 30, 2 << 30)
+
+
+def hbm_budgets() -> tuple:
+    """(resident, monolithic) bytes for this process's devices: the scan
+    cache's budget and the out-of-core threshold, shared by `QueryEngine` and
+    the cluster worker. The smallest local device decides (a mesh row-shards
+    evenly, so the fullest chip is the tightest). This starts the backend:
+    call it where a device is needed anyway, not at construction."""
+    import jax
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in jax.local_devices()]
+    if not limits or not all(limits):
+        return UNLIMITED_BUDGETS
+    limit = min(limits)
+    return int(limit * RESIDENT_SHARE), int(limit * MONOLITHIC_SHARE)
+
+
 def scan_table_key(name: str) -> str:
     """Canonical cache key for a table name: the binder sets Scan.table to the
     last dotted component lowercased (plan/binder.py), so every invalidation
@@ -52,7 +81,7 @@ class SnapshotLRU:
 
     def __init__(self, budget_bytes: int = 1 << 30,
                  capacity: Optional[int] = None):
-        self.budget_bytes = int(budget_bytes)
+        self._budget_bytes = int(budget_bytes)
         self.capacity = int(capacity) if capacity is not None else None
         self._entries: OrderedDict = OrderedDict()
         self._bytes = 0
@@ -60,6 +89,10 @@ class SnapshotLRU:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+
+    @property
+    def budget_bytes(self) -> int:
+        return self._budget_bytes
 
     def get(self, key, snapshot: object):
         with self._lock:
@@ -85,15 +118,19 @@ class SnapshotLRU:
 
     def put(self, key, value, snapshot: object, nbytes: int,
             tables: frozenset = frozenset()) -> None:
-        if nbytes > self.budget_bytes:
-            return  # larger than the whole budget: never cacheable
+        budget = self.budget_bytes
+        if nbytes > budget:
+            # larger than the whole budget: never cacheable, so every scan
+            # of it decodes and uploads again
+            counter(f"{self.counter_prefix}.too_large")
+            return
         with self._lock:
             old = self._entries.pop(key, None)
             if old is not None:
                 self._bytes -= old.nbytes
             self._entries[key] = CacheEntry(value, snapshot, nbytes, tables)
             self._bytes += nbytes
-            while self._bytes > self.budget_bytes and self._entries:
+            while self._bytes > budget and self._entries:
                 _, ev = self._entries.popitem(last=False)
                 self._bytes -= ev.nbytes
                 self.evictions += 1
@@ -157,6 +194,23 @@ class BatchCache(SnapshotLRU):
 
     def _match_table(self, key, entry, table_key: str) -> bool:
         return bool(key) and key[0] == table_key
+
+
+class ResidentCache(BatchCache):
+    """The HBM scan cache of a `QueryEngine` or a cluster worker, under the
+    device's resident share (`hbm_budgets`). The share is read at the first
+    put and not here: constructing an engine or a coordinator touches no
+    device."""
+
+    def __init__(self):
+        super().__init__(0)
+        self._share: Optional[int] = None
+
+    @property
+    def budget_bytes(self) -> int:
+        if self._share is None:
+            self._share = hbm_budgets()[0]
+        return self._share
 
 
 def provider_snapshot(provider) -> object:

@@ -609,10 +609,11 @@ class Executor:
                 hit = self._batch_cache.get(key, snap)
                 if hit is not None:
                     return hit
-            table = read_scan_table(plan)
-            if plan.projection is not None:
-                table = table.select(plan.projection)
-            batch = from_arrow(table, schema=plan.schema)
+            with tracing.span("program.scan_load", table=plan.table):
+                table = read_scan_table(plan)
+                if plan.projection is not None:
+                    table = table.select(plan.projection)
+                batch = from_arrow(table, schema=plan.schema)
             _note_carrier_ratio(plan.provider, batch)
             if self._batch_cache is not None:
                 self._batch_cache.put(key, batch, snap)
@@ -650,33 +651,38 @@ class Executor:
             return DeviceBatch(plan.schema,
                                [cached[f.name][0] for f in plan.schema], live)
         proj = [f.name for f in missing]  # non-empty: all-cached paths return above
-        table = read_scan_table(plan, projection=proj).select(proj)
-        n = table.num_rows
-        if (known_n is not None and n != known_n) or \
-                (live_n is not None and n != live_n):
-            # source changed under an identity snapshot: drop and re-read all
-            self._batch_cache.invalidate_table(plan.table)
-            return self._exec_scan(plan)
-        cap = int(live.shape[0]) if live is not None else (
-            round_capacity(n) if known_n is None
-            else next(v[0].capacity for v in cached.values() if v is not None))
-        decoded = [host_decode_column(table.column(f.name), f)
-                   for f in missing]
-        new_cols = device_columns(decoded, missing, cap)
-        for f, col in zip(missing, new_cols):
-            nbytes = col.values.nbytes + (
-                col.nulls.nbytes if col.nulls is not None else 0)
-            self._batch_cache.put_entry(base + ("col", f.name), (col, n),
-                                        snap, nbytes, plan.table)
-            cached[f.name] = (col, n)
-        if live is None:
-            live = live_lane(cap, n)
-            self._batch_cache.put_entry(base + ("live",), (live, n), snap,
-                                        live.nbytes, plan.table)
-        out = DeviceBatch(plan.schema,
-                          [cached[f.name][0] for f in plan.schema], live)
-        _note_carrier_ratio(plan.provider, out)
-        return out
+        # the miss path (provider read + decode, codec, H2D) is one span: a
+        # resident table never opens it
+        with tracing.span("program.scan_load", table=plan.table,
+                          columns=len(proj)):
+            table = read_scan_table(plan, projection=proj).select(proj)
+            n = table.num_rows
+            if (known_n is not None and n != known_n) or \
+                    (live_n is not None and n != live_n):
+                # source changed under an identity snapshot: drop, re-read
+                self._batch_cache.invalidate_table(plan.table)
+                return self._exec_scan(plan)
+            cap = int(live.shape[0]) if live is not None else (
+                round_capacity(n) if known_n is None
+                else next(v[0].capacity for v in cached.values()
+                          if v is not None))
+            decoded = [host_decode_column(table.column(f.name), f)
+                       for f in missing]
+            new_cols = device_columns(decoded, missing, cap)
+            for f, col in zip(missing, new_cols):
+                nbytes = col.values.nbytes + (
+                    col.nulls.nbytes if col.nulls is not None else 0)
+                self._batch_cache.put_entry(base + ("col", f.name), (col, n),
+                                            snap, nbytes, plan.table)
+                cached[f.name] = (col, n)
+            if live is None:
+                live = live_lane(cap, n)
+                self._batch_cache.put_entry(base + ("live",), (live, n), snap,
+                                            live.nbytes, plan.table)
+            out = DeviceBatch(plan.schema,
+                              [cached[f.name][0] for f in plan.schema], live)
+            _note_carrier_ratio(plan.provider, out)
+            return out
 
     def _exec_values(self, plan: L.Values) -> DeviceBatch:
         n = len(plan.rows)
