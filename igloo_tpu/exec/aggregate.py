@@ -27,6 +27,7 @@ from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
 from igloo_tpu.exec.dispatch import DIRECT_SEG_SMALL_LIMIT
 from igloo_tpu.exec.expr_compile import Compiled, Env
+from igloo_tpu.plan import logical as L
 from igloo_tpu.plan.expr import AggFunc
 from igloo_tpu.utils import tracing
 
@@ -428,6 +429,29 @@ def _global_aggregate(env: Env, aggs: list[AggSpec], out_schema: T.Schema,
                                          spec.out_dict))
     out_live = jnp.zeros((MIN_CAPACITY,), dtype=bool).at[0].set(True)
     return DeviceBatch(out_schema, out_cols, out_live)
+
+
+def uncompacted_filter(plan: L.Aggregate) -> Optional[L.Filter]:
+    """The Filter whose live rows `plan` reads best where they lie, or None.
+
+    An adopted cardinality hint compacts a node's output so that what
+    consumes it runs at the hinted width. Whether that pays is the
+    CONSUMER's to say: an aggregate with no group expressions is
+    `_global_aggregate`, one masked pass over its input, and a compaction in
+    front of it (a lane-wide sort, then a gather per column) costs several
+    such passes and saves none. So it asks its input not to compact; the
+    request passes through Project nodes (row-wise, width-preserving) to the
+    first Filter under them. Under a join, a distinct or a sub-aggregate the
+    answer is None: what they compact is theirs to decide. THE one place
+    this rule lives: the fused compiler (FusedCompiler._c_aggregate) and the
+    staged one (Executor._exec_aggregate) both ask it, so the two programs of
+    one plan cannot disagree."""
+    if plan.group_exprs or any(a.distinct for a in plan.aggs):
+        return None
+    node = plan.input
+    while isinstance(node, L.Project):
+        node = node.input
+    return node if isinstance(node, L.Filter) else None
 
 
 def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,

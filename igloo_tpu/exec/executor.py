@@ -30,6 +30,7 @@ from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
+    uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
@@ -758,7 +759,9 @@ class Executor:
     # --- blocking ops ---
 
     def _exec_aggregate(self, plan: L.Aggregate) -> DeviceBatch:
-        batch = self._adaptive_input(self._exec(plan.input), plan.input)
+        batch = self._exec(plan.input)
+        if uncompacted_filter(plan) is None:
+            batch = self._adaptive_input(batch, plan.input)
         distinct_aggs = [a for a in plan.aggs if a.distinct]
         if distinct_aggs:
             return self._exec_distinct_aggregate(plan, batch)

@@ -23,6 +23,21 @@ repair re-run with corrected hints, so results are always exact. Direct inner
 joins go further: with a hint, build-side columns are gathered only AFTER the
 probe-side compaction, at hinted width (lazy materialization).
 
+The producer records the hint; the CONSUMER says whether adopting it pays,
+because a compaction is itself lane-wide work (`K.compact_to`: a stable
+(pred, u32) sort, then a gather per column). On one v5e at 2^26 lanes the sort
+is 295 ms and each gathered column ~83 ms, while a whole TPC-H q1 (eight
+float64 aggregates) at the full 2^26 lanes is 105-113 ms and q6's masked sum
+there 9 ms (PERF.md, PRs 30 and 31). So an
+aggregate without group expressions (one masked pass: `_global_aggregate`)
+asks the Filter under it (`aggregate.uncompacted_filter`, which the staged
+executor asks too) to keep its lanes: the filter still records its live count
+but pushes no `acompact` fingerprint and raises no flag, so its program key is
+the same before and after the hint exists and nothing is traced or compiled
+twice (`fused.compact_declined` counts the hints left unadopted). Joins,
+grouped aggregates, sorts, top-k, windows, distinct and limits read narrower
+inputs cheaper by more than the compaction costs and keep it.
+
 Correctness flags collected across the program (direct-join duplicate keys,
 speculative join capacity overflow, compaction overflow) come back in the same
 single fetch; only a raised flag or an oversized result costs extra round trips.
@@ -43,6 +58,7 @@ from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
+    uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     MIN_CAPACITY, DeviceBatch, DeviceColumn, round_capacity,
@@ -116,6 +132,9 @@ class FusedCompiler:
         # the executor's compile-failure rung bans them all and recompiles
         # on the sort path when the program fails to lower
         self.pallas_bans: list = []
+        # Filter nodes whose consumer asked them not to compact
+        # (aggregate.uncompacted_filter): hints recorded, never adopted
+        self.uncompacted: list = []
 
     # --- side-channel ids -------------------------------------------------
 
@@ -186,18 +205,32 @@ class FusedCompiler:
                 return DeviceBatch(_s, b.columns, b.live)
             fn = renamed
         if name in self._ADAPTIVE_NODES and meta.capacity > ADAPTIVE_CAPACITY:
-            fn, meta = self._adaptive(fn, meta, name)
+            fn, meta = self._adaptive(
+                fn, meta, name,
+                compact=not any(plan is f for f in self.uncompacted))
         return fn, meta
 
-    def _adaptive(self, fn: NodeFn, meta: NodeMeta, kind: str):
+    def _adaptive(self, fn: NodeFn, meta: NodeMeta, kind: str,
+                  compact: bool = True):
         """Record this node's live count as a cardinality hint; when a prior
         run's hint shows a strong shrink, compact to the hinted capacity inside
-        the program, flagging overflow (exact repair re-run with fresh hints)."""
+        the program, flagging overflow (exact repair re-run with fresh hints).
+
+        `compact=False` is the consumer declining (module docstring: a global
+        aggregate reads the rows where they lie, and at 2^26 lanes the
+        compaction is 295 ms + ~83 ms a column in front of a pass that costs
+        9 ms, where a whole q1 is 105-113 ms). The count is still recorded,
+        so the hint and what AdaptiveStats learns do not change; the
+        fingerprints and flags are those of a node that has no hint yet."""
         hkey = (kind, tuple(self.hfps))
         sid = self._new_stat(hkey)
         hint = self._hint(hkey)
         want = round_capacity(max(hint, 1)) if hint is not None else None
-        if want is not None and want * ADAPTIVE_SHRINK <= meta.capacity:
+        shrinks = want is not None \
+            and want * ADAPTIVE_SHRINK <= meta.capacity
+        if shrinks and not compact:
+            tracing.counter("fused.compact_declined")
+        elif shrinks:
             fid = self._new_flag(("compact", hkey))
             self._push(("acompact", want), hint_fp=None)
 
@@ -523,6 +556,9 @@ class FusedCompiler:
     def _c_aggregate(self, plan: L.Aggregate):
         if any(a.distinct for a in plan.aggs):
             raise FusionUnsupported("distinct aggregate")
+        keep = uncompacted_filter(plan)
+        if keep is not None:
+            self.uncompacted.append(keep)
         cfn, meta = self._c(plan.input)
         comp = self._compiler_for(meta)
         gres, groups = self._compile_exprs(plan.group_exprs, comp)

@@ -217,3 +217,230 @@ def test_ordinary_scan_fingerprint_did_not_move():
     # key is content-light, so the literal still holds
     _, key = _compile_over(MemTable, _KEY_SQL, ("facts",))
     assert repr(key[1]) == _PARENT_FPS
+
+
+# --- who decides that a hinted node compacts (ISSUE 31) ---
+
+_SEL_N = 5000           # capacity 8192, over the lowered ADAPTIVE_CAPACITY
+_SEL_GLOBAL = "SELECT sum(x * w) AS s, count(*) AS c FROM fact WHERE w < 3"
+_SEL_PROJECTED = ("SELECT sum(y) AS s, count(*) AS c FROM "
+                  "(SELECT x * w AS y FROM fact WHERE w < 3) t")
+_SEL_GROUPED = ("SELECT fk, sum(x * w) AS s, count(*) AS c FROM fact "
+                "WHERE w < 3 GROUP BY fk ORDER BY fk")
+_SEL_JOINED = ("SELECT sum(x * w + v) AS s, count(*) AS c FROM fact "
+               "JOIN dim ON fk = k WHERE w < 3")
+# repr of key[1] (the node fingerprints) of _SEL_GROUPED's second
+# compilation, its filter's hint adopted, taken on the parent commit (7da1175)
+_PARENT_COMPACTED_FPS = (
+    "(('scan', 'fact', (), '[(col(w) < lit(3))]', None, "
+    "Schema(fk: int64, w: int64, x: float64), 8192, (False, False, False), "
+    "((0, 16), (0, 256), None), (('int8', ('int64', False, 1.0, False)), "
+    "('int8', ('int64', False, 1.0, False)), None)), "
+    "('filter', '(col(w) < lit(3))'), ('acompact', 256), "
+    "('agg', ('col(fk)', '(col(x) * col(w))'), "
+    "((<AggFunc.SUM: 'sum'>, float64), "
+    "(<AggFunc.COUNT_STAR: 'count_star'>, int64)), "
+    "Schema(fk: int64, __agg_0: float64, __agg_1: int64), ((9, 1),), "
+    "None, None), "
+    "('project', ('col(fk)', 'col(__agg_0)', 'col(__agg_1)'), "
+    "Schema(fk: int64, s: float64, c: int64)), "
+    "('sort', ('col(fk)',), (True,), (False,), "
+    "(('i32', 0, ((16, True, False),)), 1)))")
+
+
+def _sel_tables(dense: bool = False):
+    """`fact` keeps 3 % of its rows under `w < 3` (dense: ~50 %, with the same
+    capacity and bounds, so the same program and the same hint keys)."""
+    rng = np.random.default_rng(3)
+    w = rng.integers(0, 100, _SEL_N)
+    if dense:
+        w = np.where(rng.random(_SEL_N) < 0.5, w % 3, w)
+        w[:2] = (0, 99)
+    fact = pa.table({
+        "fk": pa.array(rng.integers(1, 9, _SEL_N), type=pa.int64()),
+        "w": pa.array(w, type=pa.int64()),
+        "x": pa.array(rng.random(_SEL_N), type=pa.float64())})
+    dim = pa.table({"k": pa.array(np.arange(1, 9), type=pa.int64()),
+                    "v": pa.array(np.arange(8) * 1.5, type=pa.float64())})
+    return fact, dim
+
+
+def _sel_oracle(sql: str, fact: pa.Table, dim: pa.Table) -> dict:
+    f = fact.to_pandas()
+    f = f[f.w < 3].assign(y=lambda d: d.x * d.w)
+    if sql == _SEL_GROUPED:
+        g = f.groupby("fk").y.agg(["sum", "size"]).reset_index()
+        return {"fk": list(g.fk), "s": list(g["sum"]), "c": list(g["size"])}
+    if sql == _SEL_JOINED:
+        f = f.merge(dim.to_pandas(), left_on="fk", right_on="k")
+        return {"s": [float((f.y + f.v).sum())], "c": [len(f)]}
+    return {"s": [float(f.y.sum())], "c": [len(f)]}
+
+
+def _same_answer(t: pa.Table, want: dict):
+    got = t.to_pydict()
+    assert list(got) == list(want)
+    for name in want:
+        np.testing.assert_allclose(got[name], want[name], rtol=1e-12)
+
+
+@pytest.fixture
+def small_adaptive(monkeypatch):
+    from igloo_tpu.exec.executor import Executor
+    monkeypatch.setattr(F, "ADAPTIVE_CAPACITY", 1 << 10)
+    monkeypatch.setattr(Executor, "_SPECULATIVE_JOIN_BUDGET", 1 << 10)
+
+
+def _sel_engine(dense: bool = False):
+    fact, dim = _sel_tables(dense)
+    e = QueryEngine()
+    e.register_table("fact", fact)
+    e.register_table("dim", dim)
+    return e, fact, dim
+
+
+def _compile_on(e: QueryEngine, sql: str):
+    """What the engine's next execution of `sql` would compile, its hints
+    included: (compiler, program key)."""
+    from igloo_tpu.exec.executor import Executor
+    comp = F.FusedCompiler(Executor(e._jit_cache, batch_cache=e.batch_cache))
+    _run, key, _meta = comp.compile(e.plan(sql))
+    return comp, key
+
+
+def _run_counting(e: QueryEngine, sql: str):
+    e.result_cache.clear()
+    tracing.reset_counters()
+    t = e.execute(sql)
+    return t, dict(tracing.counters())
+
+
+@pytest.mark.parametrize("sql", [_SEL_GLOBAL, _SEL_PROJECTED],
+                         ids=["filter_agg", "filter_project_agg"])
+def test_global_aggregate_declines_its_filters_compaction(small_adaptive,
+                                                          sql):
+    e, fact, dim = _sel_engine()
+    want = _sel_oracle(sql, fact, dim)
+    _, key0 = _compile_on(e, sql)
+    t, c = _run_counting(e, sql)
+    _same_answer(t, want)
+    assert c.get("jit.miss") == 1 and not c.get("fused.compact_declined")
+    # the filter's live count is recorded as before: 3 % of 5000 rows, a
+    # hint that WOULD compact 8192 lanes to 256
+    hints = {k[1]: v for k, v in e._jit_cache.items()
+             if isinstance(k, tuple) and k[0] == "nhint"}
+    [(hkey, live)] = hints.items()
+    assert hkey[0] == "filter" and live == want["c"][0] < 256
+
+    # second execution, the hint present: the same program, found again
+    t, c = _run_counting(e, sql)
+    _same_answer(t, want)
+    assert c.get("jit.hit") == 1 and not c.get("jit.miss")
+    assert c.get("fused.compact_declined") == 1
+    assert not c.get("fused.compact_repair")
+    comp, key = _compile_on(e, sql)
+    assert key == key0
+    assert not [fp for fp in comp.fps if fp[0] == "acompact"]
+    assert not [tag for tag in comp.flag_tags if tag[0] == "compact"]
+    assert comp.stat_keys == [hkey]
+
+
+@pytest.mark.parametrize("sql", [_SEL_GROUPED, _SEL_JOINED],
+                         ids=["grouped_aggregate", "join"])
+def test_other_consumers_still_get_a_compacted_filter(small_adaptive, sql):
+    e, fact, dim = _sel_engine()
+    want = _sel_oracle(sql, fact, dim)
+    t, c = _run_counting(e, sql)
+    _same_answer(t, want)
+    t, c = _run_counting(e, sql)
+    _same_answer(t, want)
+    assert c.get("jit.miss") == 1            # the hinted program
+    assert not c.get("fused.compact_declined")
+    comp, key = _compile_on(e, sql)
+    assert ("acompact", 256) in comp.fps
+    assert [tag[0] for tag in comp.flag_tags if tag[1][0] == "filter"] \
+        == ["compact"]
+    if sql == _SEL_GROUPED:
+        assert repr(key[1]) == _PARENT_COMPACTED_FPS
+
+
+def test_declined_filter_needs_no_repair_when_its_data_grows(small_adaptive):
+    e, fact, dim = _sel_engine()
+    sparse = _sel_oracle(_SEL_GLOBAL, fact, dim)
+    for _ in range(2):
+        t, c = _run_counting(e, _SEL_GLOBAL)
+    _same_answer(t, sparse)
+    # same capacity and bounds, 16 x the live rows: an adopted hint of 256
+    # lanes would overflow and pay a repair re-run; a declined one has
+    # nothing to repair
+    dense, _ = _sel_tables(dense=True)
+    e.register_table("fact", dense)
+    want = _sel_oracle(_SEL_GLOBAL, dense, dim)
+    assert want["c"][0] > 16 * sparse["c"][0]
+    t, c = _run_counting(e, _SEL_GLOBAL)
+    _same_answer(t, want)
+    assert not c.get("fused.compact_repair") and not c.get("jit.miss")
+    assert c.get("fused.compact_declined") == 1
+
+
+@pytest.mark.parametrize("sql,compacts", [
+    (_SEL_GLOBAL, 0), (_SEL_PROJECTED, 0), (_SEL_GROUPED, 1)],
+    ids=["filter_agg", "filter_project_agg", "grouped_aggregate"])
+def test_staged_executor_asks_the_same_predicate(small_adaptive, sql,
+                                                 compacts):
+    from igloo_tpu.exec.executor import Executor
+    e, fact, dim = _sel_engine()
+    want = _sel_oracle(sql, fact, dim)
+    plan = e.plan(sql)
+    for _ in range(2):      # first sight records the live count, then adopts
+        tracing.reset_counters()
+        ex = Executor(e._jit_cache, batch_cache=e.batch_cache)
+        _same_answer(ex._staged_to_arrow(plan), want)
+    assert tracing.counters().get("join.input_compact", 0) == compacts
+
+
+# sha1 of repr(key[1:5]) (node fingerprints, pool signature, marks, fetch
+# capacity) of the program each benchmark query settles on at SF 0.01 under
+# the lowered thresholds, taken on the parent commit (7da1175): q3's filters
+# feed joins and its aggregate is grouped, q1's aggregate is grouped, so
+# ISSUE 31 may move neither, and the persistent compile cache keeps hitting
+_PARENT_KEY_SHA1 = {"q3": "5a3fd21e3f0e89018e2d289fb5ac83fb72627444",
+                    "q1": "8a6131de9e9055fae4ad297a0de631e74f9a9bda"}
+
+
+@pytest.mark.parametrize("q", sorted(_PARENT_KEY_SHA1))
+def test_program_keys_of_the_bypass_queries_did_not_move(small_adaptive, q):
+    import hashlib
+    from igloo_tpu.bench.tpch import QUERIES, gen_tables, register_all
+    e = QueryEngine()
+    register_all(e, gen_tables(sf=0.01))
+    e.host_route_bytes = 0
+    for _ in range(3):                       # cold, hinted, steady
+        _t, c = _run_counting(e, QUERIES[q])
+    assert c.get("jit.hit") == 1 and not c.get("jit.miss")
+    assert not c.get("fused.compact_declined")
+    comp, key = _compile_on(e, QUERIES[q])
+    if q == "q3":
+        assert [fp for fp in comp.fps if fp[0] == "acompact"]
+    digest = hashlib.sha1(repr(key[1:5]).encode()).hexdigest()
+    assert digest == _PARENT_KEY_SHA1[q], repr(key[1:5])
+
+
+def test_chunked_global_partial_declines_too(small_adaptive, tmp_path):
+    # LocalChunkExecutor runs each chunk's partial aggregate through the same
+    # compiler: every chunk's global partial over a selective filter keeps
+    # its lanes, and no hinted second program is compiled for any of them
+    import pyarrow.parquet as pq
+    from igloo_tpu.connectors.parquet import ParquetTable
+    fact, dim = _sel_tables()
+    big = pa.concat_tables([fact] * 4)
+    path = str(tmp_path / "fact.parquet")
+    pq.write_table(big, path, row_group_size=2500)
+    e = QueryEngine(chunk_budget_bytes=1 << 16)
+    e.register_table("fact", ParquetTable(path))
+    want = _sel_oracle(_SEL_GLOBAL, big, dim)
+    for _ in range(3):      # the second execution settles the chunking
+        t, c = _run_counting(e, _SEL_GLOBAL)
+        _same_answer(t, want)
+    assert c.get("engine.chunked_route") and not c.get("jit.miss")
+    assert c.get("fused.compact_declined") == c.get("fused.execute") >= 2
