@@ -10,15 +10,20 @@ reach the engine:
   served   a CoordinatorServer + one Worker in this process on loopback,
            queried by a DistributedClient over Arrow Flight (--served).
 
+With a session phase goes one sorted probe join over made-up tables (float
+keys take no direct table; the default queries' joins are all direct): on a
+TPU its expand phase is the scatter + cummax scan, which no CPU run of the
+compilers takes.
+
 Every result is compared with the independent pandas oracle
 (bench/tpch_pandas.py) on the same tables: same rows in the query's order,
 strings/ints/dates equal, floats within REL_TOL. One JSON line per query
 says where it ran (tier, jit misses, compile-cache traffic, transfer bytes,
-pallas counters, peak HBM). The run FAILS if a query ran on the host tier,
-a Pallas compile-failure fallback fired, a `nofuse` sentinel was found
-armed, a served fragment did not execute on the worker, or JAX's first
-device is not a TPU — whatever else passed. Run it on the CPU at a small
---sf as a rehearsal: every phase runs, and it ends `"ok": false`.
+peak HBM). The run FAILS if a query ran on the host tier, a `nofuse`
+sentinel was found armed, a served fragment did not execute on the worker,
+or JAX's first device is not a TPU — whatever else passed. Run it on the
+CPU at a small --sf as a rehearsal: every phase runs, and it ends
+`"ok": false`.
 
 `--chips 4` runs ONLY the mesh tier: q1 and q3 row-sharded over a
 four-device mesh, and the same two with mesh=None on one device, both
@@ -49,7 +54,6 @@ REL_TOL = 1e-9
 
 TABLES = ("region", "nation", "supplier", "part", "partsupp", "customer",
           "orders", "lineitem")
-KERNELS = ("probe", "segagg", "gather", "match", "topk", "scatter")
 
 _failures: list = []
 
@@ -79,7 +83,7 @@ def rebuild_native() -> bool:
 
 def cache_state() -> dict:
     """The compile cache and the sidecar stores beside it, which change
-    which programs get compiled (exec/hints.py, exec/autotune.py)."""
+    which programs get compiled (exec/hints.py)."""
     from igloo_tpu import compile_cache
     d = compile_cache.active_dir()
     state = {"dir": d, "placed_by_JAX_COMPILATION_CACHE_DIR":
@@ -201,14 +205,6 @@ def summed(c0: dict, c1: dict) -> dict:
     return {k: c0.get(k, 0) + c1.get(k, 0) for k in set(c0) | set(c1)}
 
 
-def pallas_counters(delta: dict) -> dict:
-    return {k: v for k, v in delta.items() if k.startswith("pallas.") and v}
-
-
-def kernels_planned(delta: dict) -> set:
-    return {k for k in KERNELS if delta.get(f"pallas.{k}", 0)}
-
-
 def path_counters(delta: dict) -> dict:
     """Which executors and repair paths a query took (a fused program whose
     deferred flag fired re-runs staged, quietly — this is where it shows)."""
@@ -221,18 +217,10 @@ def device_checks(q: str, phase: str, counters: dict) -> None:
     """The run must have been on the device, on the path it claims."""
     check(not counters.get("engine.host_route", 0),
           f"{phase} {q}: ran on the host tier (engine.host_route)")
-    check(not counters.get("pallas.compile_fallback", 0),
-          f"{phase} {q}: pallas.compile_fallback fired")
     check(not counters.get("fused.nofuse_sentinel", 0)
           and not counters.get("fused.nofuse_armed", 0),
           f"{phase} {q}: a `nofuse` sentinel was found armed in nhints.json "
           "(an earlier process died inside this program's compile)")
-    from igloo_tpu.exec import dispatch
-    planned = kernels_planned(counters)
-    check(dispatch.mode() != "auto"
-          or planned <= dispatch.TPU_COMPILED_KERNELS,
-          f"{phase} {q}: `auto` planned {sorted(planned)}, not all of them "
-          "in dispatch.TPU_COMPILED_KERNELS")
 
 
 # --- phase 1: the in-process session -----------------------------------------
@@ -243,19 +231,17 @@ def run_session(stage_dir: str, frames: dict, queries: list) -> None:
     from igloo_tpu.bench.tpch_pandas import PANDAS_QUERIES
     from igloo_tpu.utils import stats
     engine = make_engine(stage_dir)
-    planned: set = set()
     for q in queries:
         (cold_s, res, c0), (second_s, res2, c1) = run_twice(engine, QUERIES[q])
         want = PANDAS_QUERIES[q](frames)
         err, problems = compare(res.table, want)
         err2, problems2 = compare(res2.table, want)
         both = summed(c0, c1)
-        planned |= kernels_planned(both)
         emit(phase="session", query=q, tier=[res.stats.tier, res2.stats.tier],
              rows=res.table.num_rows, cold_s=cold_s, second_s=second_s,
              h2d_bytes=[res.stats.h2d_bytes, res2.stats.h2d_bytes],
              d2h_bytes=[res.stats.d2h_bytes, res2.stats.d2h_bytes],
-             pallas=pallas_counters(both), path=path_counters(both),
+             path=path_counters(both),
              peak_hbm_bytes=stats.device_peak_hbm_bytes(),
              max_rel_err=max(err, err2),
              matches_oracle=not (problems or problems2),
@@ -266,10 +252,60 @@ def run_session(stage_dir: str, frames: dict, queries: list) -> None:
             check(st.tier == "device",
                   f"session {q}: tier {st.tier!r}, expected 'device'")
         device_checks(q, "session", both)
-    from igloo_tpu.exec import dispatch
-    check(not dispatch.TPU_COMPILED_KERNELS or dispatch.mode() != "auto"
-          or planned, "session: dispatch.TPU_COMPILED_KERNELS is not empty "
-          "but no kernel was dispatched")
+
+
+def run_sorted_join(seed: int) -> None:
+    """One `join_sorted` program through the session, against pandas."""
+    import jax
+    import numpy as np
+    import pandas as pd
+    import pyarrow as pa
+    from igloo_tpu.engine import QueryEngine
+    from igloo_tpu.exec import fused
+    rng = np.random.default_rng(seed)
+    n = 1 << 17
+    # half of the probe keys match nothing, 256 build keys come twice, both
+    # sides hold NULL keys
+    a = pa.table({"fk": pa.array(rng.integers(0, 4096, n) * 0.5,
+                                 mask=np.arange(n) % 97 == 0),
+                  "x": pa.array(np.arange(n), type=pa.int64())})
+    k = np.concatenate([np.arange(2048), np.arange(256)]) * 0.5
+    b = pa.table({"k": pa.array(k, mask=np.arange(len(k)) % 13 == 0),
+                  "v": pa.array(np.arange(len(k)) % 64, type=pa.int64())})
+    engine = QueryEngine()
+    engine.host_route_bytes = 0
+    engine.register_table("a", a)
+    engine.register_table("b", b)
+    sql = ("SELECT v, COUNT(*) AS c, SUM(x) AS sx FROM a JOIN b "
+           "ON a.fk = b.k GROUP BY v ORDER BY v")
+    (cold_s, res, c0), (second_s, res2, c1) = run_twice(engine, sql)
+    j = a.to_pandas().merge(b.to_pandas().dropna(subset=["k"]),
+                            left_on="fk", right_on="k")
+    want = j.groupby("v").agg(c=("x", "size"), sx=("x", "sum")) \
+        .reset_index().sort_values("v")
+    want = pd.DataFrame({"v": want.v, "c": want.c, "sx": want.sx})
+    _err, problems = compare(res.table, want)
+    _err2, problems2 = compare(res2.table, want)
+    comp = fused.FusedCompiler(engine._executor())
+    comp.compile(engine.plan(sql))
+    both = summed(c0, c1)
+    searched = bool(both.get("join.match_search", 0))
+    emit(phase="sorted_join", rows=res.table.num_rows, joined=len(j),
+         tier=[res.stats.tier, res2.stats.tier], cold_s=cold_s,
+         second_s=second_s, path=path_counters(both),
+         match_route="search" if searched else "scan",
+         matches_oracle=not (problems or problems2), **compile_counts(c0, c1))
+    for p in problems + problems2:
+        check(False, f"sorted_join: {p}")
+    check(any(fp[0] == "join_sorted" for fp in comp.fps),
+          "sorted_join: the plan holds no join_sorted node")
+    check(bool(both.get("fused.execute", 0))
+          and not both.get("join.speculation_overflow", 0),
+          "sorted_join: did not run as the fused program alone")
+    check(searched == (jax.default_backend() != "tpu"),
+          "sorted_join: the searchsorted route was planned on a TPU, or "
+          "the scan off it")
+    device_checks("sorted_join", "session", both)
 
 
 # --- phase 2: the served path ------------------------------------------------
@@ -340,7 +376,7 @@ def run_served(stage_dir: str, frames: dict, queries: list) -> None:
                 emit(phase="served", query=q, rows=runs[0][1].num_rows,
                      cold_s=runs[0][0], second_s=runs[1][0],
                      fragments=frag_lines, worker=wid,
-                     pallas=pallas_counters(both), path=path_counters(both),
+                     path=path_counters(both),
                      peak_hbm_bytes=stats.device_peak_hbm_bytes(),
                      max_rel_err=worst, matches_oracle=not problems,
                      **compile_counts(c0, c1))
@@ -492,6 +528,7 @@ def main(argv=None) -> int:
             session = [q for q in args.queries.split(",") if q]
             if session:
                 run_session(tmp, frames, session)
+                run_sorted_join(args.seed)
             served = [q for q in args.served.split(",") if q]
             if served:
                 run_served(tmp, frames, served)

@@ -1,10 +1,8 @@
 """Staging helpers + a single-query debug worker.
 
-The production sweep is igloo_tpu/bench/sweep.py (one process for ALL
-queries, so each table is uploaded once); bench.py orchestrates it with a
-stall watchdog. This module keeps the shared staging helpers (`ensure_staged`,
-`stage_dir`, `make_engine`) and a per-query CLI useful for isolating one
-query's behavior in a fresh process:
+The shared staging helpers (`ensure_staged`, `stage_dir`, `make_engine`;
+chip_smoke.py builds its session engine with the last) and a per-query CLI
+useful for isolating one query's behavior in a fresh process:
 
     python -m igloo_tpu.bench.runner q7 1 /tmp/igloo_bench_sf1 5
 

@@ -1,14 +1,14 @@
 """Single-threaded pandas implementations of all 22 TPC-H queries.
 
-These are the measured CPU baseline for bench.py — the stand-in for the
+These are the tests' and chip_smoke.py's reference — the stand-in for the
 reference's working single-node CPU path (DataFusion via QueryEngine::execute,
 /root/reference/crates/engine/src/lib.rs:54-57), which cannot be installed in
 this environment (no package egress; see BASELINE.md). Idiomatic, reasonably
 optimized pandas: vectorized masks, pre-projected merge inputs, no python row
 loops.
 
-Input frames use INT DAYS since epoch for date columns (bench.py converts once
-up front, outside the timed region, for both engines alike)."""
+Input frames use INT DAYS since epoch for date columns (callers convert once
+up front)."""
 from __future__ import annotations
 
 import datetime as _dt

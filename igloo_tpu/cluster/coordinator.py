@@ -381,7 +381,7 @@ class DistributedExecutor:
                           if f.bucket is not None}
         metrics["shuffle_buckets"] = len(shuffle_buckets)
         # the planner's per-join decision records (strategy / salt /
-        # adaptive_source), so sweep JSON and last_metrics show WHY this
+        # adaptive_source), so last_metrics shows WHY this
         # plan shape was chosen (docs/adaptive.md)
         metrics["adaptive"] = list(adaptive_info or ())
         try:
@@ -1261,7 +1261,7 @@ class CoordinatorServer(flight.FlightServerBase):
                  "priority": permit.priority, "demoted": 0,
                  "_plan_fp": plan_key,
                  # per-query out-of-core attribution, published in
-                 # last_metrics and the sweep JSON `oversized` block
+                 # last_metrics
                  "oversized": dict(planner.grace_info),
                  "topology": {"workers": len(live),
                               "devices": topo,
@@ -1454,9 +1454,6 @@ class CoordinatorServer(flight.FlightServerBase):
         if action.type == "compile_cache_put":
             # worker pushing a freshly compiled entry back to the cluster
             from igloo_tpu import compile_cache
-            from igloo_tpu.exec import autotune  # noqa: F401 -- the import
-            # registers the tuning-table merge hook, so a pushed
-            # autotune.json merges instead of first-writer-wins
             put = protocol.COMPILE_CACHE_PUT.parse(req)
             stored = compile_cache.write_entry(
                 put["name"], compile_cache.decode_entry(put["data"]))

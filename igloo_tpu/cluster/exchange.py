@@ -68,9 +68,7 @@ def _column_vals(col, typ) -> np.ndarray:
     strings hash their bytes via native hash64.c, numerics use a canonical
     int64/bit pattern). Nulls read as 0 — they only need a consistent ROUTE,
     equality semantics stay with the join that consumes the bucket. The
-    per-column avalanche (multiply + shift-xor) happens downstream so the
-    Pallas exchange-scatter kernel can consume these same lanes and stay
-    bit-identical to the numpy mix (exec/pallas_kernels.py hash_scatter)."""
+    per-column avalanche (multiply + shift-xor) happens downstream."""
     import pyarrow.compute as pc
 
     from igloo_tpu.exec.batch import hash64_bytes
@@ -124,52 +122,6 @@ def bucket_ids(table: pa.Table, key_indices: list[int],
     return ((h >> np.uint64(17)) % np.uint64(nbuckets)).astype(np.int64)
 
 
-# partition shapes whose Pallas scatter program failed to lower this process
-# (keyed by the plan's canonical (npad, nbuckets) — a host decision, so the
-# retry recompiles straight on the numpy path)
-_SCATTER_BANS: set = set()
-
-
-def _partition_arrays(table: pa.Table, key_indices: list[int],
-                      nbuckets: int):
-    """(bucket ids, stable order or None, unsalted counts or None) for a hash
-    partition. Routes through the Pallas exchange-scatter kernel when
-    dispatch plans it — per-key avalanche + combine + bucket counts fused in
-    one device pass over the canonical lanes, bit-identical to `bucket_ids`
-    (docs/kernels.md) — and falls back to the numpy mix otherwise (kernels
-    off, shapes out of range, no keys, or a prior lowering failure)."""
-    if key_indices:
-        try:
-            from igloo_tpu.exec import dispatch
-            plan = dispatch.plan_scatter(
-                table.num_rows, len(key_indices), nbuckets,
-                banned=_ban_key(table.num_rows, nbuckets) in _SCATTER_BANS)
-        except Exception:
-            plan = None
-        if plan is not None:
-            lanes = []
-            for i in key_indices:
-                col = table.column(i)
-                col = col.combine_chunks() \
-                    if isinstance(col, pa.ChunkedArray) else col
-                lanes.append(_column_vals(col, table.schema.field(i).type))
-            try:
-                return dispatch.exchange_scatter(plan, lanes)
-            except Exception:
-                if dispatch.compile_failure_raises():
-                    raise
-                # compile-failure rung: ban this shape class and take the
-                # numpy path (mirrors the executor's per-kernel rung)
-                _SCATTER_BANS.add((plan[1], plan[2]))
-                tracing.counter("pallas.compile_fallback")
-    return bucket_ids(table, key_indices, nbuckets), None, None
-
-
-def _ban_key(nrows: int, nbuckets: int):
-    from igloo_tpu.exec.capacity import canonical_capacity
-    return (canonical_capacity(nrows), nbuckets)
-
-
 def partition_table(table: pa.Table, key_indices: list[int],
                     nbuckets: int,
                     salt: Optional[tuple] = None) -> list[pa.Table]:
@@ -211,16 +163,13 @@ def salted_partition(table: pa.Table, key_indices: list[int], nbuckets: int,
     if table.num_rows == 0:
         return ([table.slice(0, 0) for _ in range(total)],
                 np.zeros(nbuckets, dtype=np.int64))
-    pid, dev_order, dev_counts = _partition_arrays(table, key_indices,
-                                                   nbuckets)
-    base_counts = (dev_counts if dev_counts is not None else
-                   np.bincount(pid, minlength=nbuckets)).astype(np.int64)
+    pid = bucket_ids(table, key_indices, nbuckets)
+    base_counts = np.bincount(pid, minlength=nbuckets).astype(np.int64)
     if extra and role == "probe":
         idx = np.nonzero(pid == hot)[0]
         r = np.arange(len(idx)) % (extra + 1)
         pid = pid.copy()
         pid[idx[r > 0]] = nbuckets + r[r > 0] - 1
-        dev_order = None  # salt rewrote the bucket lane: reorder on host
         tracing.counter("exchange.salted")
         tracing.counter("exchange.salted_rows", len(idx))
     elif extra and role == "build":
@@ -231,12 +180,9 @@ def salted_partition(table: pa.Table, key_indices: list[int], nbuckets: int,
             [pid] + [np.full(len(rep), nbuckets + j, dtype=pid.dtype)
                      for j in range(extra)])
         table = table.take(take)
-        dev_order = None  # replication lengthened the lane
         tracing.counter("exchange.salted")
         tracing.counter("exchange.salted_rows", len(rep) * extra)
-    order = dev_order if dev_order is not None \
-        else np.argsort(pid, kind="stable")
-    sorted_tbl = table.take(order)
+    sorted_tbl = table.take(np.argsort(pid, kind="stable"))
     counts = np.bincount(pid, minlength=total)
     out, off = [], 0
     for b in range(total):

@@ -232,7 +232,7 @@ class DistributedPlanner:
         from igloo_tpu.exec.hints import adaptive_enabled
         self.adaptive_enabled = adaptive_enabled()
         # per-join decision records, published into last_metrics["adaptive"]
-        # and the sweep JSON so every plan choice is attributable
+        # so every plan choice is attributable
         self.adaptive_info: list[dict] = []
         # distributed out-of-core (docs/out_of_core.md): with a per-host
         # budget, an over-budget join tree fragments into per-GRACE-partition

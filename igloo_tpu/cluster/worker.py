@@ -604,10 +604,6 @@ class Worker:
         # (e.g. bigger than the transport's message cap) is given up on after
         # a few beats instead of starving every entry that sorts after it
         self._push_failures: dict = {}
-        # merge-named entries (the autotune tuning table) re-push whenever
-        # their on-disk (size, mtime) moved past the last confirmed push —
-        # unlike immutable XLA entries, "pushed once" is not "done"
-        self._merge_pushed: dict = {}
 
     @property
     def address(self) -> str:
@@ -678,8 +674,6 @@ class Worker:
         setting = info.get("setting")
         if setting is not None and "IGLOO_TPU_COMPILE_CACHE" not in os.environ:
             compile_cache.configure(setting)
-        from igloo_tpu.exec import autotune  # noqa: F401 -- registers the
-        # tuning-table merge hook before any entry lands via write_entry
         local = set(compile_cache.entry_names())
         remote = list(info.get("entries") or ())
         # only REMOTE names are "known to the coordinator": local entries the
@@ -688,10 +682,7 @@ class Worker:
         self._cache_known = set(remote)
         if compile_cache.active_dir() is None:
             return
-        # merge-named entries (the autotune tuning table) re-pull even when
-        # present locally: their content evolves, and write_entry merges
-        merge = compile_cache.merge_names()
-        missing = [n for n in remote if n not in local or n in merge]
+        missing = [n for n in remote if n not in local]
         if not missing:
             return
         # pull in a DAEMON thread: a mature cluster's cache is hundreds of
@@ -746,18 +737,9 @@ class Worker:
         from igloo_tpu import compile_cache
         # only STABLE entries ship: XLA writes cache files non-atomically,
         # and a truncated blob pushed once would pin itself cluster-wide
-        merge = compile_cache.merge_names()
         stable = compile_cache.entry_names(
             min_age_s=compile_cache.TRANSFER_MIN_AGE_S)
         candidates = [n for n in stable if n not in self._cache_known]
-        merge_sigs = {}
-        for name in stable:
-            if name not in merge or name in candidates:
-                continue
-            sig = compile_cache.entry_stat(name)
-            if sig is not None and self._merge_pushed.get(name) != sig:
-                merge_sigs[name] = sig
-                candidates.append(name)
         if not candidates:
             return
         # one connection for the whole beat: a cold bench run leaves dozens
@@ -793,8 +775,6 @@ class Worker:
                     tracing.counter("compile_cache.push")
                     pushed += 1
                     self._push_failures.pop(name, None)
-                    if name in merge_sigs:
-                        self._merge_pushed[name] = merge_sigs[name]
                 else:
                     self._note_push_failure(name)
         except Exception:

@@ -55,25 +55,6 @@ _SAFE_NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9._-]*$")
 # has merge semantics of its own — never ship it as a cache entry
 _EXCLUDE = {"nhints.json"}
 
-# MUTABLE sidecar entries (the autotune tuning table): unlike XLA programs,
-# same name does NOT imply same bytes, so write_entry routes them through a
-# registered merge hook instead of first-writer-wins. Each value is
-# (merge_fn(existing_bytes_or_None, incoming_bytes) -> bytes,
-#  on_written_fn_or_None) — see exec/autotune.register_with_compile_cache.
-_MERGE_HOOKS: dict = {}
-
-
-def register_merge(name: str, merge_fn, on_written=None) -> None:
-    """Register merge semantics for a mutable entry name (idempotent)."""
-    _MERGE_HOOKS[name] = (merge_fn, on_written)
-
-
-def merge_names() -> frozenset:
-    """Entry names with registered merge semantics — the cluster transfer
-    always re-pulls/re-pushes these (their content evolves), where immutable
-    XLA entries ship at most once."""
-    return frozenset(_MERGE_HOOKS)
-
 # refuse to read/accept pathological blobs (largest observed TPU entries are
 # tens of MB; anything bigger is a bug or an attack, not a cache entry)
 MAX_ENTRY_BYTES = 256 << 20
@@ -236,20 +217,6 @@ def _entry_path(name: str, cache_dir: Optional[str]) -> Optional[str]:
     return os.path.join(d, name)
 
 
-def entry_stat(name: str,
-               cache_dir: Optional[str] = None) -> Optional[tuple]:
-    """(size, mtime) of an entry file, or None — the change signature the
-    cluster transfer uses to re-push mutable merge-named entries."""
-    p = _entry_path(name, cache_dir)
-    if p is None or not os.path.isfile(p):
-        return None
-    try:
-        st = os.stat(p)
-    except OSError:
-        return None
-    return (st.st_size, st.st_mtime)
-
-
 def read_entry(name: str, cache_dir: Optional[str] = None) -> Optional[bytes]:
     """Entry bytes by filename, or None (unknown name, unsafe name, no
     cache). Oversized entries read as None rather than shipping gigabytes;
@@ -274,29 +241,15 @@ def write_entry(name: str, data: bytes,
     An existing file of the SAME size is kept (same key ⇒ same bytes); a
     SIZE MISMATCH is overwritten — it can only be an abandoned partial
     write from a killed process, and skipping it would pin the truncated
-    blob cluster-wide with no repair path.
-
-    Names with a registered merge hook (mutable sidecars, e.g. the autotune
-    tuning table) skip the same-size shortcut entirely: the hook merges the
-    incoming bytes with the existing file and its result is what lands."""
+    blob cluster-wide with no repair path."""
     p = _entry_path(name, cache_dir)
     if p is None or not data or len(data) > MAX_ENTRY_BYTES:
         return False
-    hook = _MERGE_HOOKS.get(name)
-    if hook is not None:
-        try:
-            existing = read_entry(name, cache_dir)
-            data = hook[0](existing, data)
-        except Exception:
-            return False
-        if not data or len(data) > MAX_ENTRY_BYTES:
-            return False
-    else:
-        try:
-            if os.path.getsize(p) == len(data):
-                return True
-        except OSError:
-            pass
+    try:
+        if os.path.getsize(p) == len(data):
+            return True
+    except OSError:
+        pass
     import tempfile
     try:
         os.makedirs(os.path.dirname(p), exist_ok=True)
@@ -306,11 +259,6 @@ def write_entry(name: str, data: bytes,
         os.replace(tmp, p)
     except OSError:
         return False
-    if hook is not None and hook[1] is not None:
-        try:
-            hook[1]()
-        except Exception:
-            pass
     return True
 
 

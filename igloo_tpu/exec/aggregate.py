@@ -22,10 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from igloo_tpu import types as T
-from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
-from igloo_tpu.exec.dispatch import DIRECT_SEG_SMALL_LIMIT
 from igloo_tpu.exec.expr_compile import Compiled, Env
 from igloo_tpu.plan import logical as L
 from igloo_tpu.plan.expr import AggFunc
@@ -55,6 +53,10 @@ def minmax_order_arg(func: AggFunc, arg: Optional[Compiled],
     return rank_lane(arg, comp)
 
 
+#: the direct-scatter aggregate's "small segment space" bound: at or under
+#: this many segments seg_dims_for scatters unconditionally; above it the
+#: scatter path needs few aggregates and a segment space near the batch's
+DIRECT_SEG_SMALL_LIMIT = 1 << 16
 _DENSE_INT_SEG_LIMIT = 1 << 23
 
 
@@ -98,9 +100,7 @@ def seg_dims_for(groups: list[Compiled],
         # small (AVG = sum+count = 2 scatters) AND the segment space does not
         # dwarf the batch (bounds are GLOBAL scan stats — a filtered 64K-lane
         # batch grouping by a 6M-wide key must keep the sort path, not
-        # allocate 8M-segment outputs). The threshold is shared with the
-        # Pallas dispatch layer's hash-agg table bound (exec/dispatch.py)
-        # so the two eligibility checks cannot drift.
+        # allocate 8M-segment outputs).
         if n_aggs is None or n_aggs > 2 or prod > _DENSE_INT_SEG_LIMIT:
             return None
         if input_capacity is None or prod > 2 * input_capacity:
@@ -113,8 +113,7 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
                     aggs: list[AggSpec], out_schema: T.Schema,
                     consts: tuple = (),
                     seg_dims: Optional[tuple] = None,
-                    pack_spec: Optional[tuple] = None,
-                    pallas_agg: Optional[tuple] = None):
+                    pack_spec: Optional[tuple] = None):
     # seg_dims entries are (bucket_count, value_offset) pairs — see
     # seg_dims_for
     """Pure, jit-traceable: DeviceBatch -> DeviceBatch of one row per group.
@@ -127,10 +126,7 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
     multi-lane lex_argsort chain to a single sort pass — when every key packs
     (all-integer group-bys) the whole chain becomes one argsort, and a
     q18-shaped 5-key group-by with one float key sorts 3 lanes instead of
-    10+. `pallas_agg` (dispatch.plan_segagg, also a cache-key part; requires
-    a full-cover pack_spec) replaces the sort entirely with the one-pass
-    Pallas hash aggregation — the return value is then (DeviceBatch,
-    overflow flag) instead of a bare DeviceBatch."""
+    10+."""
     env = Env.from_batch(batch, consts)
     cap = batch.capacity
     live = batch.live
@@ -149,12 +145,6 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
     if seg_dims is not None and len(seg_dims) == len(groups):
         return _direct_aggregate(env, groups, gvals, gnulls, aggs, out_schema,
                                  live, seg_dims)
-
-    if pallas_agg is not None and pack_spec is not None and \
-            len(pack_spec[1]) == len(groups):
-        return _pallas_hash_aggregate(env, groups, gvals, gnulls, aggs,
-                                      out_schema, live, pack_spec,
-                                      pallas_agg, consts)
 
     # sort path. With a pack_spec, the indexed keys fuse into ONE packed lane
     # (NULL is a digit, so no separate null lanes for them). Grouping never
@@ -510,143 +500,6 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
                 for c in out_cols]
     out_live = jnp.arange(nseg, dtype=jnp.int32) < n_groups
     return DeviceBatch(out_schema, out_cols, out_live)
-
-
-def _pallas_hash_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
-                           aggs: list[AggSpec], out_schema: T.Schema,
-                           live: jax.Array, pack_spec: tuple,
-                           pallas_agg: tuple, consts: tuple):
-    """Sort-free grouping for fully-packable keys via the one-pass Pallas
-    hash aggregation (exec/pallas_kernels.hash_segagg through the dispatch
-    layer): the packed lane is an exact group id, the kernel builds a
-    bounded hash table over it and accumulates every aggregate in the same
-    blocked pass over the input — no lex_argsort, no per-agg scatter.
-    Returns (DeviceBatch, overflow flag); a True flag (bucket exhaustion:
-    more distinct keys than table ways) means the caller must discard the
-    result and re-run the sort path (executor deferred-flag protocol).
-
-    Per-agg semantics mirror `_reduce_one` exactly: int sums accumulate in
-    int64 (wraparound cancels), float/AVG sums in float64 (accumulation
-    ORDER differs from the sorted segment reduction, so float totals may
-    differ in the last ulps), MIN/MAX reduce a comparable lane and gather
-    the ORIGINAL value at the first winning row position."""
-    spec, packed_idx = pack_spec
-    packed = K.pack_key_lane(spec, [gvals[i] for i in packed_idx],
-                             [gnulls[i] for i in packed_idx], consts)
-    cap = live.shape[0]
-
-    ops: list = []
-    op_inputs: list = []
-    per_spec: list = []  # post-kernel assembly recipe per AggSpec
-
-    def add_op(op, *arrays):
-        ops.append(op)
-        op_inputs.extend(arrays)
-
-    for a in aggs:
-        if a.func is AggFunc.COUNT_STAR:
-            per_spec.append(("count_star",))
-            continue
-        v, nl = a.arg.fn(env)
-        valid = live if nl is None else (live & ~nl)
-        if nl is None:
-            # null-free arg: its valid-count IS the kernel's built-in
-            # live-count table — skip the redundant count op (ci=None)
-            ci = None
-        else:
-            ci = len(ops)
-            add_op("count", valid)
-        if a.func is AggFunc.COUNT:
-            per_spec.append(("count", ci))
-            continue
-        if a.func in (AggFunc.SUM, AggFunc.AVG):
-            acc_dtype = jnp.float64 if (a.out_dtype.is_float or
-                                        a.func is AggFunc.AVG) else jnp.int64
-            sval = jnp.where(valid, v.astype(acc_dtype),
-                             jnp.zeros((), acc_dtype))
-            si = len(ops)
-            add_op("sum", valid, sval)
-            per_spec.append(("avg" if a.func is AggFunc.AVG else "sum",
-                             ci, si))
-            continue
-        # MIN / MAX: comparable lane like _reduce_one; the kernel tracks the
-        # first winning row position for the exact original-value gather
-        cmp_src = v
-        if a.order_arg is not None:
-            cmp_src, _ = a.order_arg.fn(env)
-        if a.arg.dtype.is_float:
-            vnorm, nan = K.normalize_float(cmp_src)
-            lane = jnp.where(nan, jnp.asarray(jnp.inf, vnorm.dtype), vnorm)
-        else:
-            lane = cmp_src.astype(jnp.int64)
-        mi = len(ops)
-        add_op("min" if a.func is AggFunc.MIN else "max", valid, lane)
-        per_spec.append(("minmax", ci, mi, v))
-
-    # per-op output-table offsets (count/sum: 1 table; min/max: value + pos)
-    op_out = []
-    oi = 0
-    for op in ops:
-        op_out.append(oi)
-        oi += 2 if op in ("min", "max") else 1
-
-    key_table, live_cnt, tables, ovf = dispatch.segagg(
-        pallas_agg, packed, live, tuple(ops), op_inputs)
-    nseg = key_table.shape[0]
-    group_mask = key_table != dispatch.EMPTY_KEY
-
-    # group key columns decode from the stored packed key (pack_key_lane's
-    # all-ascending nulls-first encoding is invertible; offsets ride consts).
-    # Digit j belongs to groups[packed_idx[j]] — identity for a full-cover
-    # pack, but realign explicitly.
-    dvals, dnulls = K.unpack_key_digits(spec, key_table, consts)
-    kvals = [None] * len(groups)
-    knulls = [None] * len(groups)
-    for j, i in enumerate(packed_idx):
-        kvals[i], knulls[i] = dvals[j], dnulls[j]
-    out_cols: list[DeviceColumn] = []
-    for v, nl_flag, g, nl in zip(kvals, knulls, groups, gnulls):
-        out_cols.append(DeviceColumn(
-            g.dtype, v.astype(g.dtype.device_dtype()),
-            nl_flag if nl is not None else None, g.out_dict))
-
-    def n_valid_of(ci):
-        return live_cnt if ci is None else tables[op_out[ci]]
-
-    for a, rec in zip(aggs, per_spec):
-        if rec[0] == "count_star":
-            out_cols.append(DeviceColumn(T.INT64, live_cnt, None, None))
-            continue
-        n_valid = n_valid_of(rec[1])
-        all_null = n_valid == 0
-        if rec[0] == "count":
-            out_cols.append(DeviceColumn(T.INT64, n_valid, None, None))
-        elif rec[0] == "sum":
-            total = tables[op_out[rec[2]]]
-            out_cols.append(DeviceColumn(
-                a.out_dtype, total.astype(a.out_dtype.device_dtype()),
-                all_null, None))
-        elif rec[0] == "avg":
-            total = tables[op_out[rec[2]]]
-            denom = jnp.where(all_null, 1, n_valid).astype(jnp.float64)
-            out_cols.append(DeviceColumn(T.FLOAT64, total / denom,
-                                         all_null, None))
-        else:  # minmax: exact original value at the first winning position
-            best_pos = tables[op_out[rec[2]] + 1]
-            out_val = jnp.take(rec[3], jnp.clip(best_pos, 0, cap - 1))
-            out_cols.append(DeviceColumn(a.out_dtype, out_val, all_null,
-                                         a.out_dict))
-
-    # compact live groups to the front (slot order; aggregate output row
-    # order is not semantic)
-    perm_small = K.compact_perm(group_mask)
-    n_groups = jnp.sum(group_mask.astype(jnp.int32))
-    out_cols = [DeviceColumn(c.dtype, jnp.take(c.values, perm_small),
-                             jnp.take(c.nulls, perm_small)
-                             if c.nulls is not None else None, c.dictionary)
-                for c in out_cols]
-    out_live = jnp.arange(nseg, dtype=jnp.int32) < n_groups
-    return DeviceBatch(out_schema, out_cols, out_live), ovf
 
 
 def distinct_batch(batch: DeviceBatch) -> DeviceBatch:

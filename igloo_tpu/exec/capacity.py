@@ -4,9 +4,11 @@ Every static-shaped buffer in the engine (scan batches, intermediate
 compactions, aggregate output segments, exchange buckets, direct-join
 positional tables) is padded to a *canonical capacity* so that XLA programs
 are keyed by a SMALL family of shapes instead of one shape per cardinality.
-BENCH_r05 measured 12-31 s cold compiles per query against 0.08-1.2 s warm —
-for ad-hoc traffic, compilation IS the latency, so the shape family is sized
-for program reuse first and padding waste second:
+A process without its compile cache answers its first q3 after four minutes
+and every later one in a fraction of a second (PERF_LEDGER.jsonl,
+`first_setup_s` against `setup_s`) — for ad-hoc traffic, compilation IS the
+latency, so the shape family is sized for program reuse first and padding
+waste second:
 
 - **small band** (n <= 2^16): exact power-of-two buckets. Programs here
   compile in well under a second, and tight padding matters more than
@@ -117,28 +119,6 @@ def capacity_family(limit: int) -> list:
         out.append(c)
         c <<= 1
     return out
-
-
-def pow2_block(n: int, cap: int) -> int:
-    """Largest power of two <= min(n, cap) (>= 1). The Pallas kernels
-    (exec/dispatch.py) derive their grid block sizes through this: every
-    canonical capacity is a power of two, so blocks chosen here always
-    divide the padded lane count exactly and kernel programs stay keyed by
-    the same small shape family as the rest of the engine."""
-    b = 1
-    while b * 2 <= min(n, cap):
-        b <<= 1
-    return b
-
-
-def tuning_capacities(limit: int = COARSE_FLOOR) -> list:
-    """Representative family members for offline kernel sweeps
-    (exec/autotune.py, scripts/autotune_sweep.py): every member from a
-    quarter of the small-band ceiling up to `limit` — the shapes real
-    operand sets quantize to. Smaller capacities are skipped on purpose:
-    kernels there finish too fast for block/window choice to matter, and
-    every swept capacity costs a full candidate-grid benchmark."""
-    return [c for c in capacity_family(limit) if c >= COARSE_FLOOR // 4]
 
 
 def canonical_direct_table(lo: int, hi: int) -> tuple:

@@ -26,7 +26,6 @@ import pyarrow as pa
 
 from igloo_tpu import types as T
 from igloo_tpu.errors import ExecError, NotSupportedError, PlanError
-from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
@@ -41,10 +40,12 @@ from igloo_tpu.exec.expr_compile import (
 )
 from igloo_tpu.exec.join import (
     choose_direct_build, choose_match_capacity, direct_join_phase, expand_phase,
-    join_batches, make_key_hash_idxs, probe_phase,
+    join_batches, make_key_hash_idxs, match_by_search, probe_phase,
 )
 from igloo_tpu.exec.fused import FusedCompiler, FusionUnsupported
-from igloo_tpu.exec.sort_limit import limit_batch, sort_batch, topk_batch
+from igloo_tpu.exec.sort_limit import (
+    limit_batch, plan_topk, sort_batch, topk_batch,
+)
 from igloo_tpu.plan import expr as E
 from igloo_tpu.plan import logical as L
 from igloo_tpu.sql.ast import JoinType
@@ -215,7 +216,7 @@ class Executor:
         self._hints = hints  # Optional[HintStore] (persistent nhints)
         # ORDER BY + LIMIT fusion handshake (staged tier): _exec_limit sets
         # the hint before descending into its Sort child; _exec_sort consumes
-        # it (identity-matched on the plan node) when dispatch.plan_topk
+        # it (identity-matched on the plan node) when sort_limit.plan_topk
         # adopts, and raises _limit_taken so _exec_limit skips the mask pass
         self._limit_hint: Optional[tuple] = None
         self._limit_taken = False
@@ -229,11 +230,7 @@ class Executor:
 
     def _jitted(self, kind: str, fingerprint, build: Callable[[], Callable],
                 static_argnums=()) -> Callable:
-        # the Pallas dispatch token rides EVERY key: implicit dispatch
-        # decisions (the fused gather inside any traced fn) depend on the
-        # IGLOO_TPU_PALLAS mode, so a mid-process flip must never serve a
-        # program traced under the other mode
-        key = (kind, fingerprint, dispatch.cache_token())
+        key = (kind, fingerprint)
         fn = self._cache.get(key)
         if fn is None:
             tracing.counter("jit.miss")
@@ -265,7 +262,7 @@ class Executor:
             self._record_stats(stat_pairs, svals)
             fired = self._fired_deferred(deferred, vals)
             if fired:
-                return self._retry_copy(fired).execute(plan)
+                return self._exact_copy().execute(plan)
         return batch
 
     def _staged_hint(self, key) -> Optional[int]:
@@ -287,31 +284,13 @@ class Executor:
     def _record_fired_tag(self, tag) -> None:
         """Negative-cache + counter bookkeeping for ONE fired deferred flag —
         shared by the staged (_fired_deferred) and fused (_fused_run) tiers
-        so a tag kind can never gain handling in one and drift in the other
-        (the cross-tier ban-key lesson of this PR)."""
+        so a tag kind can never gain handling in one and drift in the other."""
         if tag[0] == "dup":
             # THIS side of the join proved to have duplicate keys — the
             # other side may still direct-join
             jfp_core, side = tag[1]
             self._cache[("nodirect", jfp_core, side)] = True
             tracing.counter("join.direct_dup_fallback")
-        elif tag[0] == "pallas_probe":
-            # probe window overflow: this join's build side carries longer
-            # duplicate-hash runs than the kernel scans — sort path from
-            # now on
-            self._cache[("nopallas_probe", tag[1])] = True
-            tracing.counter("pallas.probe_overflow")
-        elif tag[0] == "pallas_agg":
-            # hash-table bucket exhaustion: more distinct groups than the
-            # table holds — sort path from now on
-            self._cache[("nopallas_agg", tag[1])] = True
-            tracing.counter("pallas.agg_overflow")
-        elif tag[0] == "pallas_match":
-            # match-materialization window overflow: some probe row owns a
-            # longer match run than the kernel's window — scan path from
-            # now on
-            self._cache[("nopallas_match", tag[1])] = True
-            tracing.counter("pallas.match_overflow")
 
     def _fired_deferred(self, deferred, vals) -> list:
         """Check fetched deferred-flag values; returns the fired tags (empty
@@ -323,28 +302,9 @@ class Executor:
                 self._record_fired_tag(tag)
         return fired
 
-    def _retry_copy(self, fired_tags) -> "Executor":
-        """The executor to re-run a plan on after `fired_tags` fired. Any
-        speculative-family tag (capacity overflow, direct-join dup, semi
-        window, stale compaction) needs the exact copy. A Pallas-ONLY
-        fallback keeps speculation on: the negative caches just recorded
-        already route the failing op to the sort path, and the plan's
-        speculative joins were not at fault — disabling them would make the
-        repair run pay a count sync per join for nothing. (The sharded
-        tier never plans Pallas kernels, so its _exact_copy override is
-        always the path taken there.)"""
-        if any(t[0] not in ("pallas_probe", "pallas_agg", "pallas_match")
-               for t in fired_tags):
-            return self._exact_copy()
-        return Executor(self._cache, use_jit=self._use_jit,
-                        batch_cache=self._batch_cache,
-                        speculate=self._speculate, hints=self._hints)
-
     def _exact_copy(self) -> "Executor":
         """A sibling executor with speculation off (shares all caches); used to
-        re-run a plan after a deferred speculative-join overflow fired
-        (Pallas-only fallbacks take _retry_copy's speculation-preserving
-        sibling instead and never reach here)."""
+        re-run a plan after a deferred speculative-join overflow fired."""
         tracing.counter("join.speculation_overflow")
         return Executor(self._cache, use_jit=self._use_jit,
                         batch_cache=self._batch_cache, speculate=False,
@@ -425,16 +385,6 @@ class Executor:
             if first and self._hints is not None:
                 self._hints.remove(sentinel)
                 self._hints.flush()
-            if comp.pallas_bans and isinstance(e, Exception) \
-                    and not dispatch.compile_failure_raises():
-                # compile-failure rung: ban every Pallas plan this program
-                # contained and recompile on the sort path (an unrelated
-                # error re-raises from the Pallas-free program — the bans
-                # are then conservative, not wrong)
-                for bkey in comp.pallas_bans:
-                    self._cache[bkey] = True
-                tracing.counter("pallas.compile_fallback")
-                return self._fused_run(plan, _retry)
             raise
         if first and self._hints is not None:
             self._hints.remove(sentinel)
@@ -469,7 +419,7 @@ class Executor:
                 # stale cardinality hints only: repair with the fresh ones
                 tracing.counter("fused.compact_repair")
                 return self._fused_to_arrow(plan, _retry=False)
-            return self._retry_copy(fired).execute_to_arrow(plan)
+            return self._exact_copy().execute_to_arrow(plan)
         # result larger than the fetch window: exact compact + full fetch.
         # Clamp to the batch's own capacity (already a family member): the
         # live count can sit in the hysteresis band just under it, and an
@@ -504,7 +454,7 @@ class Executor:
             self._record_stats(stat_pairs, svals)
             fired = self._fired_deferred(deferred, flags)
             if fired:
-                return self._retry_copy(fired).execute_to_arrow(plan)
+                return self._exact_copy().execute_to_arrow(plan)
             return arrow_from_host(batch, host_live, host_vals, host_nulls,
                                    host_cargs)
         fp = ("spec_compact", batch_proto_key(batch), cap)
@@ -526,7 +476,7 @@ class Executor:
         self._record_stats(stat_pairs, svals)
         fired = self._fired_deferred(deferred, flags)
         if fired:
-            return self._retry_copy(fired).execute_to_arrow(plan)
+            return self._exact_copy().execute_to_arrow(plan)
         if int(host_n) <= cap:
             return arrow_from_host(spec, host_live, host_vals, host_nulls,
                                    host_cargs)
@@ -795,59 +745,21 @@ class Executor:
             pack_spec = K.plan_group_packing(groups, comp.pool)
             if pack_spec is not None:
                 tracing.counter("pack.agg")
-        # Pallas one-pass hash aggregation for the sort tier: needs a
-        # full-cover pack (the packed lane is then an exact group id); its
-        # table-overflow flag negative-caches this aggregate onto the sort
-        # path. A host decision -> part of the cache key.
-        pallas_agg = None
-        afp_core = ("agg", expr_fingerprint(gres + ares),
-                    tuple((a.func, a.dtype) for a in aggs))
-        if seg_dims is None and pack_spec is not None:
-            pallas_agg = dispatch.plan_segagg(
-                pack_spec, len(groups), batch.capacity,
-                banned=bool(self._cache.get(("nopallas_agg", afp_core))))
-        def agg_fn(pa):
-            fp = ("agg", expr_fingerprint(gres + ares),
-                  tuple((a.func, a.dtype) for a in aggs),
-                  batch_proto_key(batch), out_schema,
-                  comp.pool.signature(), tuple(comp.marks), seg_dims,
-                  pack_spec, pa)
+        fp = ("agg", expr_fingerprint(gres + ares),
+              tuple((a.func, a.dtype) for a in aggs),
+              batch_proto_key(batch), out_schema,
+              comp.pool.signature(), tuple(comp.marks), seg_dims, pack_spec)
 
-            def build():
-                def fn(b: DeviceBatch, consts):
-                    if pa is None:
-                        out = aggregate_batch(b, groups, specs, out_schema,
-                                              consts, seg_dims=seg_dims,
-                                              pack_spec=pack_spec)
-                        return out, jnp.zeros((), jnp.bool_)
-                    return aggregate_batch(b, groups, specs, out_schema,
-                                           consts, seg_dims=seg_dims,
-                                           pack_spec=pack_spec,
-                                           pallas_agg=pa)
-                return fn
-            return self._jitted("agg", fp, build)
-
-        try:
-            out, agg_ovf = agg_fn(pallas_agg)(strip_dicts(batch),
-                                              comp.pool.device_args())
-        except Exception:
-            if pallas_agg is None or dispatch.compile_failure_raises():
-                raise
-            # compile-failure rung (see _exec_join): sort path, negative
-            # cache, attributable
-            self._cache[("nopallas_agg", afp_core)] = True
-            tracing.counter("pallas.compile_fallback")
-            pallas_agg = None
-            out, agg_ovf = agg_fn(None)(strip_dicts(batch),
-                                        comp.pool.device_args())
+        def build():
+            def fn(b: DeviceBatch, consts):
+                return aggregate_batch(b, groups, specs, out_schema, consts,
+                                       seg_dims=seg_dims, pack_spec=pack_spec)
+            return fn
+        out = self._jitted("agg", fp, build)(strip_dicts(batch),
+                                             comp.pool.device_args())
         stats.annotate(strategy="direct_scatter" if seg_dims is not None
-                       else "pallas_segagg" if pallas_agg is not None
                        else "packed_sort" if pack_spec is not None
                        else "lex_sort")
-        if pallas_agg is not None:
-            stats.annotate(pallas="segagg")
-            self._deferred_overflow.append((("pallas_agg", afp_core),
-                                            agg_ovf))
         out = attach_dicts(out, [g.out_dict for g in groups] +
                            [s.out_dict for s in specs])
         return self._maybe_shrink(out)
@@ -1187,48 +1099,10 @@ class Executor:
                                 bnds[: len(out.columns)])
 
         stats.annotate(strategy="sorted_probe")
-        # Pallas hash-probe dispatch (docs/kernels.md): replaces the
-        # combined (m+n)-lane sort inside _probe_bounds; the kernel's
-        # overflow flag rides the deferred protocol and negative-caches
-        # this join onto the sort path when its build side proves to carry
-        # long duplicate-hash runs. The plan is a host decision -> part of
-        # the probe program's cache key.
-        pplan = None
-        if use_lk:
-            pplan = dispatch.plan_probe(
-                right.capacity, left.capacity,
-                banned=bool(self._cache.get(("nopallas_probe", jfp_core))))
-        def probe_fn(pp):
-            return self._jitted(
-                "join_probe", (fpbase, pp),
-                lambda: (lambda l, r, consts: probe_phase(
-                    l, r, use_lk, use_rk, lhx, rhx, consts, probe_plan=pp)))
-        def expand_fn(mp):
-            # the match plan rides the expand program's cache key (same rule
-            # as the probe plan above: host decisions key the trace)
-            return self._jitted(
-                "join_expand", (fpbase, plan.schema, mp),
-                lambda: (lambda l, r, p, match_cap, consts: expand_phase(
-                    l, r, p, match_cap, jt, residual, plan.schema, consts,
-                    match_plan=mp)),
-                static_argnums=(3,))
-
-        try:
-            p = probe_fn(pplan)(ls, rs, consts)
-        except Exception:
-            if pplan is None or dispatch.compile_failure_raises():
-                raise
-            # compile-failure rung: a Pallas program the backend cannot
-            # lower must fall back to the proven sort path, not fail the
-            # query (an unrelated error re-raises from the sort-path run)
-            self._cache[("nopallas_probe", jfp_core)] = True
-            tracing.counter("pallas.compile_fallback")
-            pplan = None
-            p = probe_fn(None)(ls, rs, consts)
-        if pplan is not None:
-            stats.annotate(pallas="probe")
-            self._deferred_overflow.append((("pallas_probe", jfp_core),
-                                            p.ovf))
+        p = self._jitted(
+            "join_probe", fpbase,
+            lambda: (lambda l, r, consts: probe_phase(
+                l, r, use_lk, use_rk, lhx, rhx, consts)))(ls, rs, consts)
         spec_cap = round_capacity(max(left.capacity, right.capacity))
         if (self._speculate and jt is not JoinType.CROSS
                 and spec_cap <= self._SPECULATIVE_JOIN_BUDGET):
@@ -1239,31 +1113,13 @@ class Executor:
         else:
             total = int(p.total)  # the one host sync
             match_cap = choose_match_capacity(total)
-        # Pallas match-materialization dispatch (docs/kernels.md): replaces
-        # the owner-scatter + associative-scan chain inside expand_phase;
-        # window overflow rides the deferred protocol like the probe kernel
-        mplan = dispatch.plan_match(
-            left.capacity, match_cap,
-            banned=bool(self._cache.get(("nopallas_match", jfp_core))))
-        try:
-            res = expand_fn(mplan)(ls, rs, p, match_cap, consts)
-        except Exception:
-            if mplan is None or mplan[1] != "kernel" \
-                    or dispatch.compile_failure_raises():
-                raise
-            self._cache[("nopallas_match", jfp_core)] = True
-            tracing.counter("pallas.compile_fallback")
-            mplan = dispatch.plan_match(left.capacity, match_cap, banned=True)
-            res = expand_fn(mplan)(ls, rs, p, match_cap, consts)
-        if mplan is not None:
-            out, movf = res
-            if mplan[1] == "kernel":
-                stats.annotate(
-                    pallas="probe+match" if pplan is not None else "match")
-                self._deferred_overflow.append((("pallas_match", jfp_core),
-                                                movf))
-        else:
-            out = res
+        search = match_by_search()
+        out = self._jitted(
+            "join_expand", (fpbase, plan.schema),
+            lambda: (lambda l, r, p, match_cap, consts: expand_phase(
+                l, r, p, match_cap, jt, residual, plan.schema, consts,
+                match_search=search)),
+            static_argnums=(3,))(ls, rs, p, match_cap, consts)
         out = attach_dicts(out, dicts[: len(out.columns)],
                            bnds[: len(out.columns)])
         if total is None:
@@ -1318,40 +1174,17 @@ class Executor:
             fp_core = (expr_fingerprint(res), tuple(plan.ascending),
                        tuple(plan.nulls_first), batch_proto_key(batch),
                        comp.pool.signature(), tuple(comp.marks), pack)
-            # ban key uses the FUSED compiler's topk core format so a fused
-            # compile failure's ban is visible here and vice versa
-            tfp_core = ("|".join(repr(e) for e in res),
-                        tuple(plan.ascending), tuple(plan.nulls_first))
-            tplan = dispatch.plan_topk(
-                batch.capacity, k_total,
-                pack is not None and pack[1] == len(keys),
-                banned=bool(self._cache.get(("nopallas_topk", tfp_core))))
-            if tplan is not None:
+            if plan_topk(batch.capacity, k_total, pack, len(keys)):
                 out_cap = round_capacity(k_total)
 
-                def tbuild(tp):
-                    def mk():
-                        def fn(b, consts):
-                            return topk_batch(b, keys, consts, pack, tp,
-                                              limit, offset, out_cap)
-                        return fn
-                    return self._jitted("topk", ("topk", fp_core, tp,
-                                                 limit, offset, out_cap), mk)
-                try:
-                    out = tbuild(tplan)(strip_dicts(batch),
-                                        comp.pool.device_args())
-                except Exception:
-                    if tplan[1] != "pallas" \
-                            or dispatch.compile_failure_raises():
-                        raise
-                    self._cache[("nopallas_topk", tfp_core)] = True
-                    tracing.counter("pallas.compile_fallback")
-                    tplan = dispatch.plan_topk(
-                        batch.capacity, k_total,
-                        pack is not None and pack[1] == len(keys),
-                        banned=True)
-                    out = tbuild(tplan)(strip_dicts(batch),
-                                        comp.pool.device_args())
+                def tbuild():
+                    def fn(b, consts):
+                        return topk_batch(b, keys, consts, pack,
+                                          limit, offset, out_cap)
+                    return fn
+                out = self._jitted(
+                    "topk", ("topk", fp_core, limit, offset, out_cap),
+                    tbuild)(strip_dicts(batch), comp.pool.device_args())
                 self._limit_taken = True
                 return attach_dicts(out, *col_meta(batch.columns))
         fp = ("sort", expr_fingerprint(res), tuple(plan.ascending),

@@ -54,7 +54,6 @@ from typing import Callable, Optional
 import jax.numpy as jnp
 
 from igloo_tpu import types as T
-from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
@@ -68,9 +67,11 @@ from igloo_tpu.exec.expr_compile import (
 )
 from igloo_tpu.exec.join import (
     choose_direct_build, direct_join_phase, direct_probe, expand_phase,
-    make_key_hash_idxs, probe_phase,
+    make_key_hash_idxs, match_by_search, probe_phase,
 )
-from igloo_tpu.exec.sort_limit import limit_batch, sort_batch, topk_batch
+from igloo_tpu.exec.sort_limit import (
+    limit_batch, plan_topk, sort_batch, topk_batch,
+)
 from igloo_tpu.plan import logical as L
 from igloo_tpu.sql.ast import JoinType
 from igloo_tpu.utils import tracing
@@ -128,10 +129,6 @@ class FusedCompiler:
         self.hfps: list = []
         self.flag_tags: list = []   # flag id -> ("dup"|"overflow"|"compact", key)
         self.stat_keys: list = []   # stat id -> nhint cache key
-        # negative-cache keys of every Pallas kernel this program planned:
-        # the executor's compile-failure rung bans them all and recompiles
-        # on the sort path when the program fails to lower
-        self.pallas_bans: list = []
         # Filter nodes whose consumer asked them not to compact
         # (aggregate.uncompacted_filter): hints recorded, never adopted
         self.uncompacted: list = []
@@ -184,7 +181,7 @@ class FusedCompiler:
             return out, spec, n, ctx.flags, ctx.stats
 
         key = ("fused", tuple(self.fps), self.pool.signature(),
-               tuple(self.marks), fetch_cap, dispatch.cache_token())
+               tuple(self.marks), fetch_cap)
         return run, key, meta
 
     # --- dispatch ---------------------------------------------------------
@@ -409,53 +406,18 @@ class FusedCompiler:
                 out_cap += lmeta.capacity
             if jt in (JoinType.RIGHT, JoinType.FULL):
                 out_cap += rmeta.capacity
-        # Pallas hash-probe dispatch (docs/kernels.md): a host decision, so
-        # it joins the node fingerprint; the kernel's overflow flag rides
-        # the fused flag channel and negative-caches this join onto the
-        # sort path. The tag key uses the STAGED executor's jfp_core format
-        # ("|"-joined exprs + join type) so a fused overflow's ban is
-        # visible to the exact staged re-run and vice versa.
-        pfp_core = ("|".join(repr(e) for e in lres + rres + rres2), jt)
-        pplan = None
-        if use_lk:
-            pplan = dispatch.plan_probe(
-                rmeta.capacity, lmeta.capacity,
-                banned=bool(self.ex._cache.get(("nopallas_probe",
-                                                pfp_core))))
-        # Pallas match-materialization dispatch rides the same conventions:
-        # plan in the fingerprint, window overflow on the flag channel,
-        # staged-format ban key shared across tiers
-        mplan = dispatch.plan_match(
-            lmeta.capacity, spec_cap,
-            banned=bool(self.ex._cache.get(("nopallas_match", pfp_core))))
-        self._push(("join_sorted",) + jfp[1:] + (spec_cap, plan.schema,
-                                                 pplan, mplan),
+        self._push(("join_sorted",) + jfp[1:] + (spec_cap, plan.schema),
                    hint_fp=("join_sorted",) + jfp_core[1:] + (plan.schema,))
         fid = self._new_flag(("overflow", jfp))
-        pfid = None
-        if pplan is not None:
-            pfid = self._new_flag(("pallas_probe", pfp_core))
-            self.pallas_bans.append(("nopallas_probe", pfp_core))
-        mfid = None
-        if mplan is not None and mplan[1] == "kernel":
-            mfid = self._new_flag(("pallas_match", pfp_core))
-            self.pallas_bans.append(("nopallas_match", pfp_core))
+        search = match_by_search()
 
         def fn(leaves, consts, ctx):
             lb = lfn(leaves, consts, ctx)
             rb = rfn(leaves, consts, ctx)
-            p = probe_phase(lb, rb, use_lk, use_rk, lhx, rhx, consts,
-                            probe_plan=pplan)
+            p = probe_phase(lb, rb, use_lk, use_rk, lhx, rhx, consts)
             ctx.flags[fid] = p.total > spec_cap
-            if pfid is not None:
-                ctx.flags[pfid] = p.ovf
-            out = expand_phase(lb, rb, p, spec_cap, jt, residual,
-                               plan.schema, consts, match_plan=mplan)
-            if mplan is not None:
-                out, movf = out
-                if mfid is not None:
-                    ctx.flags[mfid] = movf
-            return out
+            return expand_phase(lb, rb, p, spec_cap, jt, residual,
+                                plan.schema, consts, match_search=search)
         return fn, NodeMeta(plan.schema, out_dicts, out_bounds, out_cap)
 
     def _c_join_direct(self, plan, jfp, jfp_core, pick, lfn, lmeta, rfn,
@@ -587,39 +549,16 @@ class FusedCompiler:
             pack_spec = K.plan_group_packing(groups, self.pool)
             if pack_spec is not None:
                 tracing.counter("pack.agg")
-        # Pallas one-pass hash aggregation (docs/kernels.md): full-cover
-        # pack required; the table-overflow flag rides the fused flag
-        # channel and negative-caches this aggregate onto the sort path.
-        # The tag key mirrors the staged executor's afp_core format so bans
-        # cross the fused/staged boundary.
-        afp_core = ("agg", "|".join(repr(e) for e in gres + ares),
-                    tuple((a.func, a.dtype) for a in plan.aggs))
-        pallas_agg = None
-        if seg_dims is None and pack_spec is not None:
-            pallas_agg = dispatch.plan_segagg(
-                pack_spec, len(groups), meta.capacity,
-                banned=bool(self.ex._cache.get(("nopallas_agg", afp_core))))
-        afid = None
-        if pallas_agg is not None:
-            afid = self._new_flag(("pallas_agg", afp_core))
-            self.pallas_bans.append(("nopallas_agg", afp_core))
-        self._push(("agg", tuple(repr(e) for e in gres + ares),
-                    tuple((a.func, a.dtype) for a in plan.aggs),
-                    plan.schema, seg_dims, pack_spec, pallas_agg))
+        fp = ("agg", tuple(repr(e) for e in gres + ares),
+              tuple((a.func, a.dtype) for a in plan.aggs),
+              plan.schema, seg_dims, pack_spec)
+        self._push(fp)
         out_schema = plan.schema
 
         def fn(leaves, consts, ctx):
-            b = cfn(leaves, consts, ctx)
-            if pallas_agg is None:
-                return aggregate_batch(b, groups, specs, out_schema, consts,
-                                       seg_dims=seg_dims,
-                                       pack_spec=pack_spec)
-            out, ovf = aggregate_batch(b, groups, specs, out_schema, consts,
-                                       seg_dims=seg_dims,
-                                       pack_spec=pack_spec,
-                                       pallas_agg=pallas_agg)
-            ctx.flags[afid] = ovf
-            return out
+            return aggregate_batch(cfn(leaves, consts, ctx), groups, specs,
+                                   out_schema, consts, seg_dims=seg_dims,
+                                   pack_spec=pack_spec)
         if not groups:
             cap = MIN_CAPACITY
         elif seg_dims is not None:
@@ -627,8 +566,6 @@ class FusedCompiler:
             for d, _off in seg_dims:
                 prod *= d
             cap = round_capacity(prod + 1)
-        elif pallas_agg is not None:
-            cap = dispatch.segagg_table_rows(pallas_agg)
         else:
             cap = meta.capacity
         out_meta = NodeMeta(out_schema,
@@ -697,9 +634,8 @@ class FusedCompiler:
         return fn, meta
 
     def _c_limit_sort(self, plan: L.Limit, sp: L.Sort):
-        """ORDER BY + LIMIT fusion (docs/kernels.md): dispatch.plan_topk
-        replaces the full argsort with a partial top-k when LIMIT + OFFSET
-        is small against the batch and the prefix packing covers every key.
+        """ORDER BY + LIMIT fusion: where sort_limit.plan_topk says so, a
+        partial top-k replaces the full argsort.
         The decline path pushes fingerprints BYTE-IDENTICAL to the unfused
         sort + limit pair, so program keys and hint keys never move when the
         plan says no."""
@@ -714,14 +650,7 @@ class FusedCompiler:
             tracing.counter("pack.sort")
         asc, nf = list(sp.ascending), list(sp.nulls_first)
         k_total = plan.limit + plan.offset
-        # ban key mirrors the staged executor's topk core (cross-tier rule)
-        tfp_core = ("|".join(repr(e) for e in res), tuple(sp.ascending),
-                    tuple(sp.nulls_first))
-        tplan = dispatch.plan_topk(
-            meta.capacity, k_total,
-            pack is not None and pack[1] == len(keys),
-            banned=bool(self.ex._cache.get(("nopallas_topk", tfp_core))))
-        if tplan is None:
+        if not plan_topk(meta.capacity, k_total, pack, len(keys)):
             self._push(("sort", tuple(repr(e) for e in res),
                         tuple(sp.ascending), tuple(sp.nulls_first), pack))
             self._push(("limit", plan.limit, plan.offset))
@@ -733,12 +662,10 @@ class FusedCompiler:
             return fn, meta
         out_cap = round_capacity(k_total)
         self._push(("topk", tuple(repr(e) for e in res),
-                    tuple(sp.ascending), tuple(sp.nulls_first), pack, tplan,
+                    tuple(sp.ascending), tuple(sp.nulls_first), pack,
                     plan.limit, plan.offset, out_cap))
-        if tplan[1] == "pallas":
-            self.pallas_bans.append(("nopallas_topk", tfp_core))
 
         def fn(leaves, consts, ctx):
             return topk_batch(cfn(leaves, consts, ctx), keys, consts, pack,
-                              tplan, plan.limit, plan.offset, out_cap)
+                              plan.limit, plan.offset, out_cap)
         return fn, NodeMeta(meta.schema, meta.dicts, meta.bounds, out_cap)

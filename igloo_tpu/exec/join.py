@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from igloo_tpu import types as T
-from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, round_capacity, wide_values,
@@ -58,11 +57,6 @@ class _Probe:
     total: jax.Array       # scalar int64
     l_lanes: list          # per-key _KeyLanes on left
     r_lanes: list          # per-key _KeyLanes on right
-    # Pallas probe overflow: some probe row's equal-hash run may extend past
-    # the kernel's scan window — the executor's deferred-flag protocol
-    # discards the result and re-runs the exact sort path. Always False on
-    # the sort path.
-    ovf: jax.Array = None  # scalar bool
 
 
 # pytree registration so _Probe/_KeyLanes cross jit boundaries (probe runs in one
@@ -75,7 +69,7 @@ jax.tree_util.register_pytree_node(
 jax.tree_util.register_pytree_node(
     _Probe,
     lambda p: ((p.perm_r, p.lower, p.counts, p.prefix, p.total,
-                p.l_lanes, p.r_lanes, p.ovf), None),
+                p.l_lanes, p.r_lanes), None),
     lambda aux, ch: _Probe(*ch),
 )
 
@@ -123,15 +117,9 @@ def _key_lanes(batch: DeviceBatch, keys: list[Compiled], hash_idxs: list,
 
 def probe_phase(left: DeviceBatch, right: DeviceBatch,
                 left_keys: list[Compiled], right_keys: list[Compiled],
-                l_hash_idxs=None, r_hash_idxs=None, consts: tuple = (),
-                probe_plan=None) -> _Probe:
-    """Jit-traceable. CROSS join = empty key lists (constant key).
-    `probe_plan` (dispatch.plan_probe, part of the caller's cache key)
-    routes the bounds search through the Pallas hash-probe kernel: the
-    combined (m+n)-lane stable sort of `_probe_bounds` is replaced by a
-    bucketed window scan over the build side's sorted hash lane — which the
-    phase already pays for as `perm_r` — with the kernel's overflow flag
-    surfaced as `_Probe.ovf` (deferred exact re-run)."""
+                l_hash_idxs=None, r_hash_idxs=None,
+                consts: tuple = ()) -> _Probe:
+    """Jit-traceable. CROSS join = empty key lists (constant key)."""
     cap_l, cap_r = left.capacity, right.capacity
     if l_hash_idxs is None:
         l_hash_idxs = [None] * len(left_keys)
@@ -161,18 +149,12 @@ def probe_phase(left: DeviceBatch, right: DeviceBatch,
     sort_key = jnp.where(right.live, r_hash, jnp.iinfo(jnp.int64).max)
     perm_r = jnp.argsort(sort_key, stable=True)
 
-    if probe_plan is not None and left_keys:
-        sorted_hash = jnp.take(sort_key, perm_r)
-        lower, upper, ovf = dispatch.probe_bounds(probe_plan, sorted_hash,
-                                                  l_hash)
-    else:
-        lower, upper = _probe_bounds(sort_key, l_hash)
-        ovf = jnp.zeros((), jnp.bool_)
+    lower, upper = _probe_bounds(sort_key, l_hash)
     counts = jnp.where(left.live, (upper - lower).astype(jnp.int64), 0)
     prefix = jnp.cumsum(counts) - counts
     total = jnp.sum(counts)
     return _Probe(perm_r, lower, counts.astype(jnp.int32),
-                  prefix.astype(jnp.int64), total, l_lanes, r_lanes, ovf)
+                  prefix.astype(jnp.int64), total, l_lanes, r_lanes)
 
 
 def _probe_bounds(build_key: jax.Array, probe_key: jax.Array):
@@ -334,36 +316,35 @@ def semi_anti_phase(left: DeviceBatch, right: DeviceBatch,
     return DeviceBatch(left.schema, left.columns, keep), truncated
 
 
+def match_by_search() -> bool:
+    """Host-side choice, made by the two compilers when they plan a sorted
+    probe join, of how `expand_phase` finds each slot's probe row: a
+    searchsorted inversion of the prefix lane everywhere but on a TPU, where
+    the scatter + cummax scan stands in (see there). A constant of the
+    process's backend, so it rides no cache key."""
+    if jax.default_backend() == "tpu":
+        return False
+    tracing.counter("join.match_search")
+    return True
+
+
 def expand_phase(left: DeviceBatch, right: DeviceBatch, p: _Probe,
                  match_cap: int, join_type: JoinType,
                  residual: Optional[Compiled],
                  out_schema: T.Schema, consts: tuple = (),
-                 match_plan=None):
+                 match_search: bool = False) -> DeviceBatch:
     """Jit-traceable (match_cap static). Builds the output batch.
-
-    `match_plan` (dispatch.plan_match, part of the caller's cache key)
-    routes slot-ownership materialization — the owner-scatter +
-    associative-scan chain below — through the Pallas match kernel (route
-    "kernel": one blocked pass with a bounded per-row window, overflow
-    deferred) or a searchsorted inversion (route "search": exact, the
-    algorithmic fast path for the non-Pallas tier). With a plan the return
-    value is ``(batch, match_ovf)`` — the aggregate_batch conditional-tuple
-    convention; route "search" never overflows."""
+    `match_search` (see `match_by_search`) picks the searchsorted inversion
+    over the default scatter + cummax scan for slot ownership."""
     cap_l = left.capacity
 
     # --- candidate expansion: slot j -> (probe row, j-th candidate) ---
     j = jnp.arange(match_cap, dtype=jnp.int64)
-    match_ovf = None
-    if match_plan is not None and match_plan[1] == "kernel":
-        owner, match_ovf = dispatch.match_table(match_plan, p.prefix,
-                                                p.counts, match_cap)
-        probe_idx = jnp.clip(owner, 0, cap_l - 1)
-    elif match_plan is not None:
-        # route "search": the prefix lane is sorted (cumsum), so the owner of
-        # slot j is the LAST row whose start is <= j — zero-count rows share
-        # their successor's start and lose the right-insertion tie to the
-        # true owner; stragglers die on the offset bound below
-        match_ovf = jnp.zeros((), jnp.bool_)
+    if match_search:
+        # the prefix lane is sorted (cumsum), so the owner of slot j is the
+        # LAST row whose start is <= j — zero-count rows share their
+        # successor's start and lose the right-insertion tie to the true
+        # owner; stragglers die on the offset bound below
         probe_idx = jnp.clip(
             jnp.searchsorted(p.prefix, j, side="right").astype(jnp.int32) - 1,
             0, cap_l - 1)
@@ -437,17 +418,12 @@ def expand_phase(left: DeviceBatch, right: DeviceBatch, p: _Probe,
         r_matched = jnp.zeros((right.capacity,), dtype=jnp.int32) \
             .at[r_idx].max(ok32, mode="drop") > 0
 
-    def _ret(b):
-        return b if match_plan is None else (b, match_ovf)
-
     if join_type is JoinType.SEMI:
-        return _ret(DeviceBatch(out_schema, left.columns,
-                                left.live & l_matched))
+        return DeviceBatch(out_schema, left.columns, left.live & l_matched)
     if join_type is JoinType.ANTI:
         # NOT IN null semantics live in the binder-built residual (binder.py
         # _rewrite_in_subquery), not here — plain anti is correct as-is
-        return _ret(DeviceBatch(out_schema, left.columns,
-                                left.live & ~l_matched))
+        return DeviceBatch(out_schema, left.columns, left.live & ~l_matched)
 
     # --- inner part: verified expanded rows, NOT compacted (live rows stay
     # mask-scattered across the match_cap slots; every downstream operator is
@@ -503,7 +479,7 @@ def expand_phase(left: DeviceBatch, right: DeviceBatch, p: _Probe,
                             if c.nulls is not None else None)
                     for c in out_cols]
         out_live = jnp.take(out_live, perm)
-    return _ret(DeviceBatch(out_schema, out_cols, out_live))
+    return DeviceBatch(out_schema, out_cols, out_live)
 
 
 def _null_cols(batch: DeviceBatch, cap: int) -> list[DeviceColumn]:

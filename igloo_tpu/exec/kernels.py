@@ -317,30 +317,6 @@ def pack_key_lane(spec: tuple, vals: list, nulls: list,
     return acc
 
 
-def unpack_key_digits(spec: tuple, packed: jax.Array, consts: tuple):
-    """Inverse of `pack_key_lane` for the all-ascending nulls-first encoding
-    `plan_group_packing` emits: packed int lane -> ([per-key value lanes],
-    [per-key null flags]). Digit 0 is NULL; otherwise value = digit - 1 +
-    offset. Used by the Pallas hash-aggregate path to decode group key
-    columns straight from the stored table keys (the packed lane is a
-    bijection of its digit string, so no first-occurrence scatter)."""
-    lane_tag, oidx, digits = spec
-    offsets = consts[oidx]
-    acc = packed.astype(jnp.int64)
-    strides = []
-    s = 1
-    for card, _asc, _nf in reversed(digits):
-        strides.append(s)
-        s *= card
-    strides.reverse()
-    vals, nulls = [], []
-    for i, ((card, _asc, _nf), st) in enumerate(zip(digits, strides)):
-        d = (acc // np.int64(st)) % np.int64(card)
-        nulls.append(d == 0)
-        vals.append(d - 1 + offsets[i])
-    return vals, nulls
-
-
 def packed_sort_key(packed: jax.Array, live: jax.Array) -> jax.Array:
     """Displace dead rows to the dtype max so one argsort orders live rows by
     key AND sorts dead rows last. Digits use at most 62 (int64) / 30 (int32)
@@ -424,35 +400,17 @@ def compact_perm(live: jax.Array) -> jax.Array:
     return jnp.argsort(~live, stable=True)
 
 
-def _gather_arrays(arrays: list, idx: jax.Array) -> list:
-    """All-lane gather through the Pallas dispatch layer: one fused kernel
-    materializing every output lane when the mode and shapes allow, one
-    jnp.take (XLA gather) per lane otherwise."""
-    from igloo_tpu.exec import dispatch
-    return dispatch.gather_columns(arrays, idx)
+def _take_column(c: DeviceColumn, idx: jax.Array) -> DeviceColumn:
+    # replace() keeps the carrier spec/arg: a row gather permutes carrier
+    # lanes as happily as wide ones (bounds dropped)
+    return replace(c, values=jnp.take(c.values, idx),
+                   nulls=jnp.take(c.nulls, idx) if c.nulls is not None
+                   else None, bounds=None)
 
 
 def apply_perm(batch: DeviceBatch, perm: jax.Array) -> DeviceBatch:
-    arrays = []
-    for c in batch.columns:
-        arrays.append(c.values)
-        if c.nulls is not None:
-            arrays.append(c.nulls)
-    arrays.append(batch.live)
-    out = _gather_arrays(arrays, perm)
-    cols = []
-    i = 0
-    for c in batch.columns:
-        vals = out[i]
-        i += 1
-        nulls = None
-        if c.nulls is not None:
-            nulls = out[i]
-            i += 1
-        # replace() keeps the carrier spec/arg: a row gather permutes carrier
-        # lanes as happily as wide ones (bounds dropped, as before)
-        cols.append(replace(c, values=vals, nulls=nulls, bounds=None))
-    return DeviceBatch(batch.schema, cols, out[i])
+    cols = [_take_column(c, perm) for c in batch.columns]
+    return DeviceBatch(batch.schema, cols, jnp.take(batch.live, perm))
 
 
 def gather_batch(batch: DeviceBatch, idx: jax.Array,
@@ -461,25 +419,13 @@ def gather_batch(batch: DeviceBatch, idx: jax.Array,
     """Gather rows of all columns by `idx`. When `null_pad` and valid is given,
     out-of-match rows become NULL (outer-join padding)."""
     safe = jnp.clip(idx, 0, batch.capacity - 1)
-    arrays = []
-    for c in batch.columns:
-        arrays.append(c.values)
-        if c.nulls is not None:
-            arrays.append(c.nulls)
-    out = _gather_arrays(arrays, safe)
     cols = []
-    i = 0
     for c in batch.columns:
-        vals = out[i]
-        i += 1
-        nulls = None
-        if c.nulls is not None:
-            nulls = out[i]
-            i += 1
+        g = _take_column(c, safe)
         if null_pad and valid is not None:
             pad = ~valid
-            nulls = pad if nulls is None else (nulls | pad)
-        cols.append(replace(c, values=vals, nulls=nulls, bounds=None))
+            g = replace(g, nulls=pad if g.nulls is None else (g.nulls | pad))
+        cols.append(g)
     return cols
 
 

@@ -13,12 +13,13 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
-from igloo_tpu.exec import dispatch
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import DeviceBatch
 from igloo_tpu.exec.expr_compile import Compiled, Env
+from igloo_tpu.utils import tracing
 
 
 def sort_batch(batch: DeviceBatch, keys: list[Compiled], ascending: list[bool],
@@ -51,17 +52,29 @@ def sort_batch(batch: DeviceBatch, keys: list[Compiled], ascending: list[bool],
     return K.apply_perm(batch, perm)
 
 
+def plan_topk(cap: int, k: int, pack: Optional[tuple], n_keys: int) -> bool:
+    """Host decision (the callers fold it into their cache keys): does a
+    LIMIT over ORDER BY take `topk_batch` in place of the full sort? `k` is
+    LIMIT + OFFSET. The prefix packing must cover EVERY sort key — one lane
+    then totally orders the rows, where a partial pack still needs the
+    lexicographic tiebreak sort — and the LIMIT must leave at least half the
+    batch out, or a partial top-k buys nothing over the direct sort."""
+    if k <= 0 or pack is None or pack[1] != n_keys or 2 * k > cap:
+        return False
+    tracing.counter("topk.alg")
+    return True
+
+
 def topk_batch(batch: DeviceBatch, keys: list[Compiled],
-               consts: tuple, pack: tuple, plan: tuple,
+               consts: tuple, pack: tuple,
                limit: int, offset: int, out_cap: int) -> DeviceBatch:
-    """Jit-traceable fused ORDER BY + LIMIT: a partial top-k over the fully
-    packed sort lane replaces the full argsort when LIMIT ≪ rows. `plan`
-    (dispatch.plan_topk, part of the caller's cache key) requires `pack` to
-    cover EVERY key, so one packed lane totally orders the rows — the
-    selected positions are the stable sort's first LIMIT+OFFSET, and the
-    output batch shrinks to `out_cap` (the LIMIT's capacity family member)
-    instead of carrying the input capacity with a mask. Rows are
-    bit-identical to ``sort_batch`` + ``limit_batch``."""
+    """Jit-traceable fused ORDER BY + LIMIT where `plan_topk` says so: a
+    `lax.top_k` over the fully packed sort lane replaces the full argsort.
+    Its ties are lowest-index-first, so the selected positions are the
+    stable sort's first LIMIT+OFFSET, and the output batch shrinks to
+    `out_cap` (the LIMIT's capacity family member) instead of carrying the
+    input capacity with a mask. Rows are bit-identical to ``sort_batch`` +
+    ``limit_batch``."""
     env = Env.from_batch(batch, consts)
     vals, nls = [], []
     for k in keys:
@@ -70,8 +83,9 @@ def topk_batch(batch: DeviceBatch, keys: list[Compiled],
         nls.append(nl)
     spec, _ = pack
     packed = K.pack_key_lane(spec, vals, nls, consts)
-    perm = dispatch.topk_perm(plan, K.packed_sort_key(packed, batch.live))
     k_total = limit + offset
+    perm = jax.lax.top_k(-K.packed_sort_key(packed, batch.live),
+                         k_total)[1].astype(jnp.int32)
     if out_cap > k_total:
         perm = jnp.concatenate(
             [perm, jnp.zeros((out_cap - k_total,), perm.dtype)])

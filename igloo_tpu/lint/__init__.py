@@ -40,11 +40,6 @@ visitors (docs/static_analysis.md has the rule catalog):
                       ``cluster/rpc.py`` — every Flight connection must run
                       under the RPC policy (deadlines, retry/backoff), or a
                       hung peer wedges the calling thread forever;
-- ``pallas-dispatch`` no ``exec/pallas_kernels`` import outside
-                      ``exec/dispatch.py`` — every Pallas kernel call must
-                      run under the dispatch layer (IGLOO_TPU_PALLAS flag,
-                      eligibility checks, overflow fallback ladder), or the
-                      kill switch stops being trustworthy;
 - ``wire-contract``   whole-program protocol conformance against the
                       declarative registry in ``cluster/protocol.py``: every
                       registry-tagged ``build``/``parse`` site's fields must
@@ -238,7 +233,6 @@ def default_checkers() -> list:
     from igloo_tpu.lint.jit_key import JitKeyChecker
     from igloo_tpu.lint.lock_discipline import LockDisciplineChecker
     from igloo_tpu.lint.metric_names import MetricNamesChecker
-    from igloo_tpu.lint.pallas_dispatch import PallasDispatchChecker
     from igloo_tpu.lint.rpc_policy import RpcPolicyChecker
     from igloo_tpu.lint.span_names import SpanNamesChecker
     from igloo_tpu.lint.sync_hazard import SyncHazardChecker
@@ -249,8 +243,7 @@ def default_checkers() -> list:
     return [SyncHazardChecker(), CacheKeyChecker(), JitKeyChecker(),
             LockDisciplineChecker(), MetricNamesChecker(),
             SpanNamesChecker(), EventNamesChecker(), RpcPolicyChecker(),
-            PallasDispatchChecker(), WireContractChecker(),
-            FlightActionsChecker(), EnvKnobsChecker(),
+            WireContractChecker(), FlightActionsChecker(), EnvKnobsChecker(),
             ThreadRolesChecker(), LockOrderChecker()]
 
 
@@ -313,7 +306,7 @@ def stale_allows(paths: Optional[list] = None,
     Checkers with their own whitelists report staleness the same way: a
     checker may expose ``stale_entries()`` returning Findings (rule
     ``stale-entry``) for whitelist rows that no longer match anything —
-    sync-hazard's ``CHOKE_POINTS``/``COLD_MODULES`` rows and
+    sync-hazard's ``CHOKE_POINTS`` rows and
     lock-discipline's ``_GUARDED_BY`` locks/names — so every suppression
     surface shrinks monotonically through one report."""
     if checkers is None:

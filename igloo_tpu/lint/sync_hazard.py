@@ -40,9 +40,7 @@ where the engine's helper-extraction idiom actually lives.
 
 Findings are errors unless the enclosing function is a documented choke
 point in ``CHOKE_POINTS`` (each entry carries its rationale; the table is
-rendered in docs/static_analysis.md), the module is a ``COLD_MODULES``
-entry (the autotuner's offline benchmark harness, where
-``block_until_ready`` IS the measurement), or the line carries a
+rendered in docs/static_analysis.md) or the line carries a
 ``# lint: allow(sync-hazard)`` suppression. Whitelist entries that match
 no sync site are reported as warnings — and as ``stale-entry`` findings
 under ``--stale-allows`` — so the whitelist shrinks monotonically.
@@ -67,7 +65,7 @@ HOT_PREFIXES = ("igloo_tpu/exec/", "igloo_tpu/parallel/")
 # only sync was the ``num_live()`` count primitive (`Executor._exec`,
 # `_adaptive_input`, `_maybe_shrink`, `ShardedExecutor._observed_live`)
 # are now covered by sanctioned routing through the `DeviceBatch.num_live`
-# entry itself, and the autotuner harness moved to COLD_MODULES.
+# entry itself.
 CHOKE_POINTS = {
     ("igloo_tpu/exec/batch.py", "DeviceBatch.num_live"):
         "THE count-sync primitive: one int readback; every call site "
@@ -92,28 +90,12 @@ CHOKE_POINTS = {
         "device_get; overflow pays an exact refetch).",
     ("igloo_tpu/exec/executor.py", "Executor._exec_join"):
         "non-speculative joins must size the expand capacity: one "
-        "candidate-total readback (int(p.total), now visible through the "
-        "probe_fn jit-closure) per join.",
+        "candidate-total readback (int(p.total)) per join.",
     ("igloo_tpu/exec/codec.py", "_scaled_decimal_ok_locked"):
         "one-time per-process canary: replays the scaled-decimal divide "
         "on device before trusting it (round-5 advisor item; the locked "
         "slow path of _scaled_decimal_ok — the lock-free fast read never "
         "syncs).",
-    ("igloo_tpu/exec/dispatch.py", "exchange_scatter"):
-        "the exchange partition is a HOST operation (Arrow table in, bucket "
-        "slices out): the kernel's bucket lane must come back to drive "
-        "table.take — one readback replacing the numpy hash+argsort it "
-        "displaced.",
-}
-
-# repo-relative path -> rationale: hot-tree modules that are WHOLLY off the
-# query hot path, where syncing is the point. Kept separate from
-# CHOKE_POINTS so per-function whitelisting stays the norm.
-COLD_MODULES = {
-    "igloo_tpu/exec/autotune.py":
-        "the autotuner's candidate benchmark harness: block_until_ready IS "
-        "the measurement (sweep mode / offline script only, never on a "
-        "query's hot path).",
 }
 
 _SOURCE_PREFIXES = ("jnp.", "jax.lax.", "jax.nn.", "jax.numpy.")
@@ -265,9 +247,6 @@ class _FunctionPass(ast.NodeVisitor):
         if key in CHOKE_POINTS:
             self.checker.used_choke_points.add(key)
             return
-        if self.mod.relpath in COLD_MODULES:
-            self.checker.used_cold_modules.add(self.mod.relpath)
-            return
         if not self.report:
             return
         self.checker.out.append(Finding(
@@ -386,7 +365,6 @@ class SyncHazardChecker(TwoPassChecker):
         super().__init__()
         self.out: list[Finding] = []
         self.used_choke_points: set = set()
-        self.used_cold_modules: set = set()
         self.warnings: list[str] = []
         self._stale: list[Finding] = []
 
@@ -413,7 +391,6 @@ class SyncHazardChecker(TwoPassChecker):
         self.warnings = []
         self._stale = []
         self.used_choke_points = set()
-        self.used_cold_modules = set()
         def_lines: dict = {}
         for rel in sorted(summaries):
             sm = summaries[rel]
@@ -432,15 +409,6 @@ class SyncHazardChecker(TwoPassChecker):
                     "stale-entry", path, def_lines.get((path, qual), 1),
                     f"CHOKE_POINTS entry `{qual}` matches no sync site — "
                     "remove it from igloo_tpu/lint/sync_hazard.py"))
-        for path in sorted(COLD_MODULES):
-            if path in linted and path not in self.used_cold_modules:
-                self.warnings.append(
-                    f"sync-hazard: COLD_MODULES entry {path} suppressed "
-                    "no sync site — stale entry?")
-                self._stale.append(Finding(
-                    "stale-entry", path, 1,
-                    "COLD_MODULES entry suppresses no sync site — remove "
-                    "it from igloo_tpu/lint/sync_hazard.py"))
         return self.out
 
     def stale_entries(self) -> list:

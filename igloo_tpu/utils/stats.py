@@ -11,7 +11,7 @@ thread-local read when no query is being collected.
 
 Two collection levels keep the hot path honest:
 
-- default (engine.execute / the bench sweep): wall times, free row counts
+- default (engine.execute): wall times, free row counts
   (host Arrow / numpy shapes), transfer bytes and counter deltas — NO device
   syncs are added, so overhead is a few microseconds per operator;
 - detail (EXPLAIN ANALYZE): per-operator ACTUAL row counts, which on the
@@ -444,7 +444,7 @@ def adopt(ctx: tuple):
     qs, node, cols, tctx = ctx
     if qs is None:
         # no stats collection, but the parent thread may still hold
-        # counter_delta collectors (bench sweep) — adopt those regardless
+        # counter_delta collectors — adopt those regardless
         with flight_recorder.adopt(tctx), tracing.adopt_collectors(cols):
             yield
         return

@@ -14,8 +14,7 @@ distributed join and asserts via `last_metrics` that BOTH levels engaged:
   local 2-device mesh), with rows identical to single-device execution.
 
 `--scaling` measures the same join at 1x1 / 1x2 / 2x1 / 2x2
-(workers x per-worker devices) and emits one JSON line (without `--json` it
-also merges the block into BENCH_DETAIL.json's `twolevel_scaling`). Wall
+(workers x per-worker devices) and emits one JSON line. Wall
 times on virtual CPU
 devices measure PLUMBING (dispatch, exchange, H2D resharding), not compute
 scaling — the block's value is the per-topology `mesh_devices`/fragment
@@ -219,7 +218,7 @@ def smoke() -> int:
     return 0
 
 
-def scaling(emit_json: bool) -> int:
+def scaling() -> int:
     orders, cust = _data()
     curve = []
     for hosts, devices in ((1, 1), (1, 2), (2, 1), (2, 2)):
@@ -236,22 +235,7 @@ def scaling(emit_json: bool) -> int:
              "note": "virtual CPU devices: times measure plumbing "
                      "(dispatch/exchange/resharding), not compute scaling",
              "curve": curve}
-    if emit_json:
-        print(json.dumps(block), flush=True)
-        return 0
-    # standalone run: merge into BENCH_DETAIL.json beside the sweep blocks
-    path = os.path.join(REPO, "BENCH_DETAIL.json")
-    detail = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                detail = json.load(f)
-        except Exception:
-            detail = {}
-    detail["twolevel_scaling"] = block
-    with open(path, "w") as f:
-        json.dump(detail, f, indent=1, sort_keys=True)
-    print(f"twolevel scaling: curve written to {path}")
+    print(json.dumps(block), flush=True)
     return 0
 
 
@@ -260,15 +244,12 @@ def main() -> int:
     ap.add_argument("--worker", metavar="COORD", default=None)
     ap.add_argument("--devices", type=int, default=2)
     ap.add_argument("--scaling", action="store_true")
-    ap.add_argument("--json", action="store_true",
-                    help="with --scaling: print the block as one JSON line "
-                         "instead of merging BENCH_DETAIL.json")
     args = ap.parse_args()
     if args.worker:
         return worker_main(args.worker, args.devices)
     _force_cpu(1)  # coordinator process: planning only, one device is fine
     if args.scaling:
-        return scaling(args.json)
+        return scaling()
     return smoke()
 
 

@@ -221,7 +221,7 @@ def test_reorder_greedy_then_observed_flip(monkeypatch):
     _assert_same(eng.execute(REORDER_SQL), want)
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 61.8-76.9 s alone on the CPU (PR 32)
 def test_q9_q18_reorder_equivalence(monkeypatch):
     """The acceptance shape: q9 (6-table chain) and q18 (chain above a semi
     join) produce identical results with the adaptive loop off, on its first

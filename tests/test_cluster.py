@@ -16,8 +16,6 @@ from igloo_tpu.cluster.worker import Worker
 from igloo_tpu.engine import QueryEngine
 from igloo_tpu.errors import IglooError
 
-pytestmark = pytest.mark.slow  # multi-process Flight clusters (~6 min)
-
 
 def _make_data(tmp_path):
     rng = np.random.default_rng(11)

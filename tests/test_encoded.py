@@ -361,7 +361,6 @@ def test_grace_tier_ab(ooc_parquet, monkeypatch):
 # --- 2-worker shuffle A/B (slow: spins two in-process clusters) --------------
 
 
-@pytest.mark.slow
 def test_shuffle_ab_two_workers(monkeypatch):
     """The fourth tier: a real 2-worker distributed join, encoded vs kill
     switch — identical rows, measurably fewer exchange bytes encoded."""

@@ -647,10 +647,9 @@ def test_heartbeat_logs_first_failure_once(cluster, capsys):
     assert w._hb_down is False
 
 
-# --- chaos soak (slow) -------------------------------------------------------
+# --- chaos soak --------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_chaos_soak_worker_kill_plus_action_errors(cluster):
     """Multi-fault soak: probabilistic execute_fragment errors under a
     seeded spec while a third worker dies mid-query — every query still

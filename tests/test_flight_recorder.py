@@ -1,12 +1,9 @@
 """Flight recorder: span identity, request-scope hygiene, cross-process
 trace stitching over a 2-worker in-process cluster, the GRACE prefetch
-overlap, exports (system.query_traces / trace action / IGLOO_TRACE_DIR),
-and the bench_gate regression gate."""
+overlap, and exports (system.query_traces / trace action /
+IGLOO_TRACE_DIR)."""
 import json
-import subprocess
-import sys
 import time
-from pathlib import Path
 
 import numpy as np
 import pyarrow as pa
@@ -19,8 +16,6 @@ from igloo_tpu.cluster.coordinator import CoordinatorServer
 from igloo_tpu.cluster.worker import Worker
 from igloo_tpu.engine import QueryEngine
 from igloo_tpu.utils import flight_recorder, stats, tracing
-
-REPO = Path(__file__).resolve().parent.parent
 
 
 # --- span identity + scope hygiene (no cluster needed) -----------------------
@@ -332,49 +327,3 @@ def test_grace_pipeline_prefetch_overlaps_compute(tmp_path):
     overlapping = sum(1 for a in pre for b in par
                       if a["t0"] < b["t1"] and b["t0"] < a["t1"])
     assert overlapping >= 1, "no prefetch span overlapped a compute span"
-
-
-# --- bench gate --------------------------------------------------------------
-
-
-def _gate(*args):
-    return subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "bench_gate.py"), *args],
-        capture_output=True, text=True, cwd=REPO)
-
-
-def test_bench_gate_passes_committed_baseline():
-    r = _gate()
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
-def test_bench_gate_selftest_trips_on_doctored_sweep():
-    r = _gate("--selftest")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "doctored sweep trips" in r.stdout
-
-
-def test_bench_gate_fails_doctored_candidate(tmp_path):
-    base = json.loads((REPO / "BENCH_BASELINE.json").read_text())
-    doctored = {"queries": {q: dict(rec,
-                                    warm_med_s=rec["warm_med_s"] * 3 + 1.0)
-                            for q, rec in base["queries"].items()}}
-    p = tmp_path / "doctored.json"
-    p.write_text(json.dumps(doctored))
-    r = _gate(str(p))
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout
-
-
-def test_bench_gate_counter_drift_fails(tmp_path):
-    base = {"queries": {"q1": {"warm_med_s": 1.0,
-                               "counters": {"jit.miss": 4}}},
-            "warm_tol": 1.6, "abs_slack_s": 0.08, "counter_tol": 1.5}
-    cand = {"queries": {"q1": {"warm_med_s": 1.0,
-                               "counters": {"jit.miss": 40}}}}
-    bp, cp = tmp_path / "base.json", tmp_path / "cand.json"
-    bp.write_text(json.dumps(base))
-    cp.write_text(json.dumps(cand))
-    r = _gate(str(cp), "--baseline", str(bp))
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "jit.miss" in r.stdout

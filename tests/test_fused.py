@@ -179,6 +179,24 @@ _PARENT_FPS = (
     "(('i32', 0, ((4, True, False),)), 1)))")
 
 
+def _sha1(obj) -> str:
+    import hashlib
+    return hashlib.sha1(repr(obj).encode()).hexdigest()
+
+
+def _with_the_plans_put_back(fps: tuple) -> tuple:
+    """Node fingerprints as PR 32's parent pushed them on this backend.
+    ISSUE 32 took the kernel plans out of them: an `agg` node lost its last
+    member (None wherever no kernel was planned), a `join_sorted` node its
+    last two (None, and the match table's route), a `topk` node the member
+    before its limit, and the key as a whole its sixth member (the mode
+    token). The literals and digests taken on earlier commits stay as they
+    were taken, and are compared with this."""
+    back = {"agg": (None,), "join_sorted": (None, ("match", "search"))}
+    assert not [fp for fp in fps if fp[0] == "topk"]
+    return tuple(fp + back.get(fp[0], ()) for fp in fps)
+
+
 def _compile_over(provider_cls, sql: str, names: tuple):
     """Compile `sql` over one `provider_cls` table per name (same schema and
     capacity, different content); returns the FusedCompiler and its key."""
@@ -216,7 +234,7 @@ def test_ordinary_scan_fingerprint_did_not_move():
     # the "k" column's content differs from the parent's sample ("a"): the
     # key is content-light, so the literal still holds
     _, key = _compile_over(MemTable, _KEY_SQL, ("facts",))
-    assert repr(key[1]) == _PARENT_FPS
+    assert repr(_with_the_plans_put_back(key[1])) == _PARENT_FPS
 
 
 # --- who decides that a hinted node compacts (ISSUE 31) ---
@@ -361,7 +379,8 @@ def test_other_consumers_still_get_a_compacted_filter(small_adaptive, sql):
     assert [tag[0] for tag in comp.flag_tags if tag[1][0] == "filter"] \
         == ["compact"]
     if sql == _SEL_GROUPED:
-        assert repr(key[1]) == _PARENT_COMPACTED_FPS
+        assert repr(_with_the_plans_put_back(key[1])) \
+            == _PARENT_COMPACTED_FPS
 
 
 def test_declined_filter_needs_no_repair_when_its_data_grows(small_adaptive):
@@ -401,16 +420,42 @@ def test_staged_executor_asks_the_same_predicate(small_adaptive, sql,
 
 # sha1 of repr(key[1:5]) (node fingerprints, pool signature, marks, fetch
 # capacity) of the program each benchmark query settles on at SF 0.01 under
-# the lowered thresholds, taken on the parent commit (7da1175): q3's filters
+# the lowered thresholds, taken on PR 31's parent (7da1175): q3's filters
 # feed joins and its aggregate is grouped, q1's aggregate is grouped, so
-# ISSUE 31 may move neither, and the persistent compile cache keeps hitting
-_PARENT_KEY_SHA1 = {"q3": "5a3fd21e3f0e89018e2d289fb5ac83fb72627444",
-                    "q1": "8a6131de9e9055fae4ad297a0de631e74f9a9bda"}
+# ISSUE 31 could move neither
+_PR31_KEY_SHA1 = {"q3": "5a3fd21e3f0e89018e2d289fb5ac83fb72627444",
+                  "q1": "8a6131de9e9055fae4ad297a0de631e74f9a9bda"}
+# the same on this tree (ISSUE 32): shorter by what _with_the_plans_put_back
+# names and by nothing else — put back by hand, they reproduce the digests
+# above (neither query has a `join_sorted` node at this size; the test
+# after this one does)
+_KEY_SHA1 = {"q3": "e918bd396dbe2dbcd1e6e176ed537b4f379182c6",
+             "q1": "75266a89e44023fecd60d83b31640f50b9819573"}
+# sha1 of repr(sorted(repr(k) for the engine's nhint keys)), on PR 32's
+# parent. Hint keys persist as digests (nhints.json): a key whose chain of
+# hint fingerprints holds no `agg` node did not move (all of q1's, and the
+# sorted join's below); one at or above an aggregate is shorter by that
+# node's trailing None and by nothing else, so a store re-learns those once
+_PARENT_HINT_KEYS_SHA1 = {"q3": "e8b017fcb68d0f7d344542008144f8d0a7f43c74",
+                          "q1": "869767d35bc752688baab0f0f86fa95f93b4c852"}
+_HINT_KEYS_SHA1 = {"q3": "873609c75d9e03e61055959ba060da8f750212a0",
+                   "q1": "869767d35bc752688baab0f0f86fa95f93b4c852"}
 
 
-@pytest.mark.parametrize("q", sorted(_PARENT_KEY_SHA1))
+def _hint_keys(e: QueryEngine, put_back: bool = False) -> list:
+    """The engine's hint keys, sorted reprs; `put_back` restores the
+    trailing None an `agg` node's hint fingerprint had on PR 32's parent."""
+    def walk(x):
+        if not isinstance(x, tuple):
+            return x
+        y = tuple(walk(m) for m in x)
+        return y + (None,) if put_back and len(y) == 6 and y[0] == "agg" else y
+    return sorted(repr(walk(k)) for k in e._jit_cache
+                  if isinstance(k, tuple) and k[0] == "nhint")
+
+
+@pytest.mark.parametrize("q", sorted(_KEY_SHA1))
 def test_program_keys_of_the_bypass_queries_did_not_move(small_adaptive, q):
-    import hashlib
     from igloo_tpu.bench.tpch import QUERIES, gen_tables, register_all
     e = QueryEngine()
     register_all(e, gen_tables(sf=0.01))
@@ -422,8 +467,130 @@ def test_program_keys_of_the_bypass_queries_did_not_move(small_adaptive, q):
     comp, key = _compile_on(e, QUERIES[q])
     if q == "q3":
         assert [fp for fp in comp.fps if fp[0] == "acompact"]
-    digest = hashlib.sha1(repr(key[1:5]).encode()).hexdigest()
-    assert digest == _PARENT_KEY_SHA1[q], repr(key[1:5])
+    assert len(key) == 5
+    assert _sha1(key[1:5]) == _KEY_SHA1[q], repr(key[1:5])
+    assert _sha1((_with_the_plans_put_back(key[1]),) + key[2:5]) \
+        == _PR31_KEY_SHA1[q]
+    assert _sha1(_hint_keys(e)) == _HINT_KEYS_SHA1[q]
+    assert _sha1(_hint_keys(e, put_back=True)) == _PARENT_HINT_KEYS_SHA1[q]
+
+
+def test_sorted_join_lost_its_plans_and_kept_its_hint_key(monkeypatch):
+    # a float key takes no direct table, so the join is `join_sorted`: on
+    # PR 32's parent sha1(repr(key[1:5])) of this program was ae2b2246...
+    # and the hint key of the join e23c51c7... — the second must not move,
+    # the first only by the members named above
+    monkeypatch.setattr(F, "ADAPTIVE_CAPACITY", 1 << 10)
+    rng = np.random.default_rng(5)
+    e = QueryEngine()
+    e.register_table("a", pa.table({
+        "fk": pa.array(rng.integers(0, 16, 5000) * 0.5, type=pa.float64()),
+        "x": pa.array(rng.random(5000), type=pa.float64())}))
+    e.register_table("b", pa.table({
+        "k": pa.array(np.arange(8) * 0.5, type=pa.float64()),
+        "v": pa.array(np.arange(8), type=pa.int64())}))
+    sql = ("SELECT v, COUNT(*) AS c FROM a JOIN b ON a.fk = b.k "
+           "GROUP BY v ORDER BY v")
+    for _ in range(2):
+        t, c = _run_counting(e, sql)
+    assert sum(t.to_pydict()["c"]) == int(
+        (e.execute("SELECT COUNT(*) AS n FROM a WHERE fk < 4")
+         .to_pydict()["n"][0]))
+    comp, key = _compile_on(e, sql)
+    [jfp] = [fp for fp in comp.fps if fp[0] == "join_sorted"]
+    # ends with the match capacity and the output schema: no plan rides
+    assert jfp[-2] == 8192 and type(jfp[-1]).__name__ == "Schema"
+    assert _sha1((_with_the_plans_put_back(key[1]),) + key[2:5]) \
+        == "ae2b22463689ccf6b589218ca1a5ab7358e5524a"
+    [hkey] = [k for k in comp.stat_keys if k[0] == "join"]
+    assert hkey[1][-1][0] == "join_sorted"
+    assert _sha1(hkey) == "e23c51c7053b941ce4800afe46b226c4ccef179f"
+
+
+def _sorted_join_engine():
+    # float keys take no direct table: both compilers plan a sorted probe
+    # join, whose expand phase has two routes to a slot's probe row. Keys
+    # that match nothing (zero-count probe rows), runs of duplicates on both
+    # sides and NULL keys are the cases the routes must agree on
+    rng = np.random.default_rng(11)
+    fk = rng.integers(0, 24, 3000) * 0.5
+    a = pa.table({"fk": pa.array(fk, type=pa.float64(),
+                                 mask=np.arange(3000) % 97 == 0),
+                  "x": pa.array(np.arange(3000), type=pa.int64())})
+    k = np.repeat(np.arange(16) * 0.5, 3)
+    b = pa.table({"k": pa.array(k, type=pa.float64(),
+                                mask=np.arange(48) % 13 == 0),
+                  "v": pa.array(np.arange(48), type=pa.int64())})
+    e = QueryEngine()
+    e.register_table("a", a)
+    e.register_table("b", b)
+    return e, a.to_pandas(), b.to_pandas()
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("route", ["scan", "search"])
+@pytest.mark.parametrize("compiler", ["fused", "staged"])
+def test_both_match_routes_through_both_compilers(monkeypatch, compiler,
+                                                  route, how):
+    # off the TPU both compilers plan the searchsorted inversion; the
+    # scatter + cummax scan is what they plan on the chip. Each is held to
+    # pandas here, through the compiler that passes it on
+    from igloo_tpu.exec import executor as X
+    from igloo_tpu.exec import join as J
+    assert J.match_by_search() is True          # XLA:CPU
+    calls = []
+
+    def choice():
+        calls.append(route)
+        return route == "search"
+    monkeypatch.setattr(F, "match_by_search", choice)
+    monkeypatch.setattr(X, "match_by_search", choice)
+    e, a, b = _sorted_join_engine()
+    sql = (f"SELECT x, v FROM a {how.upper()} JOIN b ON a.fk = b.k "
+           "ORDER BY x, v")
+    if compiler == "fused":
+        got = e.execute(sql)
+        comp, _key = _compile_on(e, sql)
+        assert [fp for fp in comp.fps if fp[0] == "join_sorted"]
+    else:
+        ex = X.Executor(e._jit_cache, batch_cache=e.batch_cache)
+        got = ex._staged_to_arrow(e.plan(sql))
+    assert calls
+    want = a.merge(b.dropna(subset=["k"]), left_on="fk", right_on="k",
+                   how=how).sort_values(["x", "v"])
+    assert got.column("x").to_pylist() == want.x.tolist()
+    assert [None if v is None else int(v)
+            for v in got.column("v").to_pylist()] == \
+        [None if np.isnan(v) else int(v) for v in want.v.tolist()]
+
+
+def test_nofuse_sentinel_is_armed_once_per_program(tmp_path):
+    # ISSUE 32 took the mode token out of the program key, so `_fused_run`
+    # finds its program in the cache from the second execution on, as its
+    # comment says: the sentinel (two rewrites of nhints.json) is armed and
+    # cleared before a program's FIRST execution in the process only
+    from igloo_tpu.exec.hints import HintStore
+    fact, dim = _mk_tables(N_FACT, 1000, match_every=64)
+    e = QueryEngine()
+    e.hint_store = store = HintStore(str(tmp_path / "nhints.json"))
+    e.register_table("fact", fact)
+    e.register_table("dim", dim)
+    armed = []
+    put = store.put
+
+    def counting_put(key, n):
+        if key[0] == "nofuse":
+            armed.append(key)
+        put(key, n)
+    store.put = counting_put
+    for _ in range(4):                       # cold, hinted, steady, steady
+        _t, c = _run_counting(e, SQL)
+    assert c.get("jit.hit") and not c.get("jit.miss")
+    programs = {k for k in e._jit_cache
+                if isinstance(k, tuple) and k[0] == "fused"}
+    assert len(armed) == len(set(armed)) == len(programs) >= 2
+    # and cleared once its program has run
+    assert [store.get(k) for k in armed] == [None] * len(armed)
 
 
 def test_chunked_global_partial_declines_too(small_adaptive, tmp_path):

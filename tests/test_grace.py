@@ -58,7 +58,6 @@ PLAIN_SQL = """
 """
 
 
-@pytest.mark.slow
 def test_grace_join_agg_matches_in_memory(parquet_tables):
     d, fact, dim = parquet_tables
     want = _mk_engine(d, 1 << 40).execute(AGG_SQL)  # huge budget: normal path
@@ -77,7 +76,6 @@ def test_grace_join_agg_matches_in_memory(parquet_tables):
                                want.column("a").to_pylist(), rtol=1e-9)
 
 
-@pytest.mark.slow
 def test_grace_join_no_aggregate(parquet_tables):
     d, fact, dim = parquet_tables
     want = _mk_engine(d, 1 << 40).execute(PLAIN_SQL)
@@ -88,7 +86,6 @@ def test_grace_join_no_aggregate(parquet_tables):
     assert got.to_pydict() == want.to_pydict()
 
 
-@pytest.mark.slow
 def test_small_budget_non_join_still_normal(parquet_tables):
     d, _, _ = parquet_tables
     e = _mk_engine(d, 64 << 10)

@@ -157,7 +157,6 @@ def test_worker_streaming_exchange_counters(cluster):
     assert "igloo_grace_remote_partitions_total" in ctext
 
 
-@pytest.mark.slow
 def test_worker_death_redispatches_oversized(cluster):
     """Kill a worker that joined after sync: the oversized query either
     re-plans over the survivors or falls back to the single-node ladder —

@@ -8,8 +8,8 @@ from igloo_tpu import types as T
 from igloo_tpu.exec.aggregate import AggSpec, aggregate_batch, distinct_batch
 from igloo_tpu.exec.batch import DeviceBatch, from_arrow, to_arrow
 from igloo_tpu.exec.expr_compile import Compiled, ExprCompiler
-from igloo_tpu.exec.join import join_batches
-from igloo_tpu.exec.sort_limit import limit_batch, sort_batch
+from igloo_tpu.exec.join import _probe_bounds, expand_phase, join_batches
+from igloo_tpu.exec.sort_limit import limit_batch, plan_topk, sort_batch
 from igloo_tpu.plan.expr import AggFunc, BinOp, Binary, Column
 from igloo_tpu.sql.ast import JoinType
 
@@ -129,6 +129,13 @@ class TestAggregate:
 
 
 class TestJoin:
+    @pytest.fixture(autouse=True, params=["scan", "search"])
+    def _route(self, request):
+        # both ways expand_phase finds a slot's probe row, each held to the
+        # same expectations: the scatter + cummax scan is what the compilers
+        # plan on a TPU, the searchsorted inversion what they plan here
+        self._search = request.param == "search"
+
     def _join(self, lt, rt, jt, n_keys=1, residual=None, out_names=None,
               pool=None):
         lb, rb = from_arrow(lt), from_arrow(rt)
@@ -140,8 +147,12 @@ class TestJoin:
             fields = list(lb.schema.fields) + [
                 T.Field(f"r_{f.name}", f.dtype, True) for f in rb.schema.fields]
             schema = T.Schema(fields)
+
+        def expand(l, r, p, match_cap, consts):
+            return expand_phase(l, r, p, match_cap, jt, residual, schema,
+                                consts, match_search=self._search)
         return to_arrow(join_batches(lb, rb, lk, rk, jt, residual, schema,
-                                     pool=pool))
+                                     expand_jit=expand, pool=pool))
 
     def test_inner_with_duplicates(self):
         lt = pa.table({"k": pa.array([1, 2, 2, 3], type=pa.int64()),
@@ -334,3 +345,111 @@ class TestSortLimit:
         b = from_arrow(t)
         out = to_arrow(sort_batch(b, [col(b, 0)], [True], [False]))
         assert out.column("v").to_pylist() == [4, 3, 2, 1]  # original order kept
+
+
+# --- probe bounds: the one combined sort against numpy's searchsorted -------
+
+_I64_MAX = np.iinfo(np.int64).max
+
+
+def _mixed_build(seed, m, spread):
+    """Half live keys, a quarter of those one displaced-NULL sentinel run,
+    the rest the dead rows' MAX-sentinel run (join.probe_phase's layout)."""
+    rng = np.random.default_rng(seed)
+    live = m // 2
+    return np.concatenate([
+        rng.integers(-spread, spread, live - live // 4),
+        np.full(live // 4, 0x0FEDCBA987654321),
+        np.full(m - live, _I64_MAX)]).astype(np.int64)
+
+
+@pytest.mark.parametrize("build,n,spread", [
+    (_mixed_build(0, 512, 400), 256, 400),
+    (_mixed_build(1, 256, 50), 512, 50),          # runs of ~2 per key
+    (_mixed_build(2, 1024, 100000), 128, 100000),  # almost no match
+    (np.full(128, _I64_MAX, np.int64), 64, 100),  # empty (all-dead) build
+    (np.zeros(256, np.int64), 64, 1),             # one key, a run of 256
+], ids=["mixed", "duplicates", "sparse", "empty_build", "all_one_key"])
+def test_probe_bounds_match_searchsorted(build, n, spread):
+    import jax.numpy as jnp
+    probe = np.random.default_rng(9).integers(
+        -spread, spread, n).astype(np.int64)
+    lo, up = _probe_bounds(jnp.asarray(build), jnp.asarray(probe))
+    # hashes compare with the low bit dropped (the side tag's place);
+    # masking preserves the sort order
+    sb = np.sort(build) & np.int64(-2)
+    p = probe & np.int64(-2)
+    np.testing.assert_array_equal(np.asarray(lo),
+                                  np.searchsorted(sb, p, side="left"))
+    np.testing.assert_array_equal(np.asarray(up),
+                                  np.searchsorted(sb, p, side="right"))
+
+
+# --- ORDER BY + LIMIT: the lax.top_k route against the full sort ------------
+
+@pytest.mark.parametrize("cap,k,pack,n_keys,want", [
+    (1024, 13, (("i32", 0, ()), 2), 2, True),
+    (1024, 0, (("i32", 0, ()), 2), 2, False),      # LIMIT 0
+    (1024, 13, (("i32", 0, ()), 1), 2, False),     # a key left unpacked
+    (1024, 13, None, 2, False),
+    (64, 100, (("i32", 0, ()), 2), 2, False),      # LIMIT covers the batch
+    (64, 32, (("i32", 0, ()), 2), 2, True),        # 2k == cap still pays
+], ids=["adopts", "limit0", "partial_pack", "no_pack", "large_limit",
+        "half"])
+def test_plan_topk_rule(cap, k, pack, n_keys, want):
+    from igloo_tpu.utils import tracing
+    with tracing.counter_delta() as d:
+        assert plan_topk(cap, k, pack, n_keys) is want
+    assert d.get("topk.alg") == int(want)
+
+
+def _sort_engine(n=900, seed=5):
+    from igloo_tpu.engine import QueryEngine
+    rng = np.random.default_rng(seed)
+    e = QueryEngine()
+    e.register_table("t", pa.table({
+        "a": pa.array(rng.integers(0, 40, n), type=pa.int64()),
+        "b": pa.array([None if v < 30 else int(v)
+                       for v in rng.integers(0, 300, n)], type=pa.int64()),
+        "x": pa.array(rng.normal(size=n)),
+    }))
+    return e
+
+
+_FULL_SQL = "SELECT a, b, x FROM t ORDER BY a, b"
+
+
+def _first_k(t: pa.Table, k: int):
+    return [tuple(c[i] for c in t.to_pydict().values()) for i in range(k)]
+
+
+def test_topk_alg_route_on_kernels_off_tier():
+    """ORDER BY + LIMIT over packable keys takes lax.top_k (topk.alg) and
+    reproduces the full stable sort's first k rows — heavy duplicate keys
+    (ties) and NULLs included."""
+    from igloo_tpu.utils import tracing
+    full = _sort_engine().execute(_FULL_SQL)
+    with tracing.counter_delta() as d:
+        got = _sort_engine().execute(_FULL_SQL + " LIMIT 13")
+    assert d.get("topk.alg") > 0
+    assert got.num_rows == 13
+    assert _first_k(got, 13) == _first_k(full, 13)
+
+
+def test_topk_offset_rows():
+    full = _sort_engine().execute(_FULL_SQL)
+    got = _sort_engine().execute(_FULL_SQL + " LIMIT 10 OFFSET 5")
+    assert got.num_rows == 10
+    assert _first_k(got, 10) == _first_k(full, 15)[5:]
+
+
+def test_limit_ge_rows_takes_direct_path():
+    """Regression: a LIMIT covering most of the batch must NOT route through
+    the partial top-k (2*k > capacity buys nothing): the full sort runs."""
+    from igloo_tpu.utils import tracing
+    full = _sort_engine(n=60).execute(_FULL_SQL)
+    with tracing.counter_delta() as d:
+        got = _sort_engine(n=60).execute(_FULL_SQL + " LIMIT 100")
+    assert d.get("topk.alg") == 0
+    assert got.num_rows == 60
+    assert _first_k(got, 60) == _first_k(full, 60)

@@ -9,7 +9,6 @@ from igloo_tpu.lint.cache_key import CacheKeyChecker
 from igloo_tpu.lint.jit_key import JitKeyChecker
 from igloo_tpu.lint.lock_discipline import LockDisciplineChecker
 from igloo_tpu.lint.metric_names import MetricNamesChecker
-from igloo_tpu.lint.pallas_dispatch import PallasDispatchChecker
 from igloo_tpu.lint.rpc_policy import RpcPolicyChecker
 from igloo_tpu.lint.span_names import SpanNamesChecker
 from igloo_tpu.lint.sync_hazard import SyncHazardChecker
@@ -202,37 +201,6 @@ def test_rpc_policy_exempts_the_connect_site():
     # the fixture tree's igloo_tpu/cluster/rpc.py mirrors the real one: raw
     # connects INSIDE the policy module are the whole point
     assert _lint([PKG / "cluster" / "rpc.py"], [RpcPolicyChecker()]) == []
-
-
-# --- pallas-dispatch --------------------------------------------------------
-
-def test_pallas_dispatch_flags_bad_fixture():
-    f = _lint([PKG / "exec" / "pallas_dispatch_bad.py"],
-              [PallasDispatchChecker()])
-    lines = {x.line for x in f}
-    assert all(x.rule == "pallas-dispatch" for x in f)
-    src = (PKG / "exec" / "pallas_dispatch_bad.py").read_text().splitlines()
-    bad_lines = {i for i, ln in enumerate(src, 1) if "# BAD" in ln}
-    assert lines == bad_lines, (sorted(lines), sorted(bad_lines))
-
-
-def test_pallas_dispatch_passes_clean_fixture():
-    assert _lint([PKG / "exec" / "pallas_dispatch_clean.py"],
-                 [PallasDispatchChecker()]) == []
-
-
-def test_pallas_dispatch_exempts_the_dispatch_site():
-    # the fixture tree's igloo_tpu/exec/dispatch.py mirrors the real one:
-    # kernel imports INSIDE the dispatch module are the whole point
-    assert _lint([PKG / "exec" / "dispatch.py"],
-                 [PallasDispatchChecker()]) == []
-
-
-def test_pallas_dispatch_exempts_the_autotuner():
-    # exec/autotune.py benchmarks kernels directly on synthetic lanes — the
-    # second (and last) allowlisted site
-    assert _lint([PKG / "exec" / "autotune.py"],
-                 [PallasDispatchChecker()]) == []
 
 
 # --- metric-names -----------------------------------------------------------

@@ -131,7 +131,6 @@ def test_heartbeat_payload_has_no_dead_ts_field():
 # --- client surface for every registry action --------------------------------
 
 
-@pytest.mark.slow
 def test_client_covers_control_actions():
     """Every coordinator control action has a typed client accessor (the
     flight-actions checker warns on registry actions with no in-package

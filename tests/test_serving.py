@@ -620,7 +620,7 @@ weights = [5, 1]
 # --- soak: hundreds of clients, 2 workers, fairness (slow tier) --------------
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 40.1-52.2 s alone on the CPU (PR 32)
 def test_concurrent_soak_throughput_and_fairness():
     """200 concurrent clients vs a 2-worker cluster: everything completes
     (throughput) and the weighted fair dequeue orders waits by tier. The

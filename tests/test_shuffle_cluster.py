@@ -320,7 +320,7 @@ def test_worker_metrics_include_exchange(cluster):
 # --- streaming under the bytes budget (slow: ~100 MB table) -----------------
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 1 s, but fails in some runs: peak RSS +65.9 MB against 48 MB (ISSUE 32)
 def test_large_result_streams_under_budget_without_rss_double():
     """A fragment result ~12x the store budget spills, stays bounded in
     memory, and streams to a consumer batch-wise — peak RSS must not grow by
@@ -351,7 +351,6 @@ def test_large_result_streams_under_budget_without_rss_double():
         ws.shutdown()
 
 
-@pytest.mark.slow
 def test_worker_death_reruns_bucket_fragments(cluster):
     """Kill a worker that joined after table sync: per-bucket fragments are
     pure, so the coordinator re-dispatches them and the join still answers."""
