@@ -26,7 +26,7 @@ from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
 from igloo_tpu.exec.expr_compile import Compiled, Env
 from igloo_tpu.plan import logical as L
-from igloo_tpu.plan.expr import AggFunc
+from igloo_tpu.plan.expr import AggFunc, fingerprint
 from igloo_tpu.utils import tracing
 
 
@@ -256,8 +256,8 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
 
     # aggregates via segment reductions over sorted order
     for spec in aggs:
-        out_cols.append(_reduce_one(spec, env, perm, seg, s_live, cap, cap,
-                                    bounds=bounds))
+        out_cols.append(_reduce_one(spec, env, perm, seg, s_live, cap,
+                                    bounds))
 
     out_live = jnp.arange(cap, dtype=jnp.int32) < num_groups
     return DeviceBatch(out_schema, out_cols, out_live)
@@ -281,27 +281,52 @@ def _run_sum(vals: jax.Array, bounds) -> jax.Array:
     return jnp.take(cs, end_idx) - before
 
 
+def _acc_dtype(spec: AggSpec):
+    """SUM/AVG accumulate in float64, or in int64 for an integer SUM."""
+    return jnp.float64 if (spec.out_dtype.is_float or
+                           spec.func is AggFunc.AVG) else jnp.int64
+
+
+def _order_lane(spec: AggSpec, env: Env, v: jax.Array, perm=None):
+    """MIN/MAX compare on this lane, not on the value: (lane, lo, hi) with
+    lo/hi its identities. Floats are NaN-normalized (NaN orders as +inf),
+    everything else widens to int64; an unsorted string dictionary compares
+    by its rank lane (AggSpec.order_arg). `perm` permutes the rank lane as
+    the caller permuted `v`."""
+    src = v
+    if spec.order_arg is not None:
+        ov, _ = spec.order_arg.fn(env)
+        src = ov if perm is None else jnp.take(ov, perm)
+    if spec.arg.dtype.is_float:
+        vnorm, nan = K.normalize_float(src)
+        lane = jnp.where(nan, jnp.asarray(jnp.inf, vnorm.dtype), vnorm)
+        return lane, jnp.asarray(-jnp.inf, lane.dtype), \
+            jnp.asarray(jnp.inf, lane.dtype)
+    return src.astype(jnp.int64), jnp.iinfo(jnp.int64).min, \
+        jnp.iinfo(jnp.int64).max
+
+
 def _reduce_one(spec: AggSpec, env: Env, perm, seg, s_live, cap,
-                nseg, bounds=None) -> DeviceColumn:
-    """Segment reduction for one aggregate. `perm` sorts rows into segment
-    order (None = rows already aligned with `seg`); output arrays have length
-    `nseg` (= cap on the sort path, the padded segment count on the direct
-    path). `bounds` = per-output-row (start, end) sorted positions when
-    segments are contiguous: INTEGER sums (counts, int SUM) then run
-    scatter-free via cumsum differences (see _run_sum for why floats don't)."""
+                bounds) -> DeviceColumn:
+    """The sort path's segment reduction for one aggregate. `perm` sorts rows
+    into segment order; output arrays have length `cap`. `bounds` = per-output
+    -row (start, end) sorted positions of the contiguous segments: INTEGER
+    sums (counts, int SUM) run scatter-free via cumsum differences (see
+    _run_sum for why floats don't), everything else through K.seg_sum /
+    seg_min / seg_max (scatters; at a capacity of SMALL_NSEG or less the
+    one-pass masked reduce over every segment id)."""
     def ssum(vals):
-        if bounds is not None and jnp.issubdtype(vals.dtype, jnp.integer):
+        if jnp.issubdtype(vals.dtype, jnp.integer):
             return _run_sum(vals, bounds)
-        return K.seg_sum(vals, seg, nseg)
+        return K.seg_sum(vals, seg, cap)
 
     if spec.func is AggFunc.COUNT_STAR:
         cnt = ssum(s_live.astype(jnp.int64))
         return DeviceColumn(T.INT64, cnt, None, None)
 
     v, nl = spec.arg.fn(env)
-    sv = v if perm is None else jnp.take(v, perm)
-    snl = nl if perm is None else (jnp.take(nl, perm)
-                                   if nl is not None else None)
+    sv = jnp.take(v, perm)
+    snl = jnp.take(nl, perm) if nl is not None else None
     valid = s_live if snl is None else (s_live & ~snl)
     n_valid = ssum(valid.astype(jnp.int64))
     all_null = n_valid == 0
@@ -310,46 +335,37 @@ def _reduce_one(spec: AggSpec, env: Env, perm, seg, s_live, cap,
         return DeviceColumn(T.INT64, n_valid, None, None)
 
     if spec.func is AggFunc.SUM or spec.func is AggFunc.AVG:
-        acc_dtype = jnp.float64 if (spec.out_dtype.is_float or
-                                    spec.func is AggFunc.AVG) else jnp.int64
+        acc_dtype = _acc_dtype(spec)
         sval = jnp.where(valid, sv.astype(acc_dtype), jnp.zeros((), acc_dtype))
-        total = ssum(sval)
-        if spec.func is AggFunc.AVG:
-            denom = jnp.where(all_null, 1, n_valid).astype(jnp.float64)
-            return DeviceColumn(T.FLOAT64, total / denom, all_null, None)
-        return DeviceColumn(spec.out_dtype,
-                            total.astype(spec.out_dtype.device_dtype()),
-                            all_null, None)
+        return _sum_column(spec, ssum(sval), n_valid, all_null)
 
     # MIN / MAX: sentinel-masked segment reduce on a comparable lane, then an
     # exact gather of the original value at a winning position (so e.g. a NaN
     # winner comes back as NaN, not as its +inf ordering surrogate)
     pos = jnp.arange(cap, dtype=jnp.int32)
-    cmp_src = sv
-    if spec.order_arg is not None:
-        ov, _ = spec.order_arg.fn(env)
-        cmp_src = ov if perm is None else jnp.take(ov, perm)
-    if spec.arg.dtype.is_float:
-        vnorm, nan = K.normalize_float(cmp_src)
-        lane = jnp.where(nan, jnp.asarray(jnp.inf, vnorm.dtype), vnorm)
-        lo = jnp.asarray(-jnp.inf, lane.dtype)
-        hi = jnp.asarray(jnp.inf, lane.dtype)
-    else:
-        lane = cmp_src.astype(jnp.int64)
-        lo = jnp.iinfo(jnp.int64).min
-        hi = jnp.iinfo(jnp.int64).max
+    lane, lo, hi = _order_lane(spec, env, sv, perm)
     if spec.func is AggFunc.MIN:
         keyed = jnp.where(valid, lane, hi)
-        best_lane = K.seg_min(keyed, seg, nseg)
+        best_lane = K.seg_min(keyed, seg, cap)
     else:
         keyed = jnp.where(valid, lane, lo)
-        best_lane = K.seg_max(keyed, seg, nseg)
+        best_lane = K.seg_max(keyed, seg, cap)
     # recover a row index holding the winning lane value for exact value gather
     is_best = valid & (keyed == jnp.take(best_lane, seg))
-    best_pos = K.seg_min(jnp.where(is_best, pos, jnp.int32(cap)), seg, nseg)
+    best_pos = K.seg_min(jnp.where(is_best, pos, jnp.int32(cap)), seg, cap)
     best_pos = jnp.clip(best_pos, 0, cap - 1)
     out_val = jnp.take(sv, best_pos)
     return DeviceColumn(spec.out_dtype, out_val, all_null, spec.out_dict)
+
+
+def _sum_column(spec: AggSpec, total, n_valid, all_null) -> DeviceColumn:
+    """SUM / AVG output from a group's total and its count of non-NULLs."""
+    if spec.func is AggFunc.AVG:
+        denom = jnp.where(all_null, 1, n_valid).astype(jnp.float64)
+        return DeviceColumn(T.FLOAT64, total / denom, all_null, None)
+    return DeviceColumn(spec.out_dtype,
+                        total.astype(spec.out_dtype.device_dtype()),
+                        all_null, None)
 
 
 def _global_aggregate(env: Env, aggs: list[AggSpec], out_schema: T.Schema,
@@ -383,8 +399,7 @@ def _global_aggregate(env: Env, aggs: list[AggSpec], out_schema: T.Schema,
             lane, _ = one_row(n_valid, jnp.int64)
             out_cols.append(DeviceColumn(T.INT64, lane, None, None))
         elif spec.func in (AggFunc.SUM, AggFunc.AVG):
-            acc_dtype = jnp.float64 if (spec.out_dtype.is_float or
-                                        spec.func is AggFunc.AVG) else jnp.int64
+            acc_dtype = _acc_dtype(spec)
             total = jnp.sum(jnp.where(valid, v.astype(acc_dtype),
                                       jnp.zeros((), acc_dtype)))
             if spec.func is AggFunc.AVG:
@@ -396,19 +411,7 @@ def _global_aggregate(env: Env, aggs: list[AggSpec], out_schema: T.Schema,
                                     all_null)
                 out_cols.append(DeviceColumn(spec.out_dtype, lane, nlo, None))
         else:  # MIN / MAX with exact winning-row gather (NaN stays NaN)
-            cmp_src = v
-            if spec.order_arg is not None:
-                cmp_src, _ = spec.order_arg.fn(env)
-            if spec.arg.dtype.is_float:
-                vnorm, nan = K.normalize_float(cmp_src)
-                lane_v = jnp.where(nan, jnp.asarray(jnp.inf, vnorm.dtype),
-                                   vnorm)
-                lo = jnp.asarray(-jnp.inf, lane_v.dtype)
-                hi = jnp.asarray(jnp.inf, lane_v.dtype)
-            else:
-                lane_v = cmp_src.astype(jnp.int64)
-                lo = jnp.iinfo(jnp.int64).min
-                hi = jnp.iinfo(jnp.int64).max
+            lane_v, lo, hi = _order_lane(spec, env, v)
             keyed = jnp.where(valid, lane_v,
                               hi if spec.func is AggFunc.MIN else lo)
             best = jnp.argmin(keyed) if spec.func is AggFunc.MIN \
@@ -444,13 +447,33 @@ def uncompacted_filter(plan: L.Aggregate) -> Optional[L.Filter]:
     return node if isinstance(node, L.Filter) else None
 
 
+def _feasible_segments(seg_dims: tuple, gnulls: list) -> list:
+    """The segment ids of _direct_aggregate that can hold a live row: every
+    digit combination, less digit 0 (the NULL bucket) of a key that reached
+    the aggregate without a null lane — a trace-time fact. Q1: two dictionary
+    keys without nulls, (4 - 1) x (3 - 1) = 6 ids of a padded 16."""
+    ids = [0]
+    for (d, _off), nl in zip(seg_dims, gnulls):
+        digits = range(d) if nl is not None else range(1, d)
+        ids = [s * d + c for s in ids for c in digits]
+    return ids
+
+
 def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
                       aggs: list[AggSpec], out_schema: T.Schema,
                       live: jax.Array,
                       seg_dims: tuple) -> DeviceBatch:  # ((count, offset), ...)
-    """Direct-scatter grouping for small indexable keys (see seg_dims_for):
-    segment id = mixed-radix combination of (NULL?0:key+1) digits. Skips the
-    full-capacity lex sort; output capacity = padded segment count (small)."""
+    """Direct grouping for small indexable keys (see seg_dims_for): segment
+    id = mixed-radix combination of (NULL?0:key+1) digits. Skips the
+    full-capacity lex sort; output capacity = padded segment count (small).
+
+    What the aggregates reduce is collected first and handed to K.seg_reduce
+    together, each distinct lane once: the live count, one count per distinct
+    null lane, one sum lane per distinct argument (by E.fingerprint of the
+    expression it was compiled from) and accumulator dtype, one best-value
+    lane per MIN/MAX; MIN/MAX take a second such round for the winning
+    position. At SMALL_NSEG segments or fewer that is one pass over the
+    lanes, into the feasible segments only; above, one scatter per lane."""
     from igloo_tpu.exec.batch import round_capacity
     cap = live.shape[0]
     prod = 1
@@ -466,8 +489,74 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
             comp = jnp.where(nl, 0, comp)
         seg = seg * jnp.int32(d) + comp
     seg = jnp.where(live, seg, jnp.int32(dead))
+    # listed only for seg_reduce's one-pass arm: above the threshold a scatter
+    # fills every slot, and a dense integer domain has millions of ids
+    seg_ids = _feasible_segments(seg_dims, gnulls) \
+        if nseg <= K.SMALL_NSEG else None
 
-    counts = K.seg_sum(live.astype(jnp.int32), seg, nseg)
+    # round 1: counts, sums, each MIN/MAX's best lane value. A count never
+    # passes the batch's capacity, an int32: counted so, widened after.
+    lanes = {"live": (live.astype(jnp.int32), "sum")}  # key -> (lane, op)
+    args: dict = {}  # argument key -> (values, its non-NULL live rows,
+    #                  the key of their count)
+    null_lanes = []  # (a null lane, the key of its count): x and x * 2 carry
+    #                  ONE lane object, found again with `is`
+    plans = []       # per spec: (argument key, lane key); None: COUNT(*)
+    for i, spec in enumerate(aggs):
+        if spec.func is AggFunc.COUNT_STAR:
+            plans.append(None)
+            continue
+        # a Compiled built by hand says nothing of what it computes: by index
+        akey = fingerprint(spec.arg.expr) if spec.arg.expr is not None else i
+        if akey not in args:
+            v, nl = spec.arg.fn(env)
+            if nl is None:
+                args[akey] = (v, live, "live")
+            else:
+                ckey = next((k for n, k in null_lanes if n is nl), None)
+                if ckey is None:
+                    ckey = ("n", akey)
+                    null_lanes.append((nl, ckey))
+                    lanes[ckey] = ((live & ~nl).astype(jnp.int32), "sum")
+                args[akey] = (v, live & ~nl, ckey)
+        v, valid, _ = args[akey]
+        lkey = None
+        if spec.func in (AggFunc.SUM, AggFunc.AVG):
+            acc = _acc_dtype(spec)
+            lkey = ("sum", akey, np.dtype(acc).name)
+            if lkey not in lanes:
+                lanes[lkey] = (jnp.where(valid, v.astype(acc),
+                                         jnp.zeros((), acc)), "sum")
+        elif spec.func in (AggFunc.MIN, AggFunc.MAX):
+            op = "min" if spec.func is AggFunc.MIN else "max"
+            lkey = (op, akey)
+            if lkey not in lanes:
+                lane, lo, hi = _order_lane(spec, env, v)
+                lanes[lkey] = (jnp.where(valid, lane,
+                                         hi if op == "min" else lo), op)
+        plans.append((akey, lkey))
+    found = dict(zip(lanes, K.seg_reduce(list(lanes.values()), seg, nseg,
+                                         seg_ids)))
+
+    # round 2: a row index holding each MIN/MAX's winning lane value, for an
+    # exact gather of the original value (a NaN winner comes back as NaN,
+    # not as its +inf ordering surrogate)
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    pos_lanes = {}  # a MIN/MAX's lane key -> (its candidate positions, "min")
+    for spec, plan in zip(aggs, plans):
+        if spec.func in (AggFunc.MIN, AggFunc.MAX) and \
+                plan[1] not in pos_lanes:
+            akey, lkey = plan
+            is_best = args[akey][1] & \
+                (lanes[lkey][0] == jnp.take(found[lkey], seg))
+            pos_lanes[lkey] = (jnp.where(is_best, pos, jnp.int32(cap)), "min")
+    best_pos = dict(zip(pos_lanes, K.seg_reduce(list(pos_lanes.values()), seg,
+                                                nseg, seg_ids)))
+    if seg_ids is not None:
+        tracing.counter("agg.onepass_segments", len(seg_ids))
+        tracing.counter("agg.onepass_lanes", len(lanes) + len(pos_lanes))
+
+    counts = found["live"]
     group_mask = (counts > 0) & (jnp.arange(nseg) < prod)
 
     # group VALUES decode from the segment index (every seg_dims kind is a
@@ -487,8 +576,23 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
             g.dtype, raw.astype(g.dtype.device_dtype()),
             (digit == 0) if nl is not None else None,
             g.out_dict))
-    for spec in aggs:
-        out_cols.append(_reduce_one(spec, env, None, seg, live, cap, nseg))
+    for spec, plan in zip(aggs, plans):
+        if plan is None:
+            out_cols.append(DeviceColumn(
+                T.INT64, counts.astype(jnp.int64), None, None))
+            continue
+        akey, lkey = plan
+        v, _valid, ckey = args[akey]
+        n_valid = found[ckey].astype(jnp.int64)
+        all_null = n_valid == 0
+        if spec.func is AggFunc.COUNT:
+            out_cols.append(DeviceColumn(T.INT64, n_valid, None, None))
+        elif spec.func in (AggFunc.MIN, AggFunc.MAX):
+            at = jnp.clip(best_pos[lkey], 0, cap - 1)
+            out_cols.append(DeviceColumn(
+                spec.out_dtype, jnp.take(v, at), all_null, spec.out_dict))
+        else:
+            out_cols.append(_sum_column(spec, found[lkey], n_valid, all_null))
 
     # compact live groups to the front (segment-id order = NULL-first
     # dictionary-rank order); aggregate output row order is not semantic

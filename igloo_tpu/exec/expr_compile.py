@@ -142,6 +142,11 @@ class Compiled:
     # (lo, hi) host-known value bounds for integer-family outputs (bare column
     # refs / int literals); feeds the direct-join strategy choice. None = unknown.
     out_bounds: Optional[tuple] = None
+    # the bound expression this was compiled from (ExprCompiler.compile sets
+    # it; None on a Compiled built by hand). Two Compileds of one compiler
+    # whose `E.fingerprint(expr)` are equal compute the same lanes: the direct
+    # aggregate reduces such arguments once.
+    expr: Optional[E.Expr] = None
 
 
 class ExprCompileError(Exception):
@@ -265,7 +270,9 @@ class ExprCompiler:
         m = getattr(self, "_c_" + type(e).__name__.lower(), None)
         if m is None:
             raise ExprCompileError(f"cannot compile {type(e).__name__}: {e!r}")
-        return m(e)
+        c = m(e)
+        c.expr = e
+        return c
 
     # --- leaves ---
 

@@ -18,7 +18,7 @@ re-designed as static-shape, whole-column XLA programs:
 from __future__ import annotations
 
 from dataclasses import replace
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -350,37 +350,81 @@ def group_segments(sorted_lanes: list, sorted_nulls: list,
     return seg.astype(jnp.int32), start
 
 
-# Below this many segments, segment reductions unroll into per-segment masked
-# reductions (compare+select+reduce fuses into one memory-bound pass per
-# segment) instead of XLA scatter ops: on TPU a scatter over an 8M-row lane
-# costs ~300 ms while a fused masked reduction is bandwidth-bound (~1 ms), so
-# for Q1-sized group counts the loop is ~100x faster. Above the threshold the
-# O(nseg * N) loop loses to the O(N) scatter.
+# At or below this many segments, segment reductions are masked reductions
+# instead of XLA scatter ops (on TPU a scatter over an 8M-row lane costs
+# ~300 ms): seg_reduce hands every (lane, segment id) pair to a variadic
+# lax.reduce, whose ONE fusion reads `seg` and each lane once and keeps an
+# accumulator per pair. That pass is bound by the selects and adds, not by
+# HBM, so above the threshold its O(nseg * N) work loses to the O(N) scatter.
 SMALL_NSEG = 64
+
+# Operands of one variadic reduce; a longer list of pairs is cut into several.
+# The bound exists because at 64 operands the TPU compiler stops fusing the
+# selects into the reduce and writes every operand to HBM. Under it a whole
+# Q1 (6 lanes x 6 feasible segments) is ONE fusion: no segment mask and no
+# product reaches HBM. Measured on a v5e at 2^26 lanes (PERF.md §6, PR 33):
+# that Q1 takes 28.4 ms as one reduce, 29.7 as two of 18, 31.0 as six of 6;
+# 288 operands (6 lanes x 48 segments) take 142 ms under this bound and 122
+# under 16 or 24 — a shape no query of the benchmark has; lower the bound
+# with a measurement of one that does.
+MAX_REDUCE_OPERANDS = 48
+
+
+class _SegOp(NamedTuple):
+    fold: Callable      # (x, y) -> x op y
+    identity: Callable  # dtype -> what an empty segment reads
+    scatter: Callable   # the jax.ops.segment_* of the large-domain arm
+
+
+_SEG_OPS = {
+    "sum": _SegOp(jnp.add, lambda dt: jnp.zeros((), dt), jax.ops.segment_sum),
+    "min": _SegOp(jnp.minimum, lambda dt: _ident_max(dt),
+                  jax.ops.segment_min),
+    "max": _SegOp(jnp.maximum, lambda dt: _ident_min(dt),
+                  jax.ops.segment_max),
+}
+
+
+def seg_reduce(lanes: list, seg: jax.Array, nseg: int,
+               seg_ids=None) -> list:
+    """Segment-reduce several lanes over ONE segment lane. `lanes` is a list
+    of (values, op) with op in "sum" / "min" / "max"; returns one [nseg] array
+    per lane, in the lane's dtype. `seg_ids` (static ints, default every id)
+    are the segments that can hold a row: at SMALL_NSEG segments or fewer only
+    they are reduced, every other slot reads the op's identity (0 / dtype max
+    / dtype min) — what an empty segment reads; above, a scatter per lane
+    fills every slot. Per element the arithmetic is where(seg == i, v, identity)
+    folded in the lane's own dtype; only the association order is XLA's."""
+    if nseg > SMALL_NSEG:
+        return [_SEG_OPS[op].scatter(v, seg, num_segments=nseg)
+                for v, op in lanes]
+    ids = list(range(nseg)) if seg_ids is None else list(seg_ids)
+    idents = [_SEG_OPS[op].identity(v.dtype) for v, op in lanes]
+    pairs = [(k, i) for k in range(len(lanes)) for i in ids]
+    found = {}
+    for at in range(0, len(pairs), MAX_REDUCE_OPERANDS):
+        chunk = pairs[at:at + MAX_REDUCE_OPERANDS]
+        fold = [_SEG_OPS[lanes[k][1]].fold for k, _ in chunk]
+        outs = jax.lax.reduce(
+            [jnp.where(seg == i, lanes[k][0], idents[k]) for k, i in chunk],
+            [idents[k] for k, _ in chunk],
+            lambda xs, ys: [f(x, y) for f, x, y in zip(fold, xs, ys)],
+            (0,))
+        found.update(zip(chunk, outs))
+    return [jnp.stack([found.get((k, i), idents[k]) for i in range(nseg)])
+            for k in range(len(lanes))]
 
 
 def seg_sum(vals: jax.Array, seg: jax.Array, nseg: int) -> jax.Array:
-    if nseg <= SMALL_NSEG:
-        zero = jnp.zeros((), vals.dtype)
-        return jnp.stack([jnp.sum(jnp.where(seg == i, vals, zero))
-                          for i in range(nseg)])
-    return jax.ops.segment_sum(vals, seg, num_segments=nseg)
+    return seg_reduce([(vals, "sum")], seg, nseg)[0]
 
 
 def seg_min(vals: jax.Array, seg: jax.Array, nseg: int) -> jax.Array:
-    if nseg <= SMALL_NSEG:
-        hi = _ident_max(vals.dtype)
-        return jnp.stack([jnp.min(jnp.where(seg == i, vals, hi))
-                          for i in range(nseg)])
-    return jax.ops.segment_min(vals, seg, num_segments=nseg)
+    return seg_reduce([(vals, "min")], seg, nseg)[0]
 
 
 def seg_max(vals: jax.Array, seg: jax.Array, nseg: int) -> jax.Array:
-    if nseg <= SMALL_NSEG:
-        lo = _ident_min(vals.dtype)
-        return jnp.stack([jnp.max(jnp.where(seg == i, vals, lo))
-                          for i in range(nseg)])
-    return jax.ops.segment_max(vals, seg, num_segments=nseg)
+    return seg_reduce([(vals, "max")], seg, nseg)[0]
 
 
 def _ident_max(dtype):

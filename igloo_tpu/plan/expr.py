@@ -11,7 +11,7 @@ inferred) by the planner. `dtype` is filled in during binding.
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass, field as dc_field, fields as dc_fields
 from typing import Optional
 
 from igloo_tpu import types as T
@@ -191,7 +191,8 @@ class Like(Expr):
         return [self.operand]
 
     def __repr__(self) -> str:
-        return f"like({self.operand!r}, {self.pattern!r})"
+        return (f"like({self.operand!r}, {self.pattern!r}, "
+                f"neg={self.negated}, ci={self.case_insensitive})")
 
 
 @dataclass
@@ -369,6 +370,26 @@ def transform(e: Expr, fn) -> Expr:
         if n.agg is not None:
             n.agg = transform(n.agg, fn)
     return fn(n)
+
+
+def fingerprint(e) -> tuple:
+    """What a bound expression computes, as a hashable value: over one input
+    schema, equal fingerprints mean equal results. A `repr` is a label, not
+    that (a Column prints no index, a Literal no type, a subquery nothing of
+    its query): this reads EVERY dataclass field of every node, so that a
+    node which gains a field cannot make two expressions collide; what it
+    cannot read field by field (a subquery's AST) equals nothing, not even
+    itself on a second call."""
+    if isinstance(e, Expr):
+        return (type(e).__name__,) + tuple(
+            fingerprint(getattr(e, f.name)) for f in dc_fields(e))
+    if isinstance(e, (list, tuple)):
+        return tuple(fingerprint(x) for x in e)
+    if e is None or isinstance(e, (bool, int, float, str, enum.Enum,
+                                   T.DataType)):
+        # by type and spelling: 1 == 1.0 == True and 0.0 == -0.0 in Python
+        return (type(e).__name__, repr(e))
+    return ("opaque", object())
 
 
 def columns_in(e: Expr) -> set[str]:

@@ -453,3 +453,490 @@ def test_limit_ge_rows_takes_direct_path():
     assert d.get("topk.alg") == 0
     assert got.num_rows == 60
     assert _first_k(got, 60) == _first_k(full, 60)
+
+
+# --- the one-pass small-domain segment reduce (ISSUE 33) ---
+
+_SCATTER = {"sum": "segment_sum", "min": "segment_min", "max": "segment_max"}
+
+
+def _reduce_lanes(n, nseg, seed):
+    """Seeded lanes of the three dtypes an aggregate reduces, a segment lane
+    whose dead rows sit in the last slot, and the ids live rows can take."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    ids = list(range(1, nseg - 1, 2)) or [0]
+    live = rng.random(n) < 0.8
+    seg = np.where(live, rng.choice(ids, n), nseg - 1).astype(np.int32)
+    f64 = jnp.asarray(rng.standard_normal(n) * 1e6)
+    i64 = jnp.asarray(rng.integers(-2**40, 2**40, n))
+    i32 = jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int32))
+    return f64, i64, i32, jnp.asarray(seg), ids
+
+
+def _scatter_ref(v, op, seg, nseg, ids):
+    """jax.ops.segment_<op> on the produced ids, the identity elsewhere."""
+    import jax
+    from igloo_tpu.exec import kernels as K
+    ref = np.asarray(getattr(jax.ops, _SCATTER[op])(v, seg, num_segments=nseg))
+    ident = np.asarray(K._SEG_OPS[op].identity(v.dtype))
+    return np.where(np.isin(np.arange(nseg), ids), ref, ident)
+
+
+@pytest.mark.parametrize("nseg", [8, 16, 32, 64])
+def test_seg_reduce_equals_scatter_on_mixed_lanes(nseg):
+    """float64, int64 and int32 lanes, sums and extremes, in ONE call: each
+    result equals jax.ops.segment_* on the feasible ids, keeps its lane's
+    dtype, and reads the op's identity in every slot not produced (the dead
+    rows' slot among them)."""
+    from igloo_tpu.exec import kernels as K
+    f64, i64, i32, seg, ids = _reduce_lanes(4096, nseg, seed=nseg)
+    lanes = [(f64, "sum"), (i64, "sum"), (i32, "sum"), (f64, "min"),
+             (i64, "max"), (i32, "min"), (f64, "max")]
+    outs = K.seg_reduce(lanes, seg, nseg, ids)
+    assert len(outs) == len(lanes)
+    for (v, op), got in zip(lanes, outs):
+        assert got.shape == (nseg,) and got.dtype == v.dtype
+        want = _scatter_ref(v, op, seg, nseg, ids)
+        if v.dtype == np.float64 and op == "sum":
+            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-12)
+        else:
+            assert np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("nseg", [8, 16, 32, 64])
+def test_seg_reduce_keeps_nan_and_inf_in_their_segment(nseg):
+    """NaN, +inf and -inf rows of one segment must not reach another's sum,
+    minimum or maximum (a cumsum-difference formulation would leak them)."""
+    import jax.numpy as jnp
+    from igloo_tpu.exec import kernels as K
+    f64, _, _, seg, ids = _reduce_lanes(2048, nseg, seed=100 + nseg)
+    bad = ids[0]
+    vals = np.asarray(f64).copy()
+    rows = np.flatnonzero(np.asarray(seg) == bad)[:3]
+    vals[rows] = [np.nan, np.inf, -np.inf]
+    v = jnp.asarray(vals)
+    s, lo, hi = K.seg_reduce([(v, "sum"), (v, "min"), (v, "max")], seg, nseg,
+                             ids)
+    assert np.isnan(np.asarray(s)[bad])
+    others = [i for i in ids if i != bad]
+    for got, op in ((s, "sum"), (lo, "min"), (hi, "max")):
+        want = _scatter_ref(v, op, seg, nseg, ids)
+        assert np.all(np.isfinite(np.asarray(got)[others]))
+        np.testing.assert_allclose(np.asarray(got)[others], want[others],
+                                   rtol=1e-12)
+
+
+@pytest.mark.parametrize("op,ident", [("sum", 0), ("min", np.iinfo(np.int64).max),
+                                      ("max", np.iinfo(np.int64).min)])
+def test_seg_reduce_with_no_feasible_segment_reads_identities(op, ident):
+    from igloo_tpu.exec import kernels as K
+    _, i64, _, seg, _ = _reduce_lanes(256, 16, seed=5)
+    [got] = K.seg_reduce([(i64, op)], seg, 16, [])
+    assert np.asarray(got).tolist() == [ident] * 16
+
+
+def test_seg_reduce_duplicate_lanes_and_chunked_operands(monkeypatch):
+    """A lane handed over twice is reduced twice to the same numbers, and a
+    lane list cut into several reduces (a low operand bound, so one lane's
+    segments straddle two of them) equals the uncut one bit for bit on
+    integer lanes."""
+    from igloo_tpu.exec import kernels as K
+    f64, i64, i32, seg, ids = _reduce_lanes(4096, 32, seed=9)
+    lanes = [(f64, "sum"), (i64, "sum"), (f64, "sum"), (i32, "max"),
+             (i64, "sum")]
+    whole = K.seg_reduce(lanes, seg, 32, ids)
+    assert np.array_equal(np.asarray(whole[0]), np.asarray(whole[2]))
+    assert np.array_equal(np.asarray(whole[1]), np.asarray(whole[4]))
+    monkeypatch.setattr(K, "MAX_REDUCE_OPERANDS", 7)
+    cut = K.seg_reduce(lanes, seg, 32, ids)
+    for a, b in zip(whole, cut):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-12)
+    assert np.array_equal(np.asarray(whole[1]), np.asarray(cut[1]))
+
+
+@pytest.mark.parametrize("wrapper,op", [("seg_sum", "sum"), ("seg_min", "min"),
+                                        ("seg_max", "max")])
+@pytest.mark.parametrize("nseg", [64, 128])
+def test_seg_wrappers_on_both_sides_of_the_threshold(wrapper, op, nseg):
+    """K.seg_sum / seg_min / seg_max: the one-pass reduce over every id at
+    SMALL_NSEG, the scatter above it; the same numbers either way."""
+    import jax
+    from igloo_tpu.exec import kernels as K
+    f64, _, _, seg, _ = _reduce_lanes(2048, nseg, seed=nseg + 1)
+    got = getattr(K, wrapper)(f64, seg, nseg)
+    want = getattr(jax.ops, _SCATTER[op])(f64, seg, num_segments=nseg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-12)
+
+
+def ccol(batch: DeviceBatch, i: int) -> Compiled:
+    """Column `i` compiled from its bound expression, as the executors do:
+    it carries `expr`, so that equal arguments share their lanes (`col`
+    builds its Compiled by hand and shares with nothing)."""
+    f = batch.schema.fields[i]
+    e = Column(f.name, index=i)
+    e.dtype = f.dtype
+    return ExprCompiler.for_batch(batch).compile(e)
+
+
+def _rows(batch):
+    """A result batch as a sorted list of row tuples (NaN-safe repr)."""
+    d = to_arrow(batch).to_pydict()
+    return sorted(zip(*d.values()), key=repr)
+
+
+def _direct_and_sorted(t, group_idx, mk_aggs, names):
+    """aggregate_batch over `t` twice: on the direct path (seg_dims from the
+    keys) and on the sort path (seg_dims=None), with the counters the direct
+    trace bumped."""
+    from igloo_tpu.exec.aggregate import seg_dims_for
+    from igloo_tpu.utils import tracing
+    b = from_arrow(t)
+    g = [col(b, i) for i in group_idx]
+    aggs = mk_aggs(b)
+    schema = out_schema_for(g, aggs, b, names)
+    dims = seg_dims_for(g)
+    assert dims is not None
+    with tracing.counter_delta() as d:
+        direct = aggregate_batch(b, g, aggs, schema, seg_dims=dims)
+    return direct, aggregate_batch(b, g, aggs, schema), d, dims
+
+
+def _q1_like(n=500, seed=3, null_keys=False, null_vals=False):
+    rng = np.random.default_rng(seed)
+    flag = rng.choice(["A", "N", "R"], n).tolist()
+    status = rng.choice(["F", "O"], n).tolist()
+    qty = rng.integers(1, 51, n).astype(np.float64)
+    price = np.round(rng.uniform(900, 105000, n), 2)
+    if null_keys:
+        flag[::7] = [None] * len(flag[::7])
+    qty_arr = pa.array(qty, mask=(np.arange(n) % 5 == 0) if null_vals
+                       else None)
+    return pa.table({"flag": flag, "status": status, "qty": qty_arr,
+                     "price": pa.array(price)})
+
+
+def _q1_aggs(b, col=ccol):
+    # each spec compiles its argument anew, as the executors do: the lanes
+    # are shared by what the expressions compute, not by object identity
+    qty, price = (lambda: col(b, 2)), (lambda: col(b, 3))
+    return [AggSpec(AggFunc.SUM, qty(), T.FLOAT64, None),
+            AggSpec(AggFunc.SUM, price(), T.FLOAT64, None),
+            AggSpec(AggFunc.AVG, qty(), T.FLOAT64, None),
+            AggSpec(AggFunc.AVG, price(), T.FLOAT64, None),
+            AggSpec(AggFunc.COUNT, qty(), T.INT64, None),
+            AggSpec(AggFunc.MIN, price(), T.FLOAT64, None),
+            AggSpec(AggFunc.MAX, qty(), T.FLOAT64, None),
+            AggSpec(AggFunc.COUNT_STAR, None, T.INT64, None)]
+
+
+_Q1_NAMES = ["flag", "status", "sq", "sp", "aq", "ap", "cq", "mp", "xq", "n"]
+
+
+class TestDirectAggregate:
+    def test_key_without_null_lane_yields_no_null_group(self):
+        """Two dictionary keys without null lanes: 6 feasible segments of a
+        padded 16, no NULL group, the sort path's rows; SUM and AVG of one
+        argument share a lane and COUNT(*) shares the live count."""
+        direct, by_sort, d, dims = _direct_and_sorted(
+            _q1_like(), [0, 1], _q1_aggs, _Q1_NAMES)
+        assert dims == ((4, 0), (3, 0)) and direct.capacity == 16
+        assert d.get("agg.onepass_segments") == 6
+        # live count, sum(qty), sum(price) | min(price), max(qty) | 2 positions
+        assert d.get("agg.onepass_lanes") == 3 + 2 + 2
+        got = _rows(direct)
+        assert len(got) == 6 and all(r[0] is not None and r[1] is not None
+                                     for r in got)
+        want = _rows(by_sort)
+        for g, w in zip(got, want):
+            assert g[:2] == w[:2] and g[6:] == w[6:]
+            np.testing.assert_allclose(g[2:6], w[2:6], rtol=1e-12)
+
+    def test_key_with_null_lane_keeps_its_null_group(self):
+        direct, by_sort, d, _ = _direct_and_sorted(
+            _q1_like(null_keys=True), [0, 1], _q1_aggs, _Q1_NAMES)
+        # digit 0 of `flag` is feasible now: 4 x 2 ids
+        assert d.get("agg.onepass_segments") == 8
+        got, want = _rows(direct), _rows(by_sort)
+        assert sum(r[0] is None for r in got) == 2 and len(got) == 8
+        for g, w in zip(got, want):
+            assert g[:2] == w[:2] and g[6:] == w[6:]
+            np.testing.assert_allclose(g[2:6], w[2:6], rtol=1e-12)
+
+    def test_null_arguments_count_apart_from_live_rows(self):
+        """An argument with a null lane gets a valid-count lane of its own;
+        its COUNT differs from COUNT(*), both equal the sort path's."""
+        direct, by_sort, d, _ = _direct_and_sorted(
+            _q1_like(null_vals=True), [0, 1], _q1_aggs, _Q1_NAMES)
+        assert d.get("agg.onepass_lanes") == 4 + 2 + 2
+        got, want = _rows(direct), _rows(by_sort)
+        assert any(r[6] != r[9] for r in got)
+        for g, w in zip(got, want):
+            assert g[:2] == w[:2] and g[6:] == w[6:]
+            np.testing.assert_allclose(g[2:6], w[2:6], rtol=1e-12)
+
+    def test_all_null_argument_is_sum_null_count_zero(self):
+        t = pa.table({"k": ["a", "b", "a", "b"],
+                      "v": pa.array([None, 1.5, None, 2.5])})
+
+        def aggs(b):
+            v = ccol(b, 1)
+            return [AggSpec(AggFunc.SUM, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.COUNT, v, T.INT64, None),
+                    AggSpec(AggFunc.AVG, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.MIN, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.COUNT_STAR, None, T.INT64, None)]
+        direct, by_sort, _, _ = _direct_and_sorted(
+            t, [0], aggs, ["k", "s", "c", "a", "m", "n"])
+        assert _rows(direct) == _rows(by_sort) == [
+            ("a", None, 0, None, None, 2), ("b", 4.0, 2, 2.0, 1.5, 2)]
+
+    def test_specs_without_fingerprint_share_nothing_and_agree(self):
+        """Arguments built by hand (`Compiled.expr` is None, as the mesh
+        tier's final stage builds them): every spec reduces lanes of its
+        own; the answers are the fingerprinted ones."""
+        def bare(b):
+            return _q1_aggs(b, col=col)
+        named, _, d1, _ = _direct_and_sorted(_q1_like(), [0, 1], _q1_aggs,
+                                             _Q1_NAMES)
+        alone, _, d2, _ = _direct_and_sorted(_q1_like(), [0, 1], bare,
+                                             _Q1_NAMES)
+        assert d2.get("agg.onepass_lanes") > d1.get("agg.onepass_lanes")
+        assert _rows(named) == _rows(alone)
+
+    def test_min_max_nan_winner_on_the_direct_path(self):
+        """MAX over floats orders NaN above +inf and returns the NaN itself
+        (exact gather of the winning row), per group, as the sort path does."""
+        t = pa.table({"k": ["a", "a", "b", "b", "b"],
+                      "v": pa.array([1.0, float("nan"), float("inf"), 2.0,
+                                     -3.0])})
+
+        def aggs(b):
+            v = ccol(b, 1)
+            return [AggSpec(AggFunc.MAX, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.MIN, v, T.FLOAT64, None)]
+        direct, by_sort, _, _ = _direct_and_sorted(t, [0], aggs,
+                                                   ["k", "mx", "mn"])
+        got = to_arrow(direct).to_pydict()
+        by = dict(zip(got["k"], zip(got["mx"], got["mn"])))
+        assert np.isnan(by["a"][0]) and by["a"][1] == 1.0
+        assert by["b"] == (float("inf"), -3.0)
+        assert repr(_rows(direct)) == repr(_rows(by_sort))
+
+    def test_dense_integer_domain_above_the_threshold_still_scatters(self):
+        """A key domain over SMALL_NSEG segments keeps jax.ops.segment_*:
+        no one-pass counter moves, the answers equal the sort path's."""
+        rng = np.random.default_rng(11)
+        n = 2000
+        t = pa.table({"k": pa.array(rng.integers(0, 300, n), type=pa.int32()),
+                      "v": pa.array(rng.standard_normal(n))})
+
+        def aggs(b):
+            v = ccol(b, 1)
+            return [AggSpec(AggFunc.SUM, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.AVG, v, T.FLOAT64, None),
+                    AggSpec(AggFunc.MAX, v, T.FLOAT64, None)]
+        from igloo_tpu.exec.aggregate import seg_dims_for
+        from igloo_tpu.utils import tracing
+        b = from_arrow(t)
+        g = [Compiled(col(b, 0).fn, T.INT32, None, out_bounds=(0, 299))]
+        schema = out_schema_for(g, aggs(b), b, ["k", "s", "a", "m"])
+        dims = seg_dims_for(g)
+        assert dims == ((301, 0),)
+        with tracing.counter_delta() as d:
+            direct = aggregate_batch(b, g, aggs(b), schema, seg_dims=dims)
+        assert not d.get("agg.onepass_segments")
+        assert not d.get("agg.onepass_lanes")
+        want = _rows(aggregate_batch(b, g, aggs(b), schema))
+        for gr, w in zip(_rows(direct), want):
+            assert gr[0] == w[0] and gr[3] == w[3]
+            np.testing.assert_allclose(gr[1:3], w[1:3], rtol=1e-12)
+
+
+# --- what identifies an aggregate argument's lane (REVIEW of PR 33) ---
+
+def _bound(e, dtype):
+    e.dtype = dtype
+    return e
+
+
+def _like_flag(neg=False, ci=False, pattern="A%", index=1):
+    """CASE WHEN s LIKE <pattern> THEN 1 ELSE 0 END over column `index`,
+    bound by hand; every variant PRINTED alike before Like's repr named
+    `negated` and `case_insensitive`."""
+    from igloo_tpu.plan.expr import Case, Like, Literal
+    like = _bound(Like(_bound(Column("s", index=index), T.STRING), pattern,
+                       negated=neg, case_insensitive=ci), T.BOOL)
+    return _bound(Case([(like, _bound(Literal(1, T.INT64), T.INT64))],
+                       _bound(Literal(0, T.INT64), T.INT64)), T.INT64)
+
+
+_WORDS = ["Apple", "apricot", "Banana", "avocado", "Axe"]
+
+
+def _words_table(n=400, seed=1):
+    rng = np.random.default_rng(seed)
+    return pa.table({"k": rng.choice(["x", "y", "z"], n).tolist(),
+                     "s": rng.choice(_WORDS, n).tolist(),
+                     "s2": rng.choice(_WORDS, n).tolist()})
+
+
+@pytest.mark.parametrize("other", [dict(neg=True), dict(ci=True),
+                                   dict(neg=True, ci=True), dict(index=2)],
+                         ids=["not_like", "ilike", "not_ilike", "other_column"])
+def test_direct_aggregate_keeps_arguments_that_print_alike_apart(other):
+    """SUM(CASE WHEN s LIKE 'A%' ...) beside the same CASE over NOT LIKE,
+    ILIKE, or a column of another index under the same name, under one
+    small-domain key: each keeps a lane of its own (its sum differs from the
+    first's) and equals the sort path; the LIKE handed over twice shares."""
+    import copy
+    from igloo_tpu.exec.aggregate import seg_dims_for
+    from igloo_tpu.plan.expr import fingerprint
+    from igloo_tpu.utils import tracing
+    b = from_arrow(_words_table())
+    comp = ExprCompiler.for_batch(b)
+    exprs = [_like_flag(), _like_flag(**other), copy.deepcopy(_like_flag())]
+    assert fingerprint(exprs[0]) == fingerprint(exprs[2])
+    assert fingerprint(exprs[0]) != fingerprint(exprs[1])
+    aggs = [AggSpec(AggFunc.SUM, comp.compile(e), T.INT64, None)
+            for e in exprs]
+    g = [col(b, 0)]
+    schema = out_schema_for(g, aggs, b, ["k", "a", "b", "a2"])
+    consts = comp.pool.device_args()  # the LIKE lookup tables
+    with tracing.counter_delta() as d:
+        direct = aggregate_batch(b, g, aggs, schema, consts,
+                                 seg_dims=seg_dims_for(g))
+    # the live count, and a sum and a non-NULL count (a CASE computes a null
+    # lane of its own) for TWO arguments: the third shares the first's
+    assert d.get("agg.onepass_lanes") == 1 + 2 * 2
+    got = _rows(direct)
+    assert got == _rows(aggregate_batch(b, g, aggs, schema, consts))
+    assert all(r[1] == r[3] for r in got)
+    assert any(r[1] != r[2] for r in got)
+
+
+@pytest.mark.parametrize("route", ["fused", "staged"])
+def test_like_variants_in_one_group_by_through_sql(route):
+    """The reviewer's query: LIKE, NOT LIKE and ILIKE arguments of one
+    small-domain GROUP BY, through both compilers, against plain Python."""
+    from igloo_tpu.engine import QueryEngine
+    from igloo_tpu.exec.executor import Executor
+    from igloo_tpu.utils import tracing
+    t = _words_table()
+    e = QueryEngine()
+    e.register_table("t", t)
+    sql = ("SELECT k, SUM(CASE WHEN s LIKE 'A%' THEN 1 ELSE 0 END) AS a, "
+           "SUM(CASE WHEN s NOT LIKE 'A%' THEN 1 ELSE 0 END) AS b, "
+           "SUM(CASE WHEN s ILIKE 'a%' THEN 1 ELSE 0 END) AS c, "
+           "SUM(CASE WHEN s LIKE 'A%' THEN 1 ELSE 0 END) AS a2, "
+           "COUNT(*) AS n FROM t GROUP BY k ORDER BY k")
+    with tracing.counter_delta() as d:
+        if route == "fused":
+            got = e.execute(sql).to_pydict()
+        else:
+            ex = Executor(e._jit_cache, batch_cache=e.batch_cache)
+            got = ex._staged_to_arrow(e.plan(sql)).to_pydict()
+    # the live count; a sum and a non-NULL count for `a` with `a2`, `b`, `c`
+    assert d.get("agg.onepass_lanes") == 1 + 3 * 2
+    rows = list(zip(t["k"].to_pylist(), t["s"].to_pylist()))
+    for i, k in enumerate(got["k"]):
+        mine = [w for kk, w in rows if kk == k]
+        a = sum(w.startswith("A") for w in mine)
+        assert (got["a"][i], got["a2"][i]) == (a, a)
+        assert got["b"][i] == len(mine) - a
+        assert got["c"][i] == sum(w.lower().startswith("a") for w in mine)
+        assert got["n"][i] == len(mine)
+
+
+@pytest.mark.parametrize("second,want", [
+    ("s NOT LIKE 'A%'", lambda w: not w.startswith("A")),
+    ("s ILIKE 'A%'", lambda w: w.lower().startswith("a"))])
+def test_program_key_tells_like_from_not_like(second, want):
+    """One engine, `WHERE s LIKE 'A%'` and then its variant: before Like's
+    repr named every field the two shared a program key, and the second
+    query ran the first's program (172 where 228 rows match)."""
+    from igloo_tpu.engine import QueryEngine
+    t = _words_table()
+    e = QueryEngine()
+    e.register_table("t", t)
+    words = t["s"].to_pylist()
+    first = e.execute("SELECT COUNT(*) AS n FROM t WHERE s LIKE 'A%'")
+    assert first.to_pydict()["n"] == [sum(w.startswith("A") for w in words)]
+    got = e.execute(f"SELECT COUNT(*) AS n FROM t WHERE {second}")
+    assert got.to_pydict()["n"] == [sum(map(want, words))]
+
+
+def _fp_pairs():
+    from igloo_tpu.plan.expr import (Cast, InList, IsNull, Like, Literal,
+                                     ScalarSubquery)
+    s = lambda i=0: _bound(Column("s", index=i), T.STRING)  # noqa: E731
+    return {
+        "column_index": (s(0), s(1)),
+        "literal_type": (Literal(9000, T.INT32), Literal(9000, T.DATE32)),
+        "literal_int_float_bool": (Literal(1), Literal(1.0)),
+        "literal_bool_int": (Literal(True), Literal(1)),
+        "literal_signed_zero": (Literal(0.0), Literal(-0.0)),
+        "bound_dtype": (_bound(Literal(1), T.INT32), _bound(Literal(1), T.INT64)),
+        "like_negated": (Like(s(), "A%"), Like(s(), "A%", negated=True)),
+        "like_ci": (Like(s(), "A%"), Like(s(), "A%", case_insensitive=True)),
+        "isnull_negated": (IsNull(s()), IsNull(s(), negated=True)),
+        "inlist_negated": (InList(s(), [Literal("a")]),
+                           InList(s(), [Literal("a")], negated=True)),
+        "cast_target": (Cast(s(), T.INT32), Cast(s(), T.INT64)),
+        "subquery_identity": (ScalarSubquery(object()),
+                              ScalarSubquery(object())),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_fp_pairs()))
+def test_fingerprint_reads_every_field(case):
+    """Pairs that differ in one field — several of them PRINT alike, which
+    is why a repr cannot say that two arguments share a lane — never share a
+    fingerprint; a deep copy always does."""
+    import copy
+    from igloo_tpu.plan.expr import fingerprint
+    a, b = _fp_pairs()[case]
+    assert fingerprint(a) != fingerprint(b)
+    if case != "subquery_identity":  # a subquery's AST equals nothing
+        assert fingerprint(a) == fingerprint(copy.deepcopy(a))
+    assert isinstance(hash(fingerprint(a)), int)  # a dict key
+
+
+@pytest.mark.parametrize("route", ["fused", "staged"])
+def test_q1_sf001_equals_pandas_on_both_routes(route):
+    """TPC-H q1 at SF0.01 through the fused program and through the staged
+    executor: the direct one-pass aggregate against the pandas oracle."""
+    import datetime as dt
+    from igloo_tpu.bench.tpch import QUERIES, gen_tables, register_all
+    from igloo_tpu.engine import QueryEngine
+    from igloo_tpu.exec.executor import Executor
+    from igloo_tpu.utils import tracing
+    tables = gen_tables(sf=0.01, seed=11)
+    e = QueryEngine()
+    register_all(e, tables)
+    with tracing.counter_delta() as d:
+        if route == "fused":
+            got = e.execute(QUERIES["q1"]).to_pandas()
+            assert d.get("fused.execute") >= 1
+        else:
+            ex = Executor(e._jit_cache, batch_cache=e.batch_cache)
+            got = ex._staged_to_arrow(e.plan(QUERIES["q1"])).to_pandas()
+    # q1's five distinct float64 sum lanes and ONE count lane, 6 of 16 ids
+    assert d.get("agg.onepass_segments") == 6
+    assert d.get("agg.onepass_lanes") == 6
+    li = tables["lineitem"].to_pandas()
+    f = li[li.l_shipdate <= dt.date(1998, 12, 1) - dt.timedelta(days=90)]
+    f = f.assign(dp=f.l_extendedprice * (1 - f.l_discount))
+    f = f.assign(ch=f.dp * (1 + f.l_tax))
+    want = f.groupby(["l_returnflag", "l_linestatus"]).agg(
+        sum_qty=("l_quantity", "sum"), sum_base_price=("l_extendedprice", "sum"),
+        sum_disc_price=("dp", "sum"), sum_charge=("ch", "sum"),
+        avg_qty=("l_quantity", "mean"), avg_price=("l_extendedprice", "mean"),
+        avg_disc=("l_discount", "mean"), count_order=("l_quantity", "size"),
+    ).reset_index().sort_values(["l_returnflag", "l_linestatus"])
+    assert got["l_returnflag"].tolist() == want["l_returnflag"].tolist()
+    assert got["l_linestatus"].tolist() == want["l_linestatus"].tolist()
+    assert got["count_order"].tolist() == want["count_order"].tolist()
+    for c in ("sum_qty", "sum_base_price", "sum_disc_price", "sum_charge",
+              "avg_qty", "avg_price", "avg_disc"):
+        np.testing.assert_allclose(got[c], want[c], rtol=1e-9)

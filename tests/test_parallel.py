@@ -97,6 +97,24 @@ def test_sharded_agg_nulls(engine, mesh):
           "AVG(v) AS av FROM nl GROUP BY k ORDER BY k NULLS FIRST")
 
 
+def test_sharded_agg_arguments_that_print_alike(engine, mesh):
+    """LIKE, NOT LIKE and ILIKE arguments of one small-domain GROUP BY keep
+    lanes of their own in the per-shard partial aggregate (they printed
+    alike before Like's repr named every field; what shares a lane is
+    decided once, in `_direct_aggregate`, by `E.fingerprint`)."""
+    sql = ("SELECT grp, SUM(CASE WHEN name LIKE 'n0%' THEN 1 ELSE 0 END) AS a, "
+           "SUM(CASE WHEN name NOT LIKE 'n0%' THEN 1 ELSE 0 END) AS b, "
+           "SUM(CASE WHEN name ILIKE 'N0%' THEN 1 ELSE 0 END) AS c, "
+           "SUM(CASE WHEN name LIKE 'N0%' THEN 1 ELSE 0 END) AS d "
+           "FROM d GROUP BY grp ORDER BY grp")
+    got = ShardedExecutor(mesh=mesh).execute_to_arrow(engine.plan(sql))
+    # names n00..n39, grp = i % 5: two of each group's eight start with n0
+    assert got.to_pydict() == {"grp": [f"g{i}" for i in range(5)],
+                               "a": [2] * 5, "b": [6] * 5, "c": [2] * 5,
+                               "d": [0] * 5}
+    check(engine, mesh, sql)
+
+
 def test_sharded_agg_skewed_groups_overflow_rerun(engine, mesh):
     # 90% of rows share one key: per-device buckets overflow, the deferred
     # overflow flag fires, and the executor re-runs in exact mode

@@ -3,7 +3,7 @@
 The TPU compiler is installed with jaxlib and compiles for a chip that is
 described, not attached (`jax.experimental.topologies`). These tests compile
 two plain XLA stages of the main path at the shapes TPC-H SF1 runs them at,
-for one chip of a `v5e:2x2` host. A kernel written for Mosaic (ROADMAP A8)
+and q1's small-domain aggregate, for one chip of a `v5e:2x2` host. A kernel written for Mosaic (ROADMAP A8)
 is held to the compiler here, the same way: lower it with `one_chip`
 shardings and look for `tpu_custom_call` in `compiled.as_text()`. The six
 hand-written kernels this file used to hold to the compiler were all refused
@@ -21,6 +21,7 @@ cumsum 77 s and the float64 cumsum 350 s (PERF.md, "compile time of plain
 stages").
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,3 +88,96 @@ def test_gather_stage_compiles(one_chip):
         [((LANES,), jnp.int64), ((LANES,), jnp.float64),
          ((LANES,), jnp.int32)], one_chip)
     assert c.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+# --- q1's aggregate: the one-pass small-domain reduce (ISSUE 33; ~10-20 s) --
+
+def _q1_shaped_aggregate():
+    """`aggregate_batch` as q1's scan fragment calls it, as a function of
+    plain lanes: two dictionary keys without null lanes (3 x 2 values), four
+    float64 columns, the eight aggregates of q1, each argument compiled from
+    its bound expression as the compilers do (five distinct ones: the AVGs
+    repeat three of the SUMs'), `seg_dims` ((4, 0), (3, 0))."""
+    from igloo_tpu import types as T
+    from igloo_tpu.exec.aggregate import AggSpec, aggregate_batch
+    from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
+    from igloo_tpu.exec.expr_compile import ExprCompiler
+    from igloo_tpu.plan.expr import AggFunc, Binary, BinOp, Column, Literal
+
+    dicts = [DictInfo.from_values("ANR"), DictInfo.from_values("FO")] + \
+        [None] * 4
+    names = ["flag", "status", "qty", "price", "disc", "tax"]
+    dtypes = [T.STRING] * 2 + [T.FLOAT64] * 4
+    in_schema = T.Schema([T.Field(n, d, False) for n, d in zip(names, dtypes)])
+    out_schema = T.Schema(
+        [T.Field("flag", T.STRING, True), T.Field("status", T.STRING, True)] +
+        [T.Field(f"a{i}", T.FLOAT64, True) for i in range(7)] +
+        [T.Field("n", T.INT64, False)])
+
+    def bound(e, dtype=T.FLOAT64):
+        e.dtype = dtype
+        return e
+
+    def col(name):
+        i = names.index(name)
+        return bound(Column(name, index=i), dtypes[i])
+
+    def one(op, name):  # 1 <op> column
+        return bound(Binary(op, bound(Literal(1.0, T.FLOAT64)), col(name)))
+
+    def disc_price():
+        return bound(Binary(BinOp.MUL, col("price"), one(BinOp.SUB, "disc")))
+
+    args = {"qty": lambda: col("qty"), "price": lambda: col("price"),
+            "disc": lambda: col("disc"), "disc_price": disc_price,
+            "charge": lambda: bound(Binary(BinOp.MUL, disc_price(),
+                                           one(BinOp.ADD, "tax")))}
+    comp = ExprCompiler(dicts)
+    specs = [AggSpec(f, comp.compile(args[a]()), T.FLOAT64, None) for f, a in [
+        (AggFunc.SUM, "qty"), (AggFunc.SUM, "price"),
+        (AggFunc.SUM, "disc_price"), (AggFunc.SUM, "charge"),
+        (AggFunc.AVG, "qty"), (AggFunc.AVG, "price"), (AggFunc.AVG, "disc")]]
+    specs.append(AggSpec(AggFunc.COUNT_STAR, None, T.INT64, None))
+    groups = [comp.compile(col("flag")), comp.compile(col("status"))]
+
+    def fn(flag_ids, status_ids, qty, price, disc, tax, live):
+        lanes = [flag_ids, status_ids, qty, price, disc, tax]
+        cols = [DeviceColumn(d, v, None, dic)
+                for d, v, dic in zip(dtypes, lanes, dicts)]
+        out = aggregate_batch(DeviceBatch(in_schema, cols, live), groups,
+                              specs, out_schema, comp.pool.device_args(),
+                              seg_dims=((4, 0), (3, 0)))
+        return [(c.values, c.nulls) for c in out.columns], out.live
+    return fn
+
+
+# distinct lanes q1 hands to the one-pass reduce: five float64 sums (the AVGs
+# repeat three of them) and the live count (COUNT(*) and every valid-count)
+Q1_LANES = 6
+# segment ids that can hold a row: (4 - 1) x (3 - 1) of the padded 16
+Q1_SEGMENTS = 6
+# `bytes accessed` of this aggregate on the parent of PR 33 (f3009b3: one
+# select-reduce per aggregate lane and padded segment, 81 reduce fusions, one
+# fusion writing the 16 `pred[LANES]` masks), compiled once on a copy of it
+# for the described chip at LANES = 2^20. The change reads 5.6 % of it.
+Q1_PARENT_BYTES = 946_671_616
+
+
+def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
+    """ISSUE 33's guard that the one-pass reduce engages under the TPU
+    compiler: no fusion returns a `pred[LANES]` array (no segment mask
+    reaches HBM), the lanes are reduced by at most (distinct lanes + 2)
+    fusions and not by one per lane and segment, and the program reads under
+    a tenth of the bytes the per-segment loop read."""
+    shapes = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)] * 4 + \
+        [((LANES,), jnp.bool_)]
+    c = _lower_and_compile(_q1_shaped_aggregate(), shapes, one_chip)
+    text = c.as_text()
+    entry = text[text.index("ENTRY"):]
+    fusions = re.findall(r"^\s*(?:ROOT )?%?(\S*fusion\S*) = (.*?) fusion\(",
+                         entry, re.M)
+    assert fusions, "no fusion found in the entry computation"
+    assert not [n for n, shape in fusions if f"pred[{LANES}]" in shape]
+    reduces = [n for n, _ in fusions if "reduce" in n]
+    assert 1 <= len(reduces) <= Q1_LANES + 2, reduces
+    assert c.cost_analysis()["bytes accessed"] < Q1_PARENT_BYTES / 10
