@@ -70,6 +70,10 @@ class MemTable:
     def num_partitions(self) -> int:
         return self._partitions
 
+    def surviving_parts(self, filters, partition=None):
+        """Filters prune nothing here (exec/cache.py read_identity)."""
+        return None
+
     def read_partition(self, index: int, projection=None, filters=None) -> pa.Table:
         n = self._table.num_rows
         per = (n + self._partitions - 1) // self._partitions if n else 0

@@ -47,6 +47,7 @@ class ParquetTable:
         self.path = path
         self._store = store if store is not None else local_store()
         self._parts = None  # lazy (file, row_group) partition index
+        self._pruned: dict = {}  # (file, etag, predicates) -> groups kept
         self._plock = threading.Lock()  # guards _files/_parts (Flight threads)
         self._files = _expand_store(self._store, path)
         if not self._files:
@@ -128,6 +129,33 @@ class ParquetTable:
 
     def estimated_bytes(self) -> Optional[int]:
         return self._store.files_bytes(self._files)
+
+    def surviving_parts(self, filters, partition=None):
+        """What a read of `partition` (None: the table) under `filters`
+        returns, for the name of what a scan cache keeps of it
+        (exec/cache.py read_identity): None when the filters prune no row
+        group — uniform data, every TPC-H table — else the (file, row group)
+        pairs that survive. Pruning is remembered per file version and
+        predicate set: a resident table asks once per literal set."""
+        preds = _simple_preds(filters)
+        if not preds:
+            return None
+        _tok, etags = _snapshot.pin(self, self._snapshot_now)
+        index = self._partition_index()
+        want = index if partition is None else \
+            [index[i] for i in partition if i < len(index)]
+        alive: dict = {}
+        for path in dict.fromkeys(f for f, _ in want):
+            key = (path, etags.get(path), preds)
+            if key not in self._pruned:
+                if len(self._pruned) >= 256:
+                    self._pruned.clear()
+                self._pruned[key] = _prune_by(
+                    pq.ParquetFile(self._open(path)).metadata, preds)
+            alive[path] = self._pruned[key]
+        kept = tuple((f, rg) for f, rg in want
+                     if alive[f] is None or rg in alive[f])
+        return None if len(kept) == len(want) else kept
 
     def _open(self, path: str):
         """Open one data file for verified ranged reads: the etag pinned by
@@ -256,16 +284,17 @@ def _prune_row_groups(pf: pq.ParquetFile, filters) -> Optional[list[int]]:
     """Row-group pruning from column statistics for simple `col <op> literal`
     predicates. Best-effort: returning None means read everything (the engine
     re-applies every filter exactly)."""
-    if not filters:
-        return None
-    preds = []
-    for f in filters:
-        p = _simple_pred(f)
-        if p is not None:
-            preds.append(p)
-    if not preds:
-        return None
-    meta = pf.metadata
+    preds = _simple_preds(filters)
+    return _prune_by(pf.metadata, preds) if preds else None
+
+
+def _simple_preds(filters) -> tuple:
+    return tuple(p for p in map(_simple_pred, filters or ()) if p is not None)
+
+
+def _prune_by(meta, preds) -> Optional[list[int]]:
+    """The row groups of a file whose statistics admit every `preds`
+    (`_simple_pred` triples), or None when all do."""
     name_to_idx = {meta.schema.column(i).path: i
                    for i in range(meta.num_columns)}
     keep = []

@@ -312,6 +312,7 @@ class QueryEngine:
         stats mis-route plans, never break them."""
         from igloo_tpu.exec import hints
         peak = 0
+        root_fp = hints.plan_fp(plan) if plan is not None else None
         if qs is not None:
             # watchtower baseline check (docs/observability.md#watchtower):
             # BEFORE the adaptive gate — the anomaly detector is independent
@@ -322,7 +323,7 @@ class QueryEngine:
             # recorder below.
             peak = stats.device_peak_hbm_bytes()
             watch.check_query(
-                hints.plan_fp(plan) if plan is not None else None,
+                root_fp,
                 qs.elapsed_s, qs=qs, qid=str(qs.qid or ""),
                 trace_id=qs.trace_id or "", sql=qs.sql, tier=qs.tier,
                 hbm_bytes=(float(peak - peak_hbm0)
@@ -330,7 +331,6 @@ class QueryEngine:
         if qs is None or not hints.adaptive_enabled():
             return
         obs = {k: n for k, n in qs.observations if k is not None}
-        root_fp = hints.plan_fp(plan) if plan is not None else None
         if root_fp is not None and qs.rows is not None:
             obs[root_fp] = int(qs.rows)
         # device-memory watermark for the admission gate (docs/serving.md).

@@ -506,8 +506,11 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
         if spec.func is AggFunc.COUNT_STAR:
             plans.append(None)
             continue
-        # a Compiled built by hand says nothing of what it computes: by index
-        akey = fingerprint(spec.arg.expr) if spec.arg.expr is not None else i
+        # a Compiled built by hand says nothing of what it computes, and one
+        # that binds a literal computes it from a value no key holds: each
+        # by its index. AVG(x) beside SUM(x) is what shares.
+        akey = fingerprint(spec.arg.expr) if spec.arg.expr is not None \
+            and not spec.arg.literals else i
         if akey not in args:
             v, nl = spec.arg.fn(env)
             if nl is None:

@@ -174,12 +174,17 @@ class BatchCache(SnapshotLRU):
     """HBM scan cache. Two entry shapes, both with key[0] = table name:
 
     - column-granular (providers with stable row order):
-      (table, filter-fp, partition, 'col', name) -> (DeviceColumn, n_rows) and
-      (table, filter-fp, partition, 'live')      -> live lane array;
+      (table, what was read, partition, 'col', name) ->
+      (DeviceColumn, n_rows, the pushed filters it was loaded under) and
+      (table, what was read, partition, 'live') -> live lane array;
       scans assemble batches from these so overlapping projections share the
       uploaded lanes (written via `put_entry`).
     - whole-batch (order-unstable providers, e.g. DBAPI):
-      (table, projection, filter-fp, partition) -> DeviceBatch (via `put`)."""
+      (table, projection, what was read, partition) -> DeviceBatch (via
+      `put`).
+
+    "What was read" is `read_identity(plan)`: None wherever the pushed
+    filters pruned nothing."""
 
     counter_prefix = "cache"
 
@@ -211,6 +216,26 @@ class ResidentCache(BatchCache):
         if self._share is None:
             self._share = hbm_budgets()[0]
         return self._share
+
+
+def read_identity(plan) -> object:
+    """What names a scan's resident columns besides table, snapshot and
+    partition: what a read under the scan's pushed filters RETURNS, not the
+    text of the filters. The filters are pushed so that a provider may prune
+    (Parquet drops row groups by their statistics); the engine applies every
+    one again, exactly, in the program. A provider that prunes says which
+    parts survive (`surviving_parts`: None when all do — every TPC-H table —
+    and then no filter is in the name: two queries that read the same bytes
+    hold one copy, and a new literal loads nothing). A provider that does
+    not say may apply its filters to the rows (DBAPI renders a WHERE): there
+    the filters, values included, name the entry."""
+    if not plan.pushed_filters:
+        return None
+    surviving = getattr(plan.provider, "surviving_parts", None)
+    if surviving is not None:
+        return surviving(plan.pushed_filters, plan.partition)
+    from igloo_tpu.plan.expr import fingerprint
+    return fingerprint(plan.pushed_filters)
 
 
 def provider_snapshot(provider) -> object:

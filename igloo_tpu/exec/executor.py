@@ -112,7 +112,9 @@ def batch_proto_key(batch: DeviceBatch):
 
 
 def expr_fingerprint(exprs) -> str:
-    return "|".join(repr(e) for e in exprs)
+    """The key form of a staged program's expressions: `E.shape` (type and
+    position of a literal, not its value: the value is an argument)."""
+    return E.shape(exprs)
 
 
 def strip_dicts(batch: DeviceBatch) -> DeviceBatch:
@@ -229,9 +231,20 @@ class Executor:
     # --- cache helpers ---
 
     def _jitted(self, kind: str, fingerprint, build: Callable[[], Callable],
-                static_argnums=()) -> Callable:
+                static_argnums=(), pool: Optional[ConstPool] = None) -> Callable:
+        """`pool`: the constants the program is about to be called with. Its
+        literal values are in no key (`E.shape`); they are remembered beside
+        the program, and a dispatch that finds the program under other
+        values than its last one ran with is counted
+        (`program.literal_shared`: each was a trace and a compile while a
+        key held the value)."""
         key = (kind, fingerprint)
         fn = self._cache.get(key)
+        lits = pool.literal_values() if pool is not None else ()
+        if lits:
+            if fn is not None and self._cache.get(("literals",) + key) != lits:
+                tracing.counter("program.literal_shared")
+            self._cache[("literals",) + key] = lits
         if fn is None:
             tracing.counter("jit.miss")
             stats.bump_attr("jit_miss")
@@ -249,6 +262,17 @@ class Executor:
         tracing.counter("jit.hit")
         stats.bump_attr("jit_hit")
         return _Program(fn, kind, first=False)
+
+    def _bind(self, pool: ConstPool, *batches) -> tuple:
+        """A dispatch's arguments -> (*batches without their host metadata,
+        the constants pool on the device: LUTs, pack offsets, the literals'
+        scalars). One span, or a query's time here reads as `execute` self
+        time."""
+        with tracing.span("program.bind_args"):
+            if pool.n_scalars:
+                tracing.counter("program.literal_args", pool.n_scalars)
+            return tuple(strip_dicts(b) for b in batches) + \
+                (pool.device_args(),)
 
     # --- entry ---
 
@@ -372,12 +396,11 @@ class Executor:
                 raise FusionUnsupported("nofuse_sentinel")
             self._hints.put(sentinel, strikes + 1)
             self._hints.flush()
-        jf = self._jitted("fused", key, lambda: run)
+        jf = self._jitted("fused", key, lambda: run, pool=comp.pool)
         tracing.counter("fused.execute")
         try:
-            big, spec, n_dev, flags, stats_dev = jf(
-                [strip_dicts(b) for b in comp.leaves],
-                comp.pool.device_args())
+            *leaves, consts = self._bind(comp.pool, *comp.leaves)
+            big, spec, n_dev, flags, stats_dev = jf(leaves, consts)
         except BaseException as e:
             # an ordinary exception means the compile did NOT hang — clear
             # the strike so transient failures can't poison fusion forever
@@ -551,11 +574,12 @@ class Executor:
             # whole-batch cache entry.
             key = snap = None
             if self._batch_cache is not None:
-                from igloo_tpu.exec.cache import provider_snapshot
+                from igloo_tpu.exec.cache import provider_snapshot, \
+                    read_identity
                 key = (plan.table,
                        tuple(plan.projection) if plan.projection is not None
                        else None,
-                       expr_fingerprint(plan.pushed_filters), plan.partition)
+                       read_identity(plan), plan.partition)
                 snap = provider_snapshot(plan.provider)
                 hit = self._batch_cache.get(key, snap)
                 if hit is not None:
@@ -569,23 +593,31 @@ class Executor:
             if self._batch_cache is not None:
                 self._batch_cache.put(key, batch, snap)
             return batch
-        # COLUMN-granular HBM cache: entries are per (table, filters,
-        # partition, column), so scans with different projections share the
-        # uploaded lanes they have in common: a 22-query sweep uploads each
-        # column at most once. Entry values are
-        # (DeviceColumn, n_rows); n makes the live lane reconstructible after
-        # its entry is evicted without re-reading a column.
-        from igloo_tpu.exec.cache import provider_snapshot
+        # COLUMN-granular HBM cache: entries are per (table, what was read,
+        # partition, column) — `read_identity`: the row groups that survived
+        # pruning, never the text of the filter that pruned — so scans with
+        # different projections or different literals share the uploaded
+        # lanes they have in common: a 22-query sweep uploads each column at
+        # most once. Entry values are (DeviceColumn, n_rows, the pushed
+        # filters it was loaded under); n makes the live lane
+        # reconstructible after its entry is evicted without re-reading a
+        # column.
+        from igloo_tpu.exec.cache import provider_snapshot, read_identity
         from igloo_tpu.exec.codec import live_lane
         snap = provider_snapshot(plan.provider)
         # the engine's host fast path executes small plans under
         # jax.default_device(cpu); its uploads must not alias the
         # accelerator-resident copies of the same columns
         dev = getattr(jax.config, "jax_default_device", None)
-        base = (plan.table, expr_fingerprint(plan.pushed_filters),
+        base = (plan.table, read_identity(plan),
                 plan.partition, str(dev) if dev is not None else "default")
         cached = {f.name: self._batch_cache.get(base + ("col", f.name), snap)
                   for f in plan.schema}
+        under = E.fingerprint(plan.pushed_filters)
+        shared = sum(1 for v in cached.values()
+                     if v is not None and v[2] != under)
+        if shared:
+            tracing.counter("cache.shared_by_filter", shared)
         live = self._batch_cache.get(base + ("live",), snap)
         missing = [f for f in plan.schema if cached[f.name] is None]
         known_n = next((v[1] for v in cached.values() if v is not None), None)
@@ -623,9 +655,10 @@ class Executor:
             for f, col in zip(missing, new_cols):
                 nbytes = col.values.nbytes + (
                     col.nulls.nbytes if col.nulls is not None else 0)
-                self._batch_cache.put_entry(base + ("col", f.name), (col, n),
-                                            snap, nbytes, plan.table)
-                cached[f.name] = (col, n)
+                self._batch_cache.put_entry(base + ("col", f.name),
+                                            (col, n, under), snap, nbytes,
+                                            plan.table)
+                cached[f.name] = (col, n, under)
             if live is None:
                 live = live_lane(cap, n)
                 self._batch_cache.put_entry(base + ("live",), (live, n), snap,
@@ -678,8 +711,8 @@ class Executor:
                     keep = keep & ~nl
                 return DeviceBatch(b.schema, b.columns, keep)
             return fn
-        out = self._jitted("filter", fp, build)(strip_dicts(batch),
-                                                comp.pool.device_args())
+        out = self._jitted("filter", fp, build, pool=comp.pool)(
+            *self._bind(comp.pool, batch))
         return attach_dicts(out, *col_meta(batch.columns))
 
     def _exec_project(self, plan: L.Project) -> DeviceBatch:
@@ -701,8 +734,8 @@ class Executor:
                     cols.append(DeviceColumn(f.dtype, v, nl, None))
                 return DeviceBatch(out_schema, cols, b.live)
             return fn
-        out = self._jitted("project", fp, build)(strip_dicts(batch),
-                                                 comp.pool.device_args())
+        out = self._jitted("project", fp, build, pool=comp.pool)(
+            *self._bind(comp.pool, batch))
         return attach_dicts(out, [cc.out_dict for cc in comps],
                     [cc.out_bounds for cc in comps])
 
@@ -755,8 +788,8 @@ class Executor:
                 return aggregate_batch(b, groups, specs, out_schema, consts,
                                        seg_dims=seg_dims, pack_spec=pack_spec)
             return fn
-        out = self._jitted("agg", fp, build)(strip_dicts(batch),
-                                             comp.pool.device_args())
+        out = self._jitted("agg", fp, build, pool=comp.pool)(
+            *self._bind(comp.pool, batch))
         stats.annotate(strategy="direct_scatter" if seg_dims is not None
                        else "packed_sort" if pack_spec is not None
                        else "lex_sort")
@@ -773,7 +806,7 @@ class Executor:
         keys, applying the distinct aggregates to the deduped x column and
         merging the plain partials. Only multiple DISTINCT arguments remain
         unsupported (they would need a null-safe join of per-arg results)."""
-        args = {repr(a.arg) for a in plan.aggs if a.distinct}
+        args = {E.fingerprint(a.arg) for a in plan.aggs if a.distinct}
         if len(args) > 1:
             raise NotSupportedError(
                 "multiple distinct aggregate arguments are not supported yet")
@@ -989,8 +1022,7 @@ class Executor:
             meta_cols = list(left.columns) + list(right.columns)
         dicts, bnds = col_meta(meta_cols)
 
-        ls, rs = strip_dicts(left), strip_dicts(right)
-        consts = pool.device_args()
+        ls, rs, consts = self._bind(pool, left, right)
 
         # direct "array join" fast path (exec/join.py): dense-integer PK-FK
         # joins become one scatter + one gather; a deferred duplicate flag
@@ -1050,7 +1082,7 @@ class Executor:
                 fn = self._jitted(
                     "join_direct",
                     (fpbase, plan.schema, side, blo, tsize, ki, want),
-                    build)
+                    build, pool=pool)
                 tracing.counter("join.direct")
                 stats.annotate(strategy="direct", build_side=side)
                 out, dup, n_dev, ovf = fn(
@@ -1086,7 +1118,7 @@ class Executor:
                 lambda: (lambda l, r, consts: semi_anti_phase(
                     l, r, use_lk, use_rk, lhx, rhx,
                     jt is JoinType.ANTI, residual, win, consts,
-                    pack_eq=pack_eq)))
+                    pack_eq=pack_eq)), pool=pool)
             tracing.counter("join.semi_sorted")
             stats.annotate(strategy="semi_sorted")
             out, truncated = fn(ls, rs, consts)
@@ -1102,7 +1134,8 @@ class Executor:
         p = self._jitted(
             "join_probe", fpbase,
             lambda: (lambda l, r, consts: probe_phase(
-                l, r, use_lk, use_rk, lhx, rhx, consts)))(ls, rs, consts)
+                l, r, use_lk, use_rk, lhx, rhx, consts)),
+            pool=pool)(ls, rs, consts)
         spec_cap = round_capacity(max(left.capacity, right.capacity))
         if (self._speculate and jt is not JoinType.CROSS
                 and spec_cap <= self._SPECULATIVE_JOIN_BUDGET):
@@ -1119,7 +1152,7 @@ class Executor:
             lambda: (lambda l, r, p, match_cap, consts: expand_phase(
                 l, r, p, match_cap, jt, residual, plan.schema, consts,
                 match_search=search)),
-            static_argnums=(3,))(ls, rs, p, match_cap, consts)
+            static_argnums=(3,), pool=pool)(ls, rs, p, match_cap, consts)
         out = attach_dicts(out, dicts[: len(out.columns)],
                            bnds[: len(out.columns)])
         if total is None:
@@ -1147,8 +1180,8 @@ class Executor:
                 return window_batch(b, pk, okeys, asc, nf, specs,
                                     plan.schema, consts)
             return fn
-        out = self._jitted("window", fp, build)(strip_dicts(batch),
-                                                comp.pool.device_args())
+        out = self._jitted("window", fp, build, pool=comp.pool)(
+            *self._bind(comp.pool, batch))
         dicts, bnds = col_meta(batch.columns)
         return attach_dicts(out, dicts + wdicts, bnds + wbounds)
 
@@ -1182,9 +1215,10 @@ class Executor:
                         return topk_batch(b, keys, consts, pack,
                                           limit, offset, out_cap)
                     return fn
+                tracing.counter("program.literal_keyed")  # k sizes lanes
                 out = self._jitted(
                     "topk", ("topk", fp_core, limit, offset, out_cap),
-                    tbuild)(strip_dicts(batch), comp.pool.device_args())
+                    tbuild, pool=comp.pool)(*self._bind(comp.pool, batch))
                 self._limit_taken = True
                 return attach_dicts(out, *col_meta(batch.columns))
         fp = ("sort", expr_fingerprint(res), tuple(plan.ascending),
@@ -1196,8 +1230,8 @@ class Executor:
                 return sort_batch(b, keys, plan.ascending, plan.nulls_first,
                                   consts, pack=pack)
             return fn
-        out = self._jitted("sort", fp, build)(strip_dicts(batch),
-                                              comp.pool.device_args())
+        out = self._jitted("sort", fp, build, pool=comp.pool)(
+            *self._bind(comp.pool, batch))
         return attach_dicts(out, *col_meta(batch.columns))
 
     def _exec_limit(self, plan: L.Limit) -> DeviceBatch:
@@ -1218,6 +1252,7 @@ class Executor:
         else:
             batch = self._exec(plan.input)
         fp = ("limit", plan.limit, plan.offset, batch_proto_key(batch))
+        tracing.counter("program.literal_keyed")  # the bounds mask by position
 
         def build():
             def fn(b):

@@ -26,6 +26,7 @@ import numpy as np
 from igloo_tpu import types as T
 from igloo_tpu.exec.batch import DeviceBatch, DictInfo, wide_values
 from igloo_tpu.plan import expr as E
+from igloo_tpu.utils import tracing
 
 
 class Env:
@@ -59,10 +60,18 @@ class ConstPool:
     recompile per new dictionary).
 
     Arrays are padded to power-of-two lengths so the (shape, dtype) signature —
-    which IS part of the cache key — buckets well."""
+    which IS part of the cache key — buckets well.
+
+    Scalar literals ride here too (`add_scalar`): one vector per lane dtype,
+    one position per literal of the expression, in compile order. Their
+    number and dtypes are in `signature()`; their values are arguments, so
+    two parameter sets of one query are one program (`plan.expr.shape`)."""
 
     def __init__(self):
-        self.arrays: list[np.ndarray] = []
+        # np.ndarray, or a _Scalars vector still being filled
+        self.arrays: list = []
+        self._scalars: dict = {}  # lane dtype name -> slot of its vector
+        self.n_scalars = 0        # literals bound so far, over all vectors
 
     # pad memo keyed on the SOURCE array's id (e.g. DictInfo.hashes, which is
     # stable for a table's lifetime): repeated queries re-adding the same host
@@ -94,15 +103,32 @@ class ConstPool:
                 padded = np.zeros((c0, c1), dtype=out.dtype)
                 padded[: out.shape[0], : out.shape[1]] = out
                 out = padded
-        if len(cls._PAD_MEMO) >= cls._PAD_MEMO_MAX:
-            for k in list(cls._PAD_MEMO)[: cls._PAD_MEMO_MAX // 2]:
-                del cls._PAD_MEMO[k]
-        cls._PAD_MEMO[key] = (arr, out)
+        _memo_put(cls._PAD_MEMO, key, (arr, out), cls._PAD_MEMO_MAX)
         return out
 
     def add(self, arr: np.ndarray) -> int:
         self.arrays.append(self._padded(arr))
         return len(self.arrays) - 1
+
+    def add_scalar(self, value, np_dtype: np.dtype) -> tuple:
+        """A literal's value as a runtime argument -> (slot, position): the
+        compiled expression reads `env.consts[slot][position]`. A position
+        per literal, never merged by value: `a > 5 AND b > 5` and
+        `a > 5 AND b > 6` are one program."""
+        name = np.dtype(np_dtype).name
+        slot = self._scalars.get(name)
+        if slot is None:
+            slot = self._scalars[name] = len(self.arrays)
+            self.arrays.append(_Scalars(np.dtype(np_dtype)))
+        vec = self.arrays[slot]
+        vec.values.append(vec.dtype.type(value))
+        self.n_scalars += 1
+        return slot, len(vec.values) - 1
+
+    def literal_values(self) -> tuple:
+        """Every scalar bound so far, by lane dtype, in compile order."""
+        return tuple((n, tuple(self.arrays[s].values))
+                     for n, s in self._scalars.items())
 
     def signature(self) -> tuple:
         return tuple((a.shape, str(a.dtype)) for a in self.arrays)
@@ -124,14 +150,49 @@ class ConstPool:
         if ent is not None and ent[0] is a:
             return ent[1]
         dev = jnp.asarray(a)
-        if len(cls._DEVICE_MEMO) >= cls._DEVICE_MEMO_MAX:
-            for k in list(cls._DEVICE_MEMO)[: cls._DEVICE_MEMO_MAX // 2]:
-                del cls._DEVICE_MEMO[k]
-        cls._DEVICE_MEMO[id(a)] = (a, dev)  # lint: allow(cache-key)
+        _memo_put(cls._DEVICE_MEMO, id(a), (a, dev),  # lint: allow(cache-key)
+                  cls._DEVICE_MEMO_MAX)
+        return dev
+
+    # scalar vectors by VALUE: the compiler builds a new vector per
+    # execution, and a dashboard's handful of parameter sets comes again
+    _SCALAR_MEMO: dict = {}
+
+    @classmethod
+    def _scalars_to_device(cls, vec: "_Scalars"):
+        host = np.asarray(vec.values, dtype=vec.dtype)
+        key = (vec.dtype.name, host.tobytes())
+        dev = cls._SCALAR_MEMO.get(key)
+        if dev is None:
+            dev = jnp.asarray(host)
+            _memo_put(cls._SCALAR_MEMO, key, dev, cls._DEVICE_MEMO_MAX)
         return dev
 
     def device_args(self) -> tuple:
-        return tuple(self._to_device(a) for a in self.arrays)
+        return tuple(self._scalars_to_device(a) if isinstance(a, _Scalars)
+                     else self._to_device(a) for a in self.arrays)
+
+
+def _memo_put(memo: dict, key, value, limit: int) -> None:
+    """Bounded FIFO insert: at `limit` entries the older half goes."""
+    if len(memo) >= limit:
+        for k in list(memo)[: limit // 2]:
+            del memo[k]
+    memo[key] = value
+
+
+class _Scalars:
+    """One lane dtype's literal values of a ConstPool, as the (n,) vector
+    they are passed as."""
+    __slots__ = ("dtype", "values")
+
+    def __init__(self, dtype: np.dtype):
+        self.dtype = dtype
+        self.values: list = []
+
+    @property
+    def shape(self) -> tuple:
+        return (len(self.values),)
 
 
 @dataclass
@@ -143,10 +204,15 @@ class Compiled:
     # refs / int literals); feeds the direct-join strategy choice. None = unknown.
     out_bounds: Optional[tuple] = None
     # the bound expression this was compiled from (ExprCompiler.compile sets
-    # it; None on a Compiled built by hand). Two Compileds of one compiler
-    # whose `E.fingerprint(expr)` are equal compute the same lanes: the direct
-    # aggregate reduces such arguments once.
+    # it; None on a Compiled built by hand), and how many of its literals
+    # became arguments of the program. Two Compileds of one compiler whose
+    # `E.fingerprint(expr)` are equal and that bind no literal compute the
+    # same lanes: the direct aggregate reduces such arguments once. One that
+    # binds a literal shares with nothing: its value is no part of the
+    # program's key, so the NEXT execution of the program may bring two
+    # values where this one brought one twice.
     expr: Optional[E.Expr] = None
+    literals: int = 0
 
 
 class ExprCompileError(Exception):
@@ -270,7 +336,19 @@ class ExprCompiler:
         m = getattr(self, "_c_" + type(e).__name__.lower(), None)
         if m is None:
             raise ExprCompileError(f"cannot compile {type(e).__name__}: {e!r}")
+        n0 = self.pool.n_scalars
         c = m(e)
+        c.expr, c.literals = e, self.pool.n_scalars - n0
+        return c
+
+    def compile_arg(self, e: E.Expr) -> Compiled:
+        """A direct argument of a function call: a literal there is read on
+        the host when the call is compiled (round's digits, substr's bounds),
+        so `E.shape` keeps its value in the program's key and it is traced
+        as a constant."""
+        if not isinstance(e, E.Literal):
+            return self.compile(e)
+        c = self._c_literal(e, static=True)
         c.expr = e
         return c
 
@@ -285,13 +363,25 @@ class ExprCompiler:
         return Compiled(lambda env: (env.values[idx], env.nulls[idx]), e.dtype,
                         d, out_bounds=b)
 
-    def _c_literal(self, e: E.Literal) -> Compiled:
+    def _c_literal(self, e: E.Literal, static: bool = False) -> Compiled:
         dt = e.dtype or e.literal_type
+        if not static and E.runtime_literal(e):
+            # the value is an ARGUMENT: a scalar of the pool, bound at
+            # dispatch; its lane dtype is in the pool's signature
+            np_dtype = dt.device_dtype()
+            slot, k = self.pool.add_scalar(e.value, np_dtype)
+            return Compiled(
+                lambda env: (jnp.broadcast_to(env.consts[slot][k],
+                                              (_cap(env),)), None), dt, None)
+        # the value is part of the program's shape, and of its key; NULL is
+        # not counted: it has no value that a parameter set could change
+        # (the planner writes one per AVG into every merge fragment)
         if e.value is None:
             return Compiled(
                 lambda env: (jnp.zeros_like(env.values[0] if env.values else jnp.zeros(1), dtype=jnp.int32),
                              jnp.ones(env.values[0].shape if env.values else (1,), dtype=bool)),
                 T.NULL, None)
+        tracing.counter("program.literal_keyed")
         if dt is not None and dt.is_string:
             dinfo = DictInfo.from_values([e.value])
             return Compiled(lambda env: (jnp.zeros(_cap(env), dtype=jnp.int32), None), dt, dinfo)
@@ -583,6 +673,7 @@ class ExprCompiler:
     def _c_inlist(self, e: E.InList) -> Compiled:
         c = self.compile(e.operand)
         neg = e.negated
+        tracing.counter("program.literal_keyed")  # the list's length
         has_null_item = any(isinstance(i, E.Literal) and i.value is None for i in e.items)
         items = [i for i in e.items if not (isinstance(i, E.Literal) and i.value is None)]
         if c.dtype.is_string:
@@ -631,6 +722,7 @@ class ExprCompiler:
         if not c.dtype.is_string:
             raise ExprCompileError("LIKE on non-string")
         rx = _like_to_regex(e.pattern.lower() if e.case_insensitive else e.pattern)
+        tracing.counter("program.literal_keyed")  # the pattern
         d = c.out_dict
         lut = np.zeros(max(len(d) if d else 0, 1), dtype=bool)
         for i, v in enumerate(d.values if d else []):
@@ -649,7 +741,7 @@ class ExprCompiler:
 
     def _c_func(self, e: E.Func) -> Compiled:
         name = e.name.lower()
-        args = [self.compile(a) for a in e.args]
+        args = [self.compile_arg(a) for a in e.args]
         if name in _STRING_FUNCS:
             return self._compile_string_func(name, e, args)
         if name in ("year", "month", "day", "extract_year", "extract_month", "extract_day"):

@@ -72,6 +72,7 @@ from igloo_tpu.exec.join import (
 from igloo_tpu.exec.sort_limit import (
     limit_batch, plan_topk, sort_batch, topk_batch,
 )
+from igloo_tpu.plan import expr as E
 from igloo_tpu.plan import logical as L
 from igloo_tpu.sql.ast import JoinType
 from igloo_tpu.utils import tracing
@@ -280,7 +281,7 @@ class FusedCompiler:
         ident = idx if getattr(plan.provider, "ephemeral", False) \
             else plan.table
         self._push(("scan", ident, tuple(plan.projection or ()),
-                    repr(plan.pushed_filters), plan.partition,
+                    E.shape(plan.pushed_filters), plan.partition,
                     plan.schema, batch.capacity,
                     tuple(c.nulls is not None for c in batch.columns),
                     tuple(canonical_direct_table(b[0], b[1])
@@ -304,7 +305,7 @@ class FusedCompiler:
         comp = self._compiler_for(meta)
         res, [c] = self._compile_exprs([plan.predicate], comp)
         self.marks.extend(comp.marks)
-        self._push(("filter", repr(res[0])))
+        self._push(("filter", E.shape(res[0])))
 
         def fn(leaves, consts, ctx):
             b = cfn(leaves, consts, ctx)
@@ -321,7 +322,7 @@ class FusedCompiler:
         comp = self._compiler_for(meta)
         res, comps = self._compile_exprs(plan.exprs, comp)
         self.marks.extend(comp.marks)
-        self._push(("project", tuple(repr(e) for e in res), plan.schema))
+        self._push(("project", E.shape(res), plan.schema))
         out_schema = plan.schema
 
         def fn(leaves, consts, ctx):
@@ -374,9 +375,7 @@ class FusedCompiler:
         # jfp_core is capacity-free: hint keys derive from it so that child
         # hint adoption (which shrinks child capacities) never changes this
         # join's hint key. The full jfp (with caps) keys programs/negatives.
-        jfp_core = ("join", tuple(repr(e) for e in lres),
-                    tuple(repr(e) for e in rres),
-                    tuple(repr(e) for e in rres2), jt)
+        jfp_core = ("join", E.shape(lres), E.shape(rres), E.shape(rres2), jt)
         jfp = jfp_core + (lmeta.capacity, rmeta.capacity)
 
         pick = None
@@ -549,7 +548,7 @@ class FusedCompiler:
             pack_spec = K.plan_group_packing(groups, self.pool)
             if pack_spec is not None:
                 tracing.counter("pack.agg")
-        fp = ("agg", tuple(repr(e) for e in gres + ares),
+        fp = ("agg", E.shape(gres + ares),
               tuple((a.func, a.dtype) for a in plan.aggs),
               plan.schema, seg_dims, pack_spec)
         self._push(fp)
@@ -613,7 +612,7 @@ class FusedCompiler:
                                      self.pool)
         if pack is not None:
             tracing.counter("pack.sort")
-        self._push(("sort", tuple(repr(e) for e in res),
+        self._push(("sort", E.shape(res),
                     tuple(plan.ascending), tuple(plan.nulls_first), pack))
         asc, nf = list(plan.ascending), list(plan.nulls_first)
 
@@ -626,12 +625,18 @@ class FusedCompiler:
         if isinstance(plan.input, L.Sort) and plan.limit is not None:
             return self._c_limit_sort(plan, plan.input)
         cfn, meta = self._c(plan.input)
-        self._push(("limit", plan.limit, plan.offset))
+        self._push_limit(plan)
 
         def fn(leaves, consts, ctx):
             return limit_batch(cfn(leaves, consts, ctx), plan.limit,
                                plan.offset)
         return fn, meta
+
+    def _push_limit(self, plan: L.Limit) -> None:
+        # LIMIT's bounds stay in the key: they mask by position, and a
+        # top-k's k sizes its output
+        tracing.counter("program.literal_keyed")
+        self._push(("limit", plan.limit, plan.offset))
 
     def _c_limit_sort(self, plan: L.Limit, sp: L.Sort):
         """ORDER BY + LIMIT fusion: where sort_limit.plan_topk says so, a
@@ -651,9 +656,9 @@ class FusedCompiler:
         asc, nf = list(sp.ascending), list(sp.nulls_first)
         k_total = plan.limit + plan.offset
         if not plan_topk(meta.capacity, k_total, pack, len(keys)):
-            self._push(("sort", tuple(repr(e) for e in res),
+            self._push(("sort", E.shape(res),
                         tuple(sp.ascending), tuple(sp.nulls_first), pack))
-            self._push(("limit", plan.limit, plan.offset))
+            self._push_limit(plan)
 
             def fn(leaves, consts, ctx):
                 b = sort_batch(cfn(leaves, consts, ctx), keys, asc, nf,
@@ -661,7 +666,8 @@ class FusedCompiler:
                 return limit_batch(b, plan.limit, plan.offset)
             return fn, meta
         out_cap = round_capacity(k_total)
-        self._push(("topk", tuple(repr(e) for e in res),
+        tracing.counter("program.literal_keyed")  # k sizes the output
+        self._push(("topk", E.shape(res),
                     tuple(sp.ascending), tuple(sp.nulls_first), pack,
                     plan.limit, plan.offset, out_cap))
 

@@ -277,57 +277,60 @@ def row_width_bytes(schema) -> int:
 # --- structural plan fingerprints -------------------------------------------
 
 
-def plan_fp(plan):
+def plan_fp(plan, exact: bool = False):
     """Projection-INSENSITIVE structural fingerprint of a logical subtree:
-    expressions repr by column NAME (not index), scans by (table, filters,
+    expressions by column NAME (not index), scans by (table, filters,
     partition). The same logical work keys the same entry whether observed
     pre- or post-pruning, on the host tier, the device tier, or a cluster
     fragment. Returns None for shapes with no stable key (subqueries,
-    windows, unions...). Shared by the host tier's structural memo and every
-    AdaptiveStats producer/consumer."""
+    windows, unions...).
+
+    Expressions enter in their SHAPE form (`plan.expr.shape`: a literal's
+    type and position, not its value), as in every program and hint key:
+    what AdaptiveStats, the watchtower's baselines and the staged tier's
+    `slive` hints learnt under one parameter set of a query is found under
+    the next. `exact=True` keeps the values: the key of a RESULT, for the
+    host tier's structural memo alone."""
+    from igloo_tpu.plan import expr as E
     from igloo_tpu.plan import logical as L
 
-    def xr(x) -> Optional[str]:
-        # exprs repr by name; a nested subquery reprs as the OPAQUE
-        # "subquery(...)" (two different subqueries would collide) ->
-        # poison the fingerprint
-        r = repr(x)
-        return None if "subquery(" in r or "exists(" in r else r
+    def xr(x):
+        # a nested subquery is spelled as what equals nothing (two different
+        # subqueries must not collide) -> poison the whole key
+        r = E.fingerprint(x, by_name=True) if exact \
+            else E.shape(x, by_name=True)
+        return None if "Subquery(" in r or "Exists(" in r else r
+
+    def node(tag, exprs, *rest):
+        er = xr(exprs)
+        subs = [plan_fp(c, exact) for c in plan.children()]
+        if er is None or any(s is None for s in subs):
+            return None
+        return (tag, er) + rest + tuple(subs)
 
     t = type(plan)
     if t is L.Scan:
-        fr = xr(plan.pushed_filters)
-        return fr and ("scan", plan.table, fr, plan.partition)
+        return node("scan", plan.pushed_filters, plan.table, plan.partition)
     if t is L.Filter:
-        sub = plan_fp(plan.input)
-        pr = xr(plan.predicate)
-        return sub and pr and ("filter", pr, sub)
+        return node("filter", plan.predicate)
     if t is L.Project:
-        sub = plan_fp(plan.input)
-        er = xr(plan.exprs)
-        return sub and er and ("proj", er, tuple(plan.names), sub)
+        return node("proj", plan.exprs, tuple(plan.names))
     if t is L.Join:
-        ls, rs = plan_fp(plan.left), plan_fp(plan.right)
-        kr = xr((plan.left_keys, plan.right_keys, plan.residual))
-        return ls and rs and kr and (
-            "join", plan.join_type.value, kr, ls, rs)
+        return node("join", (plan.left_keys, plan.right_keys, plan.residual),
+                    plan.join_type.value)
     if t is L.Aggregate:
-        sub = plan_fp(plan.input)
-        ar = xr((plan.group_exprs, plan.aggs))
-        return sub and ar and ("agg", ar, tuple(plan.agg_names), sub)
+        return node("agg", (plan.group_exprs, plan.aggs),
+                    tuple(plan.agg_names))
     if t is L.Distinct:
-        sub = plan_fp(plan.input)
-        return sub and ("distinct", sub)
+        return node("distinct", ())
     if t is L.Sort:
         # ORDER BY must not poison the key: production queries near-always
         # sort their output, and an unkeyed plan gets no latency baseline
         # (docs/observability.md#watchtower)
-        sub = plan_fp(plan.input)
-        kr = xr((plan.keys, plan.ascending, plan.nulls_first))
-        return sub and kr and ("sort", kr, sub)
+        return node("sort", plan.keys, tuple(plan.ascending),
+                    tuple(plan.nulls_first))
     if t is L.Limit:
-        sub = plan_fp(plan.input)
-        return sub and ("limit", plan.limit, plan.offset, sub)
+        return node("limit", (), plan.limit, plan.offset)
     return None  # unbounded/unhandled shapes: no stable key
 
 

@@ -219,7 +219,7 @@ class HostExecutor:
         though pruning gave it a narrower scan, and the hit is served by
         name (_serve_by_name) — TPC-H q2/q11/q15/q22 halve."""
         from igloo_tpu.exec.hints import plan_fp
-        return plan_fp(plan)
+        return plan_fp(plan, exact=True)
 
     # ---- leaves ----------------------------------------------------------
 
@@ -237,11 +237,9 @@ class HostExecutor:
                     table.column(f.name), f)
                 cols.append(HCol(f.dtype, vals, nulls, dinfo))
             return HBatch(plan.schema, cols, table.num_rows)
-        from igloo_tpu.exec.cache import provider_snapshot
-        from igloo_tpu.exec.executor import expr_fingerprint
+        from igloo_tpu.exec.cache import provider_snapshot, read_identity
         snap = provider_snapshot(plan.provider)
-        base = (plan.table, expr_fingerprint(plan.pushed_filters),
-                plan.partition, "host")
+        base = (plan.table, read_identity(plan), plan.partition, "host")
         if not plan.schema.fields:  # zero-column scan: only the count matters
             table = read_scan_table(plan)
             return HBatch(plan.schema, [], table.num_rows)

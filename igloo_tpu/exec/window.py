@@ -29,6 +29,7 @@ from igloo_tpu import types as T
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn
 from igloo_tpu.exec.expr_compile import Compiled, Env
+from igloo_tpu.plan import expr as E
 from igloo_tpu.plan.expr import AggFunc
 
 
@@ -67,7 +68,7 @@ def compile_window(plan, comp, resolve) -> tuple:
             if a.arg is not None:
                 r = resolve(a.arg)
                 arg = comp.compile(r)
-                fps.append(repr(r))
+                fps.append(E.shape(r))
                 if arg.dtype.is_string:
                     raise NotSupportedError(
                         "string arguments to windowed aggregates are not "
@@ -81,14 +82,14 @@ def compile_window(plan, comp, resolve) -> tuple:
             offset = int(w.args[1].value) if len(w.args) > 1 else 1
             specs.append(WinSpec(w.func, arg=arg, offset=offset,
                                  out_dtype=w.dtype))
-            fps.append((w.func, repr(r), offset, w.dtype))
+            fps.append((w.func, E.shape(r), offset, w.dtype))
             out_dicts.append(arg.out_dict)
         else:
             specs.append(WinSpec(w.func, out_dtype=w.dtype))
             fps.append((w.func,))
             out_dicts.append(None)
         out_bounds.append(None)
-    fp = (tuple(repr(e) for e in pres), tuple(repr(e) for e in ores),
+    fp = (E.shape(pres), E.shape(ores),
           tuple(plan.ascending), tuple(plan.nulls_first), tuple(fps))
     return fp, part_keys, order_keys, specs, out_dicts, out_bounds
 

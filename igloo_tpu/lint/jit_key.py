@@ -1,4 +1,5 @@
-"""jit-key: raw data-dependent ints must not flow into `_jitted` fingerprints.
+"""jit-key: raw data-dependent ints, and the values of a query's literals,
+must not flow into `_jitted` fingerprints.
 
 The compile cache only amortizes across queries, scale factors, and (via the
 persistent cache) processes when fingerprints depend on *shape classes*, not
@@ -17,9 +18,18 @@ about the call site looks wrong. So the rule is mechanical:
   `canonical_direct_table` / `choose_match_capacity`) quantizes it to a
   shape class and clears the taint — that is exactly what those functions
   are for.
+- **literal values** are the rule's second kind of source: `repr(...)` of
+  anything and `fingerprint(...)` (plan/expr.py: what an expression computes,
+  values included) print `lit(90)`, and a key that holds that is one program
+  PER PARAMETER SET of a query — TPC-H's substitution parameters, a
+  dashboard's date picker — each a trace and an XLA compile inside a user's
+  query. A literal's value is an argument (a ConstPool scalar); its type and
+  position key the program: `shape(...)` / `expr_fingerprint(...)`, which
+  are this source's sanitizers.
 - **sinks**: the fingerprint argument (second positional) of any
-  `*._jitted(...)` call. A tainted name or inline source expression there is
-  a finding.
+  `*._jitted(...)` call, and every argument of `*._push(...)`: the fused
+  compiler's node fingerprints, which become the fused program's key and its
+  hint keys. A tainted name or inline source expression there is a finding.
 
 The checker is function-local by design (no cross-function dataflow): every
 `_jitted` fingerprint in the tree is assembled in the same function that
@@ -38,7 +48,8 @@ RULE = "jit-key"
 # quantizers that turn a data-dependent int into a shape-class value
 SANITIZERS = {"round_capacity", "canonical_capacity",
               "canonical_direct_table",
-              "choose_match_capacity", "batch_proto_key", "len"}
+              "choose_match_capacity", "batch_proto_key", "len",
+              "shape", "expr_fingerprint"}
 
 # attribute-call names that produce data-dependent scalars. The adaptive
 # stats accessors (exec/hints.AdaptiveStats) are sources by design: observed
@@ -48,6 +59,8 @@ SANITIZERS = {"round_capacity", "canonical_capacity",
 # data size, exactly the cold-start regression the store exists to avoid.
 _SOURCE_METHODS = {"num_live", "item", "device_get",
                    "observed", "observed_rows", "selectivity"}
+# calls that print an expression with its literals' VALUES
+_VALUE_FORMS = {"repr", "fingerprint"}
 
 
 def _call_name(node: ast.Call) -> Optional[str]:
@@ -106,7 +119,7 @@ class _FnTaint:
                 name = _call_name(node)
                 if name in SANITIZERS:
                     continue  # quantized: whatever is inside is now a class
-                if name in _SOURCE_METHODS:
+                if name in _SOURCE_METHODS or name in _VALUE_FORMS:
                     return node
                 if name in ("int", "float") and node.args:
                     arg = node.args[0]
@@ -135,18 +148,26 @@ class JitKeyChecker(Checker):
         for fn in fns:
             taint = None
             for node in ast.walk(fn):
-                if isinstance(node, ast.Call) and \
-                        _call_name(node) == "_jitted" and \
-                        len(node.args) >= 2:
+                if isinstance(node, ast.Call) and _sink_args(node):
                     if taint is None:
                         taint = _FnTaint(fn)
                     calls[id(node)] = (node, taint)
         for node, taint in calls.values():
-            bad = taint.expr_tainted(node.args[1])
+            bad = next((b for b in map(taint.expr_tainted, _sink_args(node))
+                        if b is not None), None)
             if bad is None:
                 continue
             what = dotted(bad) if isinstance(bad, ast.Name) else \
                 (_call_name(bad) or "expression")
+            if what in _VALUE_FORMS:
+                out.append(Finding(
+                    RULE, mod.relpath, node.lineno,
+                    f"`{what}(...)` of an expression flows into a program "
+                    "fingerprint: it prints every literal's VALUE, so the "
+                    "compile cache gets one program PER PARAMETER SET of a "
+                    "query — key on plan.expr.shape() (the value is a "
+                    "ConstPool argument)"))
+                continue
             out.append(Finding(
                 RULE, mod.relpath, node.lineno,
                 f"raw data-dependent value `{what}` flows into a _jitted "
@@ -155,3 +176,13 @@ class JitKeyChecker(Checker):
                 "round_capacity()/canonical_capacity() (exec/capacity.py) "
                 "or key on the batch prototype instead"))
         return out
+
+
+def _sink_args(call: ast.Call) -> list:
+    """The fingerprint expressions of a sink call, [] for any other call."""
+    name = _call_name(call)
+    if name == "_jitted" and len(call.args) >= 2:
+        return [call.args[1]]
+    if name == "_push":
+        return list(call.args)
+    return []

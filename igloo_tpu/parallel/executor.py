@@ -93,10 +93,10 @@ class ShardedExecutor(Executor):
     def _exec_scan(self, plan: L.Scan) -> DeviceBatch:
         key = snap = None
         if self._batch_cache is not None:
-            from igloo_tpu.exec.cache import provider_snapshot
+            from igloo_tpu.exec.cache import provider_snapshot, read_identity
             key = (plan.table, "sharded", self.n_dev,
                    tuple(plan.projection) if plan.projection is not None else None,
-                   expr_fingerprint(plan.pushed_filters), plan.partition)
+                   read_identity(plan), plan.partition)
             snap = provider_snapshot(plan.provider)
             hit = self._batch_cache.get(key, snap)
             if hit is not None:

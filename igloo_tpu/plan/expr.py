@@ -11,6 +11,7 @@ inferred) by the planner. `dtype` is filled in during binding.
 from __future__ import annotations
 
 import enum
+import itertools
 from dataclasses import dataclass, field as dc_field, fields as dc_fields
 from typing import Optional
 
@@ -372,24 +373,125 @@ def transform(e: Expr, fn) -> Expr:
     return fn(n)
 
 
-def fingerprint(e) -> tuple:
+def fingerprint(e, by_name: bool = False) -> str:
     """What a bound expression computes, as a hashable value: over one input
     schema, equal fingerprints mean equal results. A `repr` is a label, not
     that (a Column prints no index, a Literal no type, a subquery nothing of
-    its query): this reads EVERY dataclass field of every node, so that a
+    its query): this spells EVERY dataclass field of every node, so that a
     node which gains a field cannot make two expressions collide; what it
     cannot read field by field (a subquery's AST) equals nothing, not even
-    itself on a second call."""
+    itself on a second call. Keys a RESULT (which aggregate lanes are one,
+    the host tier's memo): the value of every literal is in it. `by_name`
+    as in `shape`."""
+    out: list = []
+    _spell(e, False, by_name, False, out)
+    return "".join(out)
+
+
+def shape(e, by_name: bool = False) -> str:
+    """`fingerprint` without the VALUE of the literals that are arguments of
+    a program (`runtime_literal`): their type and position stay. THE key form
+    of programs, hints, flags, negatives and AdaptiveStats: two parameter
+    sets of one query (TPC-H's substitution parameters, a dashboard's date
+    picker) are one shape, so one trace, one compile, one set of hints. The
+    value of a literal that sizes or selects code stays in the shape: a
+    string, NULL, and every literal that is a direct argument of a function
+    call (round's digits, substr's bounds: read on the host when the call is
+    compiled, `ExprCompiler.compile_arg`).
+    `by_name` leaves a Column's index out: the projection-insensitive form
+    of `exec/hints.py plan_fp`."""
+    out: list = []
+    _spell(e, True, by_name, False, out)
+    return "".join(out)
+
+
+def runtime_literal(e: "Literal") -> bool:
+    """Is this literal's value an argument of the program (a ConstPool
+    scalar bound at dispatch) and no part of its key? Every typed scalar
+    that lives in a numeric, date, timestamp or bool lane; not a string (a
+    dictionary is built from it), not NULL (it has no lane of its own)."""
+    dt = e.dtype or e.literal_type
+    return (dt is not None and not dt.is_string and dt.id is not T.TypeId.NULL
+            and isinstance(e.value, (bool, int, float)))
+
+
+# every node class of this module -> its dataclass field names (a class
+# defined elsewhere is read as it comes)
+_FIELD_NAMES = {c: tuple(f.name for f in dc_fields(c))
+                for c in list(globals().values())
+                if isinstance(c, type) and issubclass(c, Expr)}
+_OPAQUE = itertools.count()
+
+
+def _spell(e, mask: bool, by_name: bool, static: bool, out: list) -> None:
+    """Append the spelling of `e` to `out`: `fingerprint` (mask off) and
+    `shape` (mask on). A string, not nested tuples: it is hashed at every
+    lookup of a program (a str caches its hash, a tuple of tuples is walked
+    again) and digested for every hint, on the host path of every query.
+    Column, Literal and Binary — nine nodes in ten — are spelled in one
+    format each, letter for letter what `_spell_fields` spells from their
+    dataclass fields (tests/test_expr.py holds them to it)."""
+    cls = type(e)
+    if cls is Column:
+        out.append("Column(%s,%r,%s,)" % (
+            "~" if e.dtype is None else e.dtype, e.name,
+            "" if by_name else "~" if e.index is None else "i%d" % e.index))
+    elif cls is Binary:
+        out.append("Binary(%s,%s," % ("~" if e.dtype is None else e.dtype,
+                                      e.op))
+        _spell(e.left, mask, by_name, False, out)
+        out.append(",")
+        _spell(e.right, mask, by_name, False, out)
+        out.append(",)")
+    elif cls is Literal and mask and not static and runtime_literal(e):
+        out.append("Literal(%s,?,%s,)" % (
+            "~" if e.dtype is None else e.dtype,
+            "~" if e.literal_type is None else e.literal_type))
+    else:
+        _spell_fields(e, mask, by_name, static, out)
+
+
+def _spell_fields(e, mask: bool, by_name: bool, static: bool,
+                  out: list) -> None:
+    """`_spell` for every node: each dataclass field, in order."""
     if isinstance(e, Expr):
-        return (type(e).__name__,) + tuple(
-            fingerprint(getattr(e, f.name)) for f in dc_fields(e))
-    if isinstance(e, (list, tuple)):
-        return tuple(fingerprint(x) for x in e)
-    if e is None or isinstance(e, (bool, int, float, str, enum.Enum,
-                                   T.DataType)):
+        cls = type(e)
+        names = _FIELD_NAMES.get(cls) or \
+            tuple(f.name for f in dc_fields(cls))
+        hide = skip = None
+        if cls is Literal:
+            hide = "value" if mask and not static and runtime_literal(e) \
+                else None
+        elif cls is Column and by_name:
+            skip = "index"
+        inner = mask and cls is Func
+        out.append(cls.__name__)
+        out.append("(")
+        for n in names:
+            if n == hide:
+                out.append("?")
+            elif n != skip:
+                _spell(getattr(e, n), mask, by_name, inner, out)
+            out.append(",")
+        out.append(")")
+    elif e is None:
+        out.append("~")
+    elif isinstance(e, str):
+        out.append(repr(e))
+    elif isinstance(e, (enum.Enum, T.DataType)):
+        out.append(str(e))
+    elif isinstance(e, (list, tuple)):
+        out.append("[")
+        for x in e:
+            _spell(x, mask, by_name, static, out)
+            out.append(",")
+        out.append("]")
+    elif isinstance(e, (bool, int, float)):
         # by type and spelling: 1 == 1.0 == True and 0.0 == -0.0 in Python
-        return (type(e).__name__, repr(e))
-    return ("opaque", object())
+        out.append(type(e).__name__[0])
+        out.append(repr(e))
+    else:
+        out.append(f"<opaque {next(_OPAQUE)}>")
 
 
 def columns_in(e: Expr) -> set[str]:

@@ -49,3 +49,27 @@ class AdaptiveEx:
     def selectivity_in_key(self, store, fp_key, build):
         sel = store.selectivity(fp_key)
         return self._jitted("join", ("join", sel), build)  # BAD
+
+
+class LiteralEx:
+    """A literal's VALUE in a program key is one program per parameter set:
+    `repr` and `fingerprint` print it."""
+
+    def _jitted(self, kind, fp, build):
+        return build()
+
+    def _push(self, fp, hint_fp="same"):
+        pass
+
+    def repr_in_key(self, pred, proto, build):
+        fp = ("filter", repr(pred), proto)
+        return self._jitted("filter", fp, build)  # BAD
+
+    def fingerprint_in_key(self, exprs, build):
+        return self._jitted("project", ("project", fingerprint(exprs)), build)  # BAD
+
+    def repr_in_pushed_node(self, res):
+        self._push(("sort", tuple(repr(e) for e in res)))  # BAD
+
+    def repr_in_hint_member(self, res, core):
+        self._push(core, ("join", repr(res)))  # BAD

@@ -61,3 +61,34 @@ class AdaptiveEx:
         if rows is not None and rows < 1024:
             return self._jitted("small", ("small", 1024), build_a)
         return self._jitted("big", ("big", 4096), build_b)
+
+
+def shape(e):
+    return e
+
+
+def expr_fingerprint(exprs):
+    return tuple(exprs)
+
+
+class LiteralEx:
+    """Expressions enter a key in their shape form; a repr may still label
+    a log line or digest a finished key."""
+
+    def _jitted(self, kind, fp, build):
+        return build()
+
+    def _push(self, fp, hint_fp="same"):
+        pass
+
+    def shape_in_key(self, pred, proto, build):
+        fp = ("filter", shape(pred), proto)
+        print(repr(fp))
+        return self._jitted("filter", fp, build)
+
+    def staged_form(self, exprs, build):
+        return self._jitted("project", ("project", expr_fingerprint(exprs)),
+                            build)
+
+    def shape_in_pushed_node(self, res):
+        self._push(("sort", shape(res)), hint_fp=("sort", shape(res)))

@@ -220,3 +220,82 @@ def test_cast_date_to_timestamp():
     e.dtype = T.TIMESTAMP
     v, n, _ = run(e, b)
     assert v[0] == 8766 * 86_400_000_000
+
+
+# --- the two spellings of an expression (ISSUE 34) ---
+
+def _sample_exprs():
+    from igloo_tpu.plan import expr as E
+
+    def typed(e, dt):
+        e.dtype = dt
+        return e
+    col = typed(E.Column("t.x", index=3), T.FLOAT64)
+    unbound = E.Column("y")
+    lits = [typed(E.Literal(5, T.INT64), T.INT64), E.Literal(5.0, T.FLOAT64),
+            E.Literal(True, T.BOOL), E.Literal(None), E.Literal("a'b", T.STRING),
+            E.Literal(9000, T.DATE32), E.Literal(7)]
+    out = [col, unbound] + lits
+    out += [typed(E.Binary(E.BinOp.GT, col, lit), T.BOOL) for lit in lits]
+    out.append(E.Binary(E.BinOp.AND, out[-1], E.Not(out[-2])))
+    out.append(E.Func("round", [col, E.Literal(2, T.INT64)]))
+    out.append(E.Func("coalesce", [E.Binary(E.BinOp.ADD, col, lits[0]),
+                                   E.Literal(0, T.INT64)]))
+    out.append(E.InList(col, [lits[0], lits[3]], negated=True))
+    out.append(E.Case([(out[-4], lits[1])], else_=None))
+    out.append(E.Aggregate(E.AggFunc.SUM, E.Binary(E.BinOp.MUL, col, lits[1])))
+    return out
+
+
+def test_fast_spellings_are_the_fields_spellings(monkeypatch):
+    """Column, Literal and Binary are spelled by one format each; letter for
+    letter what the generic walk over their dataclass fields spells. A field
+    added to one of them shows here."""
+    from dataclasses import fields
+    from igloo_tpu.plan import expr as E
+    assert [f.name for f in fields(E.Column)] == ["dtype", "name", "index"]
+    assert [f.name for f in fields(E.Literal)] == ["dtype", "value",
+                                                   "literal_type"]
+    assert [f.name for f in fields(E.Binary)] == ["dtype", "op", "left",
+                                                  "right"]
+    exprs = _sample_exprs()
+    fast = [(E.shape(e), E.shape(e, by_name=True), E.fingerprint(e),
+             E.fingerprint(e, by_name=True)) for e in exprs]
+    monkeypatch.setattr(E, "_spell", E._spell_fields)
+    slow = [(E.shape(e), E.shape(e, by_name=True), E.fingerprint(e),
+             E.fingerprint(e, by_name=True)) for e in exprs]
+    assert fast == slow
+    assert len({f[2] for f in fast}) == len(exprs)      # all differ by value
+
+
+def test_shape_masks_values_and_keeps_what_sizes_code():
+    from igloo_tpu.plan import expr as E
+    col = E.Column("x", index=0)
+
+    def gt(v, dt):
+        return E.Binary(E.BinOp.GT, col, E.Literal(v, dt))
+    assert E.shape(gt(5, T.INT64)) == E.shape(gt(6, T.INT64))
+    assert E.fingerprint(gt(5, T.INT64)) != E.fingerprint(gt(6, T.INT64))
+    assert E.shape(gt(5, T.INT64)) != E.shape(gt(5.0, T.FLOAT64))   # dtype
+    assert E.shape(gt(5, T.DATE32)) != E.shape(gt(5, T.INT32))
+    assert E.shape(gt(True, T.BOOL)) == E.shape(gt(False, T.BOOL))
+    # strings, NULL, untyped literals and a function's literal arguments
+    # keep their values
+    assert E.shape(gt("a", T.STRING)) != E.shape(gt("b", T.STRING))
+    assert E.shape(gt(None, T.INT64)) != E.shape(gt(5, T.INT64))
+    assert E.shape(gt(5, None)) != E.shape(gt(6, None))
+    r1, r2 = (E.Func("round", [col, E.Literal(d, T.INT64)]) for d in (1, 2))
+    assert E.shape(r1) != E.shape(r2)
+    deep = [E.Func("abs", [gt(v, T.INT64)]) for v in (1, 2)]
+    assert E.shape(deep[0]) == E.shape(deep[1])     # not a DIRECT argument
+    # position: the same values the other way round are another shape
+    a = E.Binary(E.BinOp.AND, gt(5, T.INT64), gt(5.0, T.FLOAT64))
+    b = E.Binary(E.BinOp.AND, gt(5.0, T.FLOAT64), gt(5, T.INT64))
+    assert E.shape(a) != E.shape(b)
+    # by_name: the projection-insensitive form of hints.plan_fp
+    moved = E.Binary(E.BinOp.GT, E.Column("x", index=4), E.Literal(5, T.INT64))
+    assert E.shape(moved) != E.shape(gt(5, T.INT64))
+    assert E.shape(moved, by_name=True) == E.shape(gt(5, T.INT64), by_name=True)
+    # a subquery equals nothing, not even itself
+    sub = E.ScalarSubquery(query=object())
+    assert E.shape(sub) != E.shape(sub)

@@ -160,41 +160,18 @@ _KEY_SQL = ("SELECT k, SUM(v) AS s, COUNT(*) AS c FROM {0} "
 # aliased, as a fragment's expressions keep the statement's qualifiers
 _KEY_JOIN_SQL = ("SELECT a.k, SUM(a.v + b.v) AS s FROM {0} a "
                  "JOIN {1} b ON a.n = b.n GROUP BY a.k ORDER BY 1")
-# repr of key[1] (the node fingerprints) of _KEY_SQL over an ordinary
-# MemTable named `facts`, taken on the parent commit (2149ae4)
-_PARENT_FPS = (
-    "(('scan', 'facts', (), '[(col(n) < lit(4))]', None, "
-    "Schema(k: string, v: float64, n: int64), 8, (True, False, False), "
-    "(None, None, (0, 8)), (('int8', ('int32', False, 1.0, False)), "
-    "('int8', ('float64', False, 1.0, False)), "
-    "('int8', ('int64', False, 1.0, False)))), "
-    "('filter', '(col(n) < lit(4))'), "
-    "('agg', ('col(k)', 'col(v)'), ((<AggFunc.SUM: 'sum'>, float64), "
-    "(<AggFunc.COUNT_STAR: 'count_star'>, int64)), "
-    "Schema(k: string, __agg_0: float64, __agg_1: int64), ((3, 0),), "
-    "None, None), "
-    "('project', ('col(k)', 'col(__agg_0)', 'col(__agg_1)'), "
-    "Schema(k: string, s: float64, c: int64)), "
-    "('sort', ('col(k)',), (True,), (False,), "
-    "(('i32', 0, ((4, True, False),)), 1)))")
+# sha1 of repr(key[1]) (the node fingerprints) of _KEY_SQL over an ordinary
+# MemTable named `facts`, taken on PR 34's tree. ISSUE 34 moved every key
+# that holds an expression, once: an expression enters as `E.shape` (every
+# field of every node, a literal's type and position but not its value)
+# where it entered as its repr (`'(col(n) < lit(4))'`: no index, no type,
+# the value). Everything else of the nodes is what PR 32 left.
+_FPS_SHA1 = "18f3f357ce5b2ab2e4f4fd6cae3b67657bcce61a"
 
 
 def _sha1(obj) -> str:
     import hashlib
     return hashlib.sha1(repr(obj).encode()).hexdigest()
-
-
-def _with_the_plans_put_back(fps: tuple) -> tuple:
-    """Node fingerprints as PR 32's parent pushed them on this backend.
-    ISSUE 32 took the kernel plans out of them: an `agg` node lost its last
-    member (None wherever no kernel was planned), a `join_sorted` node its
-    last two (None, and the match table's route), a `topk` node the member
-    before its limit, and the key as a whole its sixth member (the mode
-    token). The literals and digests taken on earlier commits stay as they
-    were taken, and are compared with this."""
-    back = {"agg": (None,), "join_sorted": (None, ("match", "search"))}
-    assert not [fp for fp in fps if fp[0] == "topk"]
-    return tuple(fp + back.get(fp[0], ()) for fp in fps)
 
 
 def _compile_over(provider_cls, sql: str, names: tuple):
@@ -231,10 +208,16 @@ def test_ordinary_scan_is_keyed_by_name():
 
 
 def test_ordinary_scan_fingerprint_did_not_move():
-    # the "k" column's content differs from the parent's sample ("a"): the
-    # key is content-light, so the literal still holds
+    # content-light: neither the "k" column's content nor the value of the
+    # filter's literal is in it (the literal's type is: `n < 4.0` differs)
     _, key = _compile_over(MemTable, _KEY_SQL, ("facts",))
-    assert repr(_with_the_plans_put_back(key[1])) == _PARENT_FPS
+    assert _sha1(key[1]) == _FPS_SHA1, repr(key[1])
+    _, other = _compile_over(MemTable, _KEY_SQL.replace("< 4", "< 3"),
+                             ("facts",))
+    assert other == key
+    _, typed = _compile_over(MemTable, _KEY_SQL.replace("< 4", "< 4.0"),
+                             ("facts",))
+    assert typed != key
 
 
 # --- who decides that a hinted node compacts (ISSUE 31) ---
@@ -247,23 +230,10 @@ _SEL_GROUPED = ("SELECT fk, sum(x * w) AS s, count(*) AS c FROM fact "
                 "WHERE w < 3 GROUP BY fk ORDER BY fk")
 _SEL_JOINED = ("SELECT sum(x * w + v) AS s, count(*) AS c FROM fact "
                "JOIN dim ON fk = k WHERE w < 3")
-# repr of key[1] (the node fingerprints) of _SEL_GROUPED's second
-# compilation, its filter's hint adopted, taken on the parent commit (7da1175)
-_PARENT_COMPACTED_FPS = (
-    "(('scan', 'fact', (), '[(col(w) < lit(3))]', None, "
-    "Schema(fk: int64, w: int64, x: float64), 8192, (False, False, False), "
-    "((0, 16), (0, 256), None), (('int8', ('int64', False, 1.0, False)), "
-    "('int8', ('int64', False, 1.0, False)), None)), "
-    "('filter', '(col(w) < lit(3))'), ('acompact', 256), "
-    "('agg', ('col(fk)', '(col(x) * col(w))'), "
-    "((<AggFunc.SUM: 'sum'>, float64), "
-    "(<AggFunc.COUNT_STAR: 'count_star'>, int64)), "
-    "Schema(fk: int64, __agg_0: float64, __agg_1: int64), ((9, 1),), "
-    "None, None), "
-    "('project', ('col(fk)', 'col(__agg_0)', 'col(__agg_1)'), "
-    "Schema(fk: int64, s: float64, c: int64)), "
-    "('sort', ('col(fk)',), (True,), (False,), "
-    "(('i32', 0, ((16, True, False),)), 1)))")
+# sha1 of repr(key[1]) (the node fingerprints) of _SEL_GROUPED's second
+# compilation, its filter's hint adopted (`('acompact', 256)` after the
+# filter node), taken on PR 34's tree (see _FPS_SHA1 for what moved)
+_COMPACTED_FPS_SHA1 = "bc243be2fe669857ead8da9dbd880c4445599693"
 
 
 def _sel_tables(dense: bool = False):
@@ -379,8 +349,9 @@ def test_other_consumers_still_get_a_compacted_filter(small_adaptive, sql):
     assert [tag[0] for tag in comp.flag_tags if tag[1][0] == "filter"] \
         == ["compact"]
     if sql == _SEL_GROUPED:
-        assert repr(_with_the_plans_put_back(key[1])) \
-            == _PARENT_COMPACTED_FPS
+        assert [fp[0] for fp in comp.fps] == [
+            "scan", "filter", "acompact", "agg", "project", "sort"]
+        assert _sha1(key[1]) == _COMPACTED_FPS_SHA1, repr(key[1])
 
 
 def test_declined_filter_needs_no_repair_when_its_data_grows(small_adaptive):
@@ -420,37 +391,21 @@ def test_staged_executor_asks_the_same_predicate(small_adaptive, sql,
 
 # sha1 of repr(key[1:5]) (node fingerprints, pool signature, marks, fetch
 # capacity) of the program each benchmark query settles on at SF 0.01 under
-# the lowered thresholds, taken on PR 31's parent (7da1175): q3's filters
-# feed joins and its aggregate is grouped, q1's aggregate is grouped, so
-# ISSUE 31 could move neither
-_PR31_KEY_SHA1 = {"q3": "5a3fd21e3f0e89018e2d289fb5ac83fb72627444",
-                  "q1": "8a6131de9e9055fae4ad297a0de631e74f9a9bda"}
-# the same on this tree (ISSUE 32): shorter by what _with_the_plans_put_back
-# names and by nothing else — put back by hand, they reproduce the digests
-# above (neither query has a `join_sorted` node at this size; the test
-# after this one does)
-_KEY_SHA1 = {"q3": "e918bd396dbe2dbcd1e6e176ed537b4f379182c6",
-             "q1": "75266a89e44023fecd60d83b31640f50b9819573"}
-# sha1 of repr(sorted(repr(k) for the engine's nhint keys)), on PR 32's
-# parent. Hint keys persist as digests (nhints.json): a key whose chain of
-# hint fingerprints holds no `agg` node did not move (all of q1's, and the
-# sorted join's below); one at or above an aggregate is shorter by that
-# node's trailing None and by nothing else, so a store re-learns those once
-_PARENT_HINT_KEYS_SHA1 = {"q3": "e8b017fcb68d0f7d344542008144f8d0a7f43c74",
-                          "q1": "869767d35bc752688baab0f0f86fa95f93b4c852"}
-_HINT_KEYS_SHA1 = {"q3": "873609c75d9e03e61055959ba060da8f750212a0",
-                   "q1": "869767d35bc752688baab0f0f86fa95f93b4c852"}
+# the lowered thresholds, and of repr(sorted(repr(k) for the engine's nhint
+# keys)), taken on PR 34's tree. Both moved once with ISSUE 34 (expressions
+# enter as `E.shape`, and the pool's signature gained the literals' scalar
+# vectors); hint keys persist as digests (nhints.json), so a store re-learns
+# its hints once. What must hold from here on: the digests do not move with
+# a query's substitution parameters (the last lines of the test).
+_KEY_SHA1 = {"q3": "9ed4e63a6c17884e17c5c3b44139fc680d8fca38",
+             "q1": "dbe5763c310b20b1e1b86f0386b236f474d27536"}
+_HINT_KEYS_SHA1 = {"q3": "139fef303a521d3bd27ad7e741235d27c700ee66",
+                   "q1": "5f5d0c50f43ff0f7b01abd5e0ade452ad151a97d"}
 
 
-def _hint_keys(e: QueryEngine, put_back: bool = False) -> list:
-    """The engine's hint keys, sorted reprs; `put_back` restores the
-    trailing None an `agg` node's hint fingerprint had on PR 32's parent."""
-    def walk(x):
-        if not isinstance(x, tuple):
-            return x
-        y = tuple(walk(m) for m in x)
-        return y + (None,) if put_back and len(y) == 6 and y[0] == "agg" else y
-    return sorted(repr(walk(k)) for k in e._jit_cache
+def _hint_keys(e: QueryEngine) -> list:
+    """The engine's hint keys, sorted reprs."""
+    return sorted(repr(k) for k in e._jit_cache
                   if isinstance(k, tuple) and k[0] == "nhint")
 
 
@@ -469,17 +424,18 @@ def test_program_keys_of_the_bypass_queries_did_not_move(small_adaptive, q):
         assert [fp for fp in comp.fps if fp[0] == "acompact"]
     assert len(key) == 5
     assert _sha1(key[1:5]) == _KEY_SHA1[q], repr(key[1:5])
-    assert _sha1((_with_the_plans_put_back(key[1]),) + key[2:5]) \
-        == _PR31_KEY_SHA1[q]
     assert _sha1(_hint_keys(e)) == _HINT_KEYS_SHA1[q]
-    assert _sha1(_hint_keys(e, put_back=True)) == _PARENT_HINT_KEYS_SHA1[q]
+    # another parameter set of the query (TPC-H clauses 2.4.1.3, 2.4.3.3):
+    # the same program, under the hints the first set learnt
+    other = QUERIES[q].replace("'90' DAY", "'68' DAY") \
+        .replace("1995-03-15", "1995-03-20")
+    assert other != QUERIES[q]
+    assert _compile_on(e, other)[1] == key
 
 
 def test_sorted_join_lost_its_plans_and_kept_its_hint_key(monkeypatch):
-    # a float key takes no direct table, so the join is `join_sorted`: on
-    # PR 32's parent sha1(repr(key[1:5])) of this program was ae2b2246...
-    # and the hint key of the join e23c51c7... — the second must not move,
-    # the first only by the members named above
+    # a float key takes no direct table, so the join is `join_sorted`; the
+    # digests are PR 34's (see _KEY_SHA1 for what moved them)
     monkeypatch.setattr(F, "ADAPTIVE_CAPACITY", 1 << 10)
     rng = np.random.default_rng(5)
     e = QueryEngine()
@@ -500,11 +456,10 @@ def test_sorted_join_lost_its_plans_and_kept_its_hint_key(monkeypatch):
     [jfp] = [fp for fp in comp.fps if fp[0] == "join_sorted"]
     # ends with the match capacity and the output schema: no plan rides
     assert jfp[-2] == 8192 and type(jfp[-1]).__name__ == "Schema"
-    assert _sha1((_with_the_plans_put_back(key[1]),) + key[2:5]) \
-        == "ae2b22463689ccf6b589218ca1a5ab7358e5524a"
+    assert _sha1(key[1:5]) == "4dd1351adeb052c88cfda60a5155650d645bb0c6", repr(key[1:5])
     [hkey] = [k for k in comp.stat_keys if k[0] == "join"]
     assert hkey[1][-1][0] == "join_sorted"
-    assert _sha1(hkey) == "e23c51c7053b941ce4800afe46b226c4ccef179f"
+    assert _sha1(hkey) == "a33f34fc472ea3b4c66d25595bab3d2d63cb2fc6"
 
 
 def _sorted_join_engine():

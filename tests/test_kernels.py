@@ -788,7 +788,11 @@ def test_direct_aggregate_keeps_arguments_that_print_alike_apart(other):
     """SUM(CASE WHEN s LIKE 'A%' ...) beside the same CASE over NOT LIKE,
     ILIKE, or a column of another index under the same name, under one
     small-domain key: each keeps a lane of its own (its sum differs from the
-    first's) and equals the sort path; the LIKE handed over twice shares."""
+    first's) and equals the sort path. The LIKE handed over twice does not
+    share either, since ISSUE 34: the CASE's `1` and `0` are arguments of
+    the program, whose key holds no value, so its next execution may bring
+    other ones (an argument that binds no literal still shares: q1's AVGs,
+    tests/test_tpu_compile.py)."""
     import copy
     from igloo_tpu.exec.aggregate import seg_dims_for
     from igloo_tpu.plan.expr import fingerprint
@@ -807,8 +811,9 @@ def test_direct_aggregate_keeps_arguments_that_print_alike_apart(other):
         direct = aggregate_batch(b, g, aggs, schema, consts,
                                  seg_dims=seg_dims_for(g))
     # the live count, and a sum and a non-NULL count (a CASE computes a null
-    # lane of its own) for TWO arguments: the third shares the first's
-    assert d.get("agg.onepass_lanes") == 1 + 2 * 2
+    # lane of its own) for each of the THREE arguments
+    assert [a.arg.literals for a in aggs] == [2, 2, 2]
+    assert d.get("agg.onepass_lanes") == 1 + 3 * 2
     got = _rows(direct)
     assert got == _rows(aggregate_batch(b, g, aggs, schema, consts))
     assert all(r[1] == r[3] for r in got)

@@ -140,15 +140,18 @@ def _q1_shaped_aggregate():
     specs.append(AggSpec(AggFunc.COUNT_STAR, None, T.INT64, None))
     groups = [comp.compile(col("flag")), comp.compile(col("status"))]
 
-    def fn(flag_ids, status_ids, qty, price, disc, tax, live):
+    def fn(flag_ids, status_ids, qty, price, disc, tax, live, *consts):
         lanes = [flag_ids, status_ids, qty, price, disc, tax]
         cols = [DeviceColumn(d, v, None, dic)
                 for d, v, dic in zip(dtypes, lanes, dicts)]
         out = aggregate_batch(DeviceBatch(in_schema, cols, live), groups,
-                              specs, out_schema, comp.pool.device_args(),
+                              specs, out_schema, consts,
                               seg_dims=((4, 0), (3, 0)))
         return [(c.values, c.nulls) for c in out.columns], out.live
-    return fn
+    # the constants pool as PARAMETERS, as a dispatch passes it: q1's three
+    # literals (the 1 of `1 - l_discount`, twice, and of `1 + l_tax`) are
+    # scalars of one float64 vector (ISSUE 34), no constants of the trace
+    return fn, [(a.shape, a.dtype) for a in comp.pool.device_args()]
 
 
 # distinct lanes q1 hands to the one-pass reduce: five float64 sums (the AVGs
@@ -169,9 +172,11 @@ def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
     reaches HBM), the lanes are reduced by at most (distinct lanes + 2)
     fusions and not by one per lane and segment, and the program reads under
     a tenth of the bytes the per-segment loop read."""
+    fn, consts = _q1_shaped_aggregate()
+    assert consts == [((3,), jnp.float64)]
     shapes = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)] * 4 + \
-        [((LANES,), jnp.bool_)]
-    c = _lower_and_compile(_q1_shaped_aggregate(), shapes, one_chip)
+        [((LANES,), jnp.bool_)] + consts
+    c = _lower_and_compile(fn, shapes, one_chip)
     text = c.as_text()
     entry = text[text.index("ENTRY"):]
     fusions = re.findall(r"^\s*(?:ROOT )?%?(\S*fusion\S*) = (.*?) fusion\(",
@@ -181,3 +186,43 @@ def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
     reduces = [n for n, _ in fusions if "reduce" in n]
     assert 1 <= len(reduces) <= Q1_LANES + 2, reduces
     assert c.cost_analysis()["bytes accessed"] < Q1_PARENT_BYTES / 10
+
+
+# --- a literal is an argument: one lowering per shape (ISSUE 34; ~1 s each) -
+
+def _lowered_for_the_chip(engine, sql: str, sharding):
+    """What the engine's next execution of `sql` would hand the chip's
+    compiler: (program key, text of the fused program lowered for the
+    described chip, with the leaves' and the constants' shapes as the
+    engine holds them here)."""
+    from igloo_tpu.exec.executor import Executor, strip_dicts
+    from igloo_tpu.exec.fused import FusedCompiler
+    comp = FusedCompiler(Executor(engine._jit_cache,
+                                  batch_cache=engine.batch_cache))
+    run, key, _meta = comp.compile(engine.plan(sql))
+    args = ([strip_dicts(b) for b in comp.leaves], comp.pool.device_args())
+    spec = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), args)
+    return key, jax.jit(run).lower(*spec).as_text()
+
+
+@pytest.mark.parametrize("q", ["q1", "q6"])
+def test_two_parameter_sets_lower_to_one_program(one_chip, q):
+    """TPC-H's substitution parameters (clauses 2.4.1.3, 2.4.6.3) reach the
+    chip's compiler as arguments: under two sets, q1's and q6's programs —
+    scan, filter, aggregate: what a worker's scan fragment runs — have one
+    key and lower to byte-identical text for the described chip, and the
+    text holds neither set's values."""
+    from test_literal_args import Q1_SETS, Q6_SETS, _sql
+    from igloo_tpu.bench.tpch import gen_tables
+    from igloo_tpu.engine import QueryEngine
+    engine = QueryEngine()
+    engine.register_table("lineitem", gen_tables(sf=0.002)["lineitem"])
+    sets = {"q1": Q1_SETS, "q6": Q6_SETS}[q]
+    (k0, t0), (k1, t1) = (_lowered_for_the_chip(engine, _sql(q, p), one_chip)
+                          for p in sets[:2])
+    assert k0 == k1
+    assert t0 == t1
+    # the dates, as the days since the epoch a constant would hold
+    for days in {"q1": ("10471", "10493"), "q6": ("8766", "9496")}[q]:
+        assert days not in t0
