@@ -1408,6 +1408,7 @@ class CoordinatorServer(flight.FlightServerBase):
     def shutdown(self):  # pragma: no cover - exercised via tests' finally
         self._stop.set()
         super().shutdown()
+        rpc.close_idle_connections()
 
     # --- Flight methods (full surface; reference implements 2 of 9) ---
 

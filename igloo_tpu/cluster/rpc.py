@@ -22,9 +22,19 @@ bugs as flakes. Knobs: `IGLOO_RPC_*` env vars or `[rpc]` config
 (docs/distributed.md#failure-model). This module is the package's ONLY
 Flight connection site — the igloo-lint `rpc-policy` checker flags
 `flight.connect` anywhere else, so no code path can bypass the deadlines.
+
+CONNECTIONS: the helpers keep one process-wide pool of idle connections per
+peer address (`_ConnPool`). A call checks one out (or opens one), has it to
+itself, and checks it back in only after it SUCCEEDED; whatever raised, was
+closed early or was abandoned closes its connection, and every retry opens a
+new one — so the failure model above reads as it did when each attempt
+connected, and a first attempt to a peer just spoken to costs a round trip
+and no TCP + HTTP/2 set-up. The auth token rides in `call_options` per call:
+a kept connection carries none.
 """
 from __future__ import annotations
 
+import atexit
 import contextlib
 import dataclasses
 import json
@@ -218,7 +228,8 @@ _default_policy: Optional[RpcPolicy] = None
 # policies built from a half-read environment
 _policy_lock = threading.Lock()
 
-_GUARDED_BY = {"_policy_lock": ("_default_policy",)}
+_GUARDED_BY = {"_policy_lock": ("_default_policy",),
+               "_lock": ("_idle",)}     # _ConnPool
 
 
 def default_policy() -> RpcPolicy:
@@ -273,17 +284,117 @@ def connect(addr: str) -> flight.FlightClient:
     """The package's ONE Flight connection site (gRPC connects lazily; the
     per-call deadline in `call_options` bounds establishment + call). Every
     other module must come through here or the `flight_*` helpers — enforced
-    by the igloo-lint `rpc-policy` checker."""
+    by the igloo-lint `rpc-policy` checker. The caller owns the connection
+    (`DistributedClient` keeps its own); the helpers below lease theirs from
+    the pool."""
+    tracing.counter("rpc.conn_opened")
     return flight.connect(normalize(addr))
 
 
+def _close_quietly(client: flight.FlightClient) -> None:
+    try:
+        client.close()
+    except Exception:
+        pass
+
+
+class _ConnPool:
+    """Idle Flight connections of this process, each under its normalized
+    peer address. A connection is made only when its address has none idle
+    (a retry drops them first), so an address never holds more than were in
+    use at the same time; over all addresses the idle ones are bounded by
+    `MAX_IDLE`, least recently returned first out (a test session that
+    starts hundreds of servers on ephemeral ports must not keep a
+    connection to each dead one)."""
+
+    MAX_IDLE = 64
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._idle: list = []   # (address, connection), oldest return first
+
+    def take(self, addr: str) -> Optional[flight.FlightClient]:
+        """The most recently returned idle connection to `addr`, if any."""
+        with self._lock:
+            for i in range(len(self._idle) - 1, -1, -1):
+                if self._idle[i][0] == addr:
+                    return self._idle.pop(i)[1]
+        return None
+
+    def give(self, addr: str, client: flight.FlightClient) -> None:
+        """Keep a connection whose call ran to its end."""
+        with self._lock:
+            self._idle.append((addr, client))
+            evicted = self._idle[:-self.MAX_IDLE]
+            del self._idle[:-self.MAX_IDLE]
+        for _, c in evicted:
+            _close_quietly(c)
+
+    def drop(self, addr: Optional[str] = None) -> None:
+        """Close the idle connections to `addr` (None: to every peer)."""
+        with self._lock:
+            gone = [e for e in self._idle if addr in (None, e[0])]
+            self._idle = [e for e in self._idle if addr not in (None, e[0])]
+        for _, c in gone:
+            _close_quietly(c)
+
+    def idle(self, addr: Optional[str] = None) -> int:
+        with self._lock:
+            return sum(addr in (None, a) for a, _ in self._idle)
+
+
+_pool = _ConnPool()
+atexit.register(_pool.drop)
+
+
+def close_idle_connections() -> None:
+    """Close every idle pooled connection of this process (a server's
+    shutdown; at interpreter exit by `atexit`). A connection in use is its
+    caller's, and goes where its call sends it."""
+    _pool.drop()
+
+
+def idle_connections(addr: Optional[str] = None) -> int:
+    """How many idle connections the pool holds (to `addr`, or in all)."""
+    return _pool.idle(None if addr is None else normalize(addr))
+
+
+class _Lease:
+    """One connection, its holder's alone: an idle one from the pool, else a
+    new one. A retry (`fresh`) always opens its own, and first drops what the
+    pool holds for that peer — after a failure they are suspects. `release`
+    returns the connection to the pool, `discard` closes it; whichever comes
+    first wins, so a stream's finally block and its weakref finalizer may
+    both run."""
+
+    def __init__(self, addr: str, fresh: bool = False):
+        self.addr = normalize(addr)
+        self._done = False
+        if fresh:
+            _pool.drop(self.addr)
+        client = None if fresh else _pool.take(self.addr)
+        if client is not None:
+            tracing.counter("rpc.conn_reused")
+        self.client = client or connect(self.addr)
+
+    def release(self) -> None:
+        if not self._done:
+            self._done = True
+            _pool.give(self.addr, self.client)
+
+    def discard(self) -> None:
+        if not self._done:
+            self._done = True
+            _close_quietly(self.client)
+
+
 def _run_attempts(addr: str, what: str, fn, policy: Optional[RpcPolicy],
-                  deadline: Optional[float], close_on_success: bool = True):
-    """The ONE retry loop: connect per attempt, run `fn(client)`, classify-
-    then-retry with backoff, never past the caller's deadline. With
-    `close_on_success=False` the client survives a successful attempt (the
-    stream-open path — the connection must outlive the call); every failure
-    path still closes it."""
+                  deadline: Optional[float]):
+    """The ONE retry loop: lease a connection per attempt (the first from
+    the pool, every retry a new one), run `fn(lease)`, classify-then-retry
+    with backoff, never past the caller's deadline. An attempt that raises
+    discards its connection; one that returns leaves its lease to `fn`'s
+    caller, who releases it (an action: at once; a stream: when exhausted)."""
     policy = policy or default_policy()
     attempt = 0
     # timeline: inside an active flight-recorder scope each ATTEMPT is a
@@ -293,15 +404,15 @@ def _run_attempts(addr: str, what: str, fn, policy: Optional[RpcPolicy],
     traced = flight_recorder.current() is not None
     while True:
         check_deadline(deadline, what)
-        client = None
+        lease = None
         ok = False
         try:
             span_cm = tracing.span("rpc", what=what, attempt=attempt) \
                 if traced else contextlib.nullcontext()
             with span_cm:
                 faults.inject(f"client.{what}")
-                client = connect(addr)
-                out = fn(client)
+                lease = _Lease(addr, fresh=attempt > 0)
+                out = fn(lease)
             ok = True
             return out
         except Exception as ex:
@@ -320,8 +431,8 @@ def _run_attempts(addr: str, what: str, fn, policy: Optional[RpcPolicy],
                 raise
             time.sleep(delay)
         finally:
-            if client is not None and not (ok and not close_on_success):
-                client.close()
+            if lease is not None and not ok:
+                lease.discard()
 
 
 def _with_retry(addr: str, what: str, fn, policy: Optional[RpcPolicy],
@@ -329,12 +440,15 @@ def _with_retry(addr: str, what: str, fn, policy: Optional[RpcPolicy],
                 timeout_s: Optional[float] = None):
     """Run `fn(client, options)` under the policy: per-attempt deadline
     (recomputed each attempt as the caller's absolute deadline shrinks),
-    classify-then-retry with backoff."""
+    classify-then-retry with backoff. The attempt that returns gives its
+    connection back to the pool."""
     policy = policy or default_policy()
 
-    def attempt(client):
+    def attempt(lease):
         t = _effective_timeout(timeout_s or policy.call_timeout_s, deadline)
-        return fn(client, call_options(timeout_s=t))
+        out = fn(lease.client, call_options(timeout_s=t))
+        lease.release()
+        return out
     return _run_attempts(addr, what, attempt, policy, deadline)
 
 
@@ -342,7 +456,7 @@ def flight_action(addr: str, name: str, payload: Optional[dict] = None,
                   policy: Optional[RpcPolicy] = None,
                   deadline: Optional[float] = None,
                   timeout_s: Optional[float] = None) -> dict:
-    """One-shot action RPC: connect, act, close — under the RPC policy
+    """One action RPC on a pooled connection — under the RPC policy
     (per-call deadline, retry/backoff on retryable failures). Returns the
     decoded first result (or {}). `deadline` is an absolute `time.time()`
     bound the whole call (retries included) must respect."""
@@ -356,7 +470,7 @@ def flight_action_raw(addr: str, name: str,
                       policy: Optional[RpcPolicy] = None,
                       deadline: Optional[float] = None,
                       timeout_s: Optional[float] = None) -> bytes:
-    """One-shot action RPC returning the raw first-result bytes — for
+    """One action RPC returning the raw first-result bytes — for
     actions whose payload is NOT JSON (the `metrics` Prometheus text)."""
     body = json.dumps(payload).encode() if payload is not None else b""
 
@@ -371,24 +485,26 @@ def flight_actions_raw(addr: str, actions,
                        policy: Optional[RpcPolicy] = None):
     """Run several action RPCs over ONE connection, yielding each action's
     raw first-result bytes in order. `actions` iterates (name, payload)
-    pairs. The connection closes when the generator is exhausted or closed —
+    pairs. The connection goes back to the pool when the generator is
+    exhausted; a call that raises, or a generator closed early, closes it —
     the worker's registration pre-warm pulls hundreds of compile-cache
-    entries and must not pay a TCP connect/teardown per entry. Each call
-    carries the policy's per-call deadline but is NOT retried (callers — the
-    compile-cache push/pull loops — already have per-entry retry logic, and
-    replaying the already-consumed prefix of `actions` is impossible)."""
+    entries over it. Each call carries the policy's per-call deadline but is
+    NOT retried (callers — the compile-cache push/pull loops — already have
+    per-entry retry logic, and replaying the already-consumed prefix of
+    `actions` is impossible)."""
     policy = policy or default_policy()
-    client = connect(addr)
+    lease = _Lease(addr)
     try:
         for name, payload in actions:
             faults.inject(f"client.action.{name}")
             body = json.dumps(payload).encode() if payload is not None else b""
-            results = list(client.do_action(
+            results = list(lease.client.do_action(
                 flight.Action(name, body),
                 call_options(timeout_s=policy.call_timeout_s)))
             yield results[0].body.to_pybytes() if results else b""
+        lease.release()
     finally:
-        client.close()
+        lease.discard()
 
 
 def flight_stream_response(schema, gen):
@@ -431,14 +547,16 @@ def flight_stream_batches(addr: str, ticket,
     already yielded cannot be un-consumed). A bounded `ping` probe
     (connect_timeout_s) catches a HUNG peer at open time; without it a
     worker that accepts TCP but never answers would hold do_get for the
-    full stream timeout. The connection is also closed by a weakref
-    finalizer when a consumer ABANDONS the generator without closing it —
-    a never-started generator's close() does not run its finally block, and
-    before this fix each abandoned stream leaked one Flight connection."""
+    full stream timeout (on a kept connection the probe is a round trip).
+    The connection returns to the pool only when the generator is EXHAUSTED;
+    a stream that raises, is closed early, or is ABANDONED (the weakref
+    finalizer: a never-started generator's close() does not run its finally
+    block) closes it — a half-read stream never goes back."""
     raw = ticket if isinstance(ticket, bytes) else ticket.encode()
     policy = policy or default_policy()
 
-    def open_stream(c):
+    def open_stream(lease):
+        c = lease.client
         probe_t = _effective_timeout(policy.connect_timeout_s, deadline)
         list(c.do_action(flight.Action("ping", b""),
                          call_options(timeout_s=probe_t)))
@@ -446,32 +564,19 @@ def flight_stream_batches(addr: str, ticket,
         reader = c.do_get(flight.Ticket(raw), call_options(timeout_s=t))
         # the schema read is where a hung/failed do_get actually surfaces —
         # it must happen inside the retried attempt
-        return c, reader, reader.schema
+        return lease, reader, reader.schema
 
-    client, reader, schema = _run_attempts(addr, "do_get", open_stream,
-                                           policy, deadline,
-                                           close_on_success=False)
-
-    done = [False]
-
-    def cleanup():
-        # idempotent: the generator's finally on the normal path, the
-        # weakref finalizer when the consumer drops an unstarted generator
-        if done[0]:
-            return
-        done[0] = True
-        try:
-            client.close()
-        except Exception:
-            pass
+    lease, reader, schema = _run_attempts(addr, "do_get", open_stream,
+                                          policy, deadline)
 
     def gen():
         try:
             for chunk in reader:
                 if chunk.data is not None:
                     yield chunk.data
+            lease.release()
         finally:
-            cleanup()
+            lease.discard()
     g = gen()
-    weakref.finalize(g, cleanup)
+    weakref.finalize(g, lease.discard)
     return schema, g

@@ -844,6 +844,7 @@ class Worker:
     def shutdown(self) -> None:
         self._stop.set()
         self.server.shutdown()
+        rpc.close_idle_connections()
 
 
 def main(argv=None) -> int:
