@@ -86,6 +86,16 @@ class MemTable:
         return self._table.nbytes
 
 
+class EphemeralTable(MemTable):
+    """A result that is the input of ONE execution: a fragment's dependency
+    on a worker, a chunk's partial aggregate in the chunked tier. Its table
+    name carries a per-query id; `ephemeral` tells the fused compiler to key
+    a scan of it by position instead (exec/fused.py `_c_scan`), so the
+    consumer's program is built once, not per query."""
+
+    ephemeral = True
+
+
 class Catalog:
     """Thread-safe name -> provider registry (the coordinator serves one per
     cluster; the reference wraps a plain HashMap, catalog.rs:10-27)."""

@@ -427,9 +427,11 @@ def predict_hbm_bytes(plan) -> int:
     gate: the AdaptiveStats `peak_hbm_bytes` observation for the plan's
     structural fingerprint when one exists (a previous run of the same
     shape MEASURED its watermark), else a conservative first-sight estimate
-    — decoded-lane bytes of every scanned source, doubled for join/sort
-    intermediates. Over-estimation costs concurrency; under-estimation is
-    what the degradation ladder exists to absorb (docs/serving.md)."""
+    — what each scan's lanes will hold on the device (the columns it
+    reads, `chunked.estimated_lane_bytes`: the price the routing ladder
+    uses), doubled for join/sort intermediates. Over-estimation costs
+    concurrency; under-estimation is what the degradation ladder exists to
+    absorb (docs/serving.md)."""
     from igloo_tpu.exec import hints
     if hints.adaptive_enabled():
         fp = hints.plan_fp(plan)
@@ -437,12 +439,5 @@ def predict_hbm_bytes(plan) -> int:
             rec = hints.adaptive_store().observed(fp)
             if rec and rec.get("peak_hbm_bytes"):
                 return int(rec["peak_hbm_bytes"])
-    from igloo_tpu.exec.chunked import estimated_lane_bytes
-    from igloo_tpu.plan import logical as L
-    total = 0
-    for n in L.walk_plan(plan):
-        if isinstance(n, L.Scan) and n.provider is not None:
-            nb = estimated_lane_bytes(n.provider)
-            if nb:
-                total += nb
-    return int(total * 2)
+    from igloo_tpu.exec.chunked import priced_scans
+    return 2 * priced_scans(plan)

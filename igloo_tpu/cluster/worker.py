@@ -39,7 +39,7 @@ from typing import Optional
 import pyarrow as pa
 import pyarrow.flight as flight
 
-from igloo_tpu.catalog import Catalog, MemTable
+from igloo_tpu.catalog import Catalog, EphemeralTable
 from igloo_tpu.cluster import events, exchange, faults, protocol, serde
 from igloo_tpu.cluster.fragment import (FRAG_PREFIX, _frag_refs,
                                         _subtree_scan, _with_partition)
@@ -105,15 +105,6 @@ def _dep_key(frag_id: str, bucket) -> str:
     are hex, so `__dep_*` keys cannot collide with produced results."""
     base = f"__dep_{frag_id}:"
     return base if bucket is None else f"{base}{bucket}"
-
-
-class _DependencyTable(MemTable):
-    """A dependency's result as the input of ONE fragment execution. Its
-    table name carries the dependency's per-query id; `ephemeral` tells the
-    fused compiler to key a scan of it by position instead (exec/fused.py
-    `_c_scan`), so the consumer's program is built once, not per query."""
-
-    ephemeral = True
 
 
 class _OverlayCatalog:
@@ -284,7 +275,7 @@ class WorkerServer(flight.FlightServerBase):
                                         ref.get("bucket"), ref.get("buckets"),
                                         deadline=deadline)
                     input_rows += t.num_rows
-                    overlay[name] = _DependencyTable(t)
+                    overlay[name] = EphemeralTable(t)
                 dep_s = time.perf_counter() - t_dep0
                 catalog = _OverlayCatalog(self._catalog, overlay)
                 plan = serde.plan_from_json(plan_json, catalog)

@@ -47,6 +47,7 @@ class ParquetTable:
         self.path = path
         self._store = store if store is not None else local_store()
         self._parts = None  # lazy (file, row_group) partition index
+        self._lanes = None  # (the files' etags + sizes, their lane_stats())
         self._pruned: dict = {}  # (file, etag, predicates) -> groups kept
         self._plock = threading.Lock()  # guards _files/_parts (Flight threads)
         self._files = _expand_store(self._store, path)
@@ -129,6 +130,45 @@ class ParquetTable:
 
     def estimated_bytes(self) -> Optional[int]:
         return self._store.files_bytes(self._files)
+
+    def lane_stats(self) -> Optional[tuple]:
+        """(rows, {column: (lo, hi, nulls)}) of the table, from the files'
+        footers alone: what a scan of it is priced from before any data is
+        read (exec/chunked.py estimated_lane_bytes). `lo` and `hi` bound an
+        integer or date32 column's values (the physical integers of the
+        row groups' statistics: the days of a date), None where a row group
+        states none or the column is of another type: the width of its
+        carrier is then not known before the data is. `nulls`: some row
+        group counts a null, or does not say. Remembered per version of the
+        files (their etags and sizes: one `head` each, what
+        `estimated_bytes` costs; the file SET is the one the last
+        `snapshot()` listed): a routing decision over files that have not
+        changed opens nothing, and pins nothing.
+        None when a footer cannot be read: the price is then the file's."""
+        with self._plock:
+            files = list(self._files)
+        tok = self._store.snapshot_token(files)[0]
+        memo = self._lanes
+        if memo is not None and memo[0] == tok:
+            return memo[1]
+        bounded = {f.name for f in self._arrow_schema
+                   if pa.types.is_integer(f.type) or pa.types.is_date32(f.type)}
+        rows, cols = 0, {}
+        try:
+            for path in files:
+                meta = pq.ParquetFile(self._open(path)).metadata
+                rows += meta.num_rows
+                for g in range(meta.num_row_groups):
+                    rg = meta.row_group(g)
+                    for c in range(rg.num_columns):
+                        _fold_stats(cols, rg.column(c), bounded)
+            stats = (rows, cols)
+        except Exception:
+            # a price is best effort, as `estimated_bytes` is: the read that
+            # follows raises what is wrong with the file, typed
+            stats = None
+        self._lanes = (tok, stats)
+        return stats
 
     def surviving_parts(self, filters, partition=None):
         """What a read of `partition` (None: the table) under `filters`
@@ -238,6 +278,29 @@ class ParquetTable:
         except Exception as ex:
             raise quarantine.record(path, fh.etag, -1, str(ex),
                                     table=self.path) from None
+
+
+#: (lo, hi, nulls) of a column no chunk has been folded into: lo > hi
+_NO_VALUES = (0, -1, False)
+
+
+def _fold_stats(cols: dict, chunk, bounded: set) -> None:
+    """Widen `cols[name]` = (lo, hi, nulls) by one column chunk's footer
+    statistics (`ParquetTable.lane_stats`)."""
+    name = chunk.path_in_schema
+    lo, hi, nulls = cols.get(name, _NO_VALUES)
+    st = chunk.statistics
+    nulls = nulls or st is None or not st.has_null_count or st.null_count > 0
+    if name not in bounded or lo is None:
+        lo = hi = None
+    elif st is None or not st.has_min_max:
+        if st is None or st.num_values:     # values, and no bounds for them
+            lo = hi = None
+    elif lo > hi:
+        lo, hi = st.min_raw, st.max_raw
+    else:
+        lo, hi = min(lo, st.min_raw), max(hi, st.max_raw)
+    cols[name] = (lo, hi, nulls)
 
 
 def files_bytes(files: list[str]) -> Optional[int]:

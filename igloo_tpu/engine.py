@@ -79,18 +79,20 @@ class QueryEngine:
         self.udfs: dict[str, UdfDef] = {}
         self._jit_cache: dict = {}
         self._use_jit = use_jit
-        # source tables whose estimated DEVICE-LANE size exceeds this
-        # execute partition-at-a-time (exec/chunked.py) or via
-        # GRACE-partitioned joins (exec/grace.py) instead of as one
-        # DeviceBatch. Comparisons use estimated_lane_bytes (file estimates
-        # x the provider's bytes_expansion): SF10's 1.2 GB parquet lineitem
-        # decodes to ~4 GB of int64/float64 lanes, and its full-width join
-        # intermediates at 67M lanes crash a 16 GB-HBM chip if run
-        # monolithically. None: the monolithic share of the device's memory
-        # (exec/cache.py hbm_budgets: 1/8, 2.1 GB on the v5e; 2 GiB on
-        # XLA:CPU, which reports no limit), read at the first routing
-        # decision so that constructing an engine touches no device
+        # what one program may scan (docs/out_of_core.md "The two
+        # budgets"). A number given bounds it under both rules of the
+        # ladder. None: each rule takes its share of the device's memory
+        # (exec/cache.py hbm_budgets) — a decomposable aggregate over a scan
+        # is chunked (exec/chunked.py) when the columns it reads are priced
+        # over the RESIDENT share (1/2: SF10's seven q1 columns, 2.0 GB at
+        # 2^26 lanes, run as one program over resident columns, as a worker's
+        # scan fragment does), a join tree goes through GRACE (exec/grace.py)
+        # when a table of it is sized over the MONOLITHIC share (1/8, 2.1 GB
+        # on the v5e: no join at 2^26 lanes has run on a chip). The shares
+        # are read at the first routing decision, so that constructing an
+        # engine touches no device
         self._chunk_budget_bytes = chunk_budget_bytes
+        self._shares: Optional[tuple] = None
         # multi-chip execution: "auto" = row-shard across all local devices
         # when more than one is visible (parallel/ShardedExecutor); None =
         # single-device; or an explicit jax.sharding.Mesh
@@ -385,18 +387,38 @@ class QueryEngine:
         finally:
             self._demote_tls.budget, self._demote_tls.force_host = prev
 
+    def _derived_shares(self) -> tuple:
+        if self._shares is None:
+            from igloo_tpu.exec.cache import hbm_budgets
+            self._shares = hbm_budgets()
+        return self._shares
+
     @property
     def chunk_budget_bytes(self) -> int:
-        if self._chunk_budget_bytes is None:
-            from igloo_tpu.exec.cache import hbm_budgets
-            self._chunk_budget_bytes = hbm_budgets()[1]
-        return self._chunk_budget_bytes
+        """The constructor's number, else the device's monolithic share:
+        what a table of a join may come to before GRACE partitions it, and
+        what the demotion ladder takes its quarter of."""
+        if self._chunk_budget_bytes is not None:
+            return self._chunk_budget_bytes
+        return self._derived_shares()[1]
 
     def _chunk_budget(self) -> int:
+        """`chunk_budget_bytes` under this thread's demotion, if any."""
         override = getattr(self._demote_tls, "budget", None)
         if override is not None:
             return min(int(override), self.chunk_budget_bytes)
         return self.chunk_budget_bytes
+
+    def _scan_budget(self) -> int:
+        """What the columns one scan-and-aggregate program reads may be
+        priced at (exec/chunked.py chunk_count): a number given — the
+        constructor's, this thread's demotion — as `_chunk_budget`; none
+        given, the device's resident share, under which the scan cache
+        holds them once for every later query."""
+        if self._chunk_budget_bytes is not None or \
+                getattr(self._demote_tls, "budget", None) is not None:
+            return self._chunk_budget()
+        return self._derived_shares()[0]
 
     def _resolve_mesh(self):
         """The execution mesh, resolved once: None for single-device."""
@@ -431,14 +453,19 @@ class QueryEngine:
     def _execute_plan(self, plan: L.LogicalPlan) -> pa.Table:
         """The full routing ladder shared by _run_select and EXPLAIN ANALYZE:
         host tier (small sources, accelerator backend) -> chunked tier
-        (decomposable aggregates over big scans) -> GRACE tier (over-budget
-        join trees, exec/grace.py) -> normal executor. A resolved multi-chip
+        (decomposable aggregates over scans whose columns are priced over
+        `_scan_budget`) -> GRACE tier (join trees with a table over
+        `_chunk_budget`, exec/grace.py) -> normal executor. A resolved multi-chip
         mesh takes precedence over single-device chunking / out-of-core: the
         sharded executor already bounds per-chip memory by row-sharding, and
         silently chunking would discard the parallelism."""
-        from igloo_tpu.exec.chunked import LocalChunkExecutor, chunk_count
+        from igloo_tpu.exec.chunked import LocalChunkExecutor, \
+            chunk_count, scan_prices
         qs = stats.current()
         budget = self._chunk_budget()
+        prices = scan_prices(plan)
+        tracing.counter("engine.route_priced_bytes",
+                        sum(v or 0 for v in prices.values()))
         force_host = getattr(self._demote_tls, "force_host", False)
         if force_host or self._host_route(plan):
             from igloo_tpu.exec.host import HostExecutor, HostUnsupported
@@ -461,7 +488,8 @@ class QueryEngine:
                 # tier, not fail the query
                 tracing.counter("engine.host_route_oom")
         mesh = self._resolve_mesh()
-        chunks = 0 if mesh is not None else chunk_count(plan, budget)
+        chunks = 0 if mesh is not None else \
+            chunk_count(plan, self._scan_budget(), prices)
         grace_found = None
         if mesh is None and not chunks:
             from igloo_tpu.exec.grace import find_grace_join

@@ -24,12 +24,20 @@ from igloo_tpu.utils.tracing import counter
 
 
 # Shares of ONE device's `memory_stats()["bytes_limit"]` (docs/out_of_core.md
-# "The two budgets"): what the scan cache may keep resident, and the largest
-# source one monolithic program may scan before the chunked / GRACE tiers are
-# chosen. A program's inputs ARE resident columns, so the other half of the
-# device is for its temporaries. The monolithic share keeps on a 16 GB chip
-# the 2 GiB that every chip run so far was routed under: a larger one takes a
-# chip run of an SF10 join first.
+# "The two budgets"). A program's inputs ARE resident columns, so the other
+# half of the device is for its temporaries:
+# - RESIDENT: what the scan cache may keep, and so what the columns ONE
+#   scan-and-aggregate program reads may be priced at before the chunked tier
+#   takes it (exec/chunked.py chunk_count) — columns that fit are cached once
+#   and every later query hits them. Rests on every chip run of the served
+#   SF10 cells since PR 30 (q1 / q6 as one program over 2^26 lanes, seven
+#   columns resident, 2.0 GB: PERF_LEDGER.jsonl) and on the in-process SF10
+#   cell of PR 36.
+# - MONOLITHIC: the largest table a JOIN tree may hold before GRACE
+#   partitions it (exec/grace.py find_grace_join). It keeps on a 16 GB chip
+#   the 2 GiB that every join on a chip was routed under: a join's
+#   intermediates at full width are several times its inputs, and no join at
+#   2^26 lanes has run on a chip (ROADMAP A2a). Raising it takes that run.
 RESIDENT_SHARE = 1 / 2
 MONOLITHIC_SHARE = 1 / 8
 # where the backend reports no limit (XLA:CPU): the constants the engine had
@@ -39,8 +47,8 @@ UNLIMITED_BUDGETS = (1 << 30, 2 << 30)
 
 def hbm_budgets() -> tuple:
     """(resident, monolithic) bytes for this process's devices: the scan
-    cache's budget and the out-of-core threshold, shared by `QueryEngine` and
-    the cluster worker. The smallest local device decides (a mesh row-shards
+    cache's budget, which is also the chunked tier's threshold, and the GRACE
+    trigger's, shared by `QueryEngine` and the cluster worker. The smallest local device decides (a mesh row-shards
     evenly, so the fullest chip is the tightest). This starts the backend:
     call it where a device is needed anyway, not at construction."""
     import jax

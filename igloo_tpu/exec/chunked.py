@@ -42,17 +42,16 @@ def estimated_bytes(provider) -> Optional[int]:
     return None
 
 
-def estimated_lane_bytes(provider) -> Optional[int]:
-    """Estimated size once RESIDENT as device lanes: the raw estimate times
-    the provider's `bytes_expansion` (compressed parquet decodes to ~3-4x
-    its file size as int64/float64 lanes; in-memory Arrow tables report
-    decoded bytes already, factor 1), times the measured carrier ratio
-    (codec.carrier_ratio — columns stay NARROW in HBM since PR 16, so a
-    provider whose scans ride int8/int16 carriers prices well under its
-    wide-lane size; unmeasured providers price at ratio 1.0, the safe
-    upper bound). Every device-memory budget check — chunked tier, GRACE
-    trigger, serving's predict_hbm_bytes — flows through THIS, not file
-    bytes."""
+def table_lane_bytes(provider) -> Optional[int]:
+    """Estimated size of the WHOLE table as device lanes, every column of
+    it: the raw estimate times the provider's `bytes_expansion` (compressed
+    parquet decodes to ~3-4x its file size as int64/float64 lanes; in-memory
+    Arrow tables report decoded bytes already, factor 1), times the carrier
+    ratio its last scan measured (codec.carrier_ratio). A measure of tables
+    against each other and against the monolithic share: the optimizer's
+    join order and build sides (plan/optimizer.py) and the GRACE trigger
+    (exec/grace.py) read it. What ONE scan may hold on the device is
+    `estimated_lane_bytes`."""
     nb = estimated_bytes(provider)
     if nb is None:
         return None
@@ -61,9 +60,81 @@ def estimated_lane_bytes(provider) -> Optional[int]:
                * codec.carrier_ratio(provider))
 
 
-def chunk_count(plan: L.LogicalPlan, budget_bytes: int) -> int:
-    """How many chunks the largest over-budget scanned table needs (0 = no
-    chunking). Only scans that the fragment planner can actually stream —
+def estimated_lane_bytes(provider, projection=None) -> Optional[int]:
+    """The price of a scan: the bytes its lanes will hold on the device,
+    from the provider's metadata alone (no data read). A function of the
+    file and the columns read (`projection`: None reads every column), so
+    the same scan is priced alike whatever ran before it; the chunked
+    tier's trigger and chunk count (`chunk_count`) and serving's
+    `predict_hbm_bytes` all read THIS.
+
+    A provider that knows its rows and its columns' bounds without reading
+    them (`lane_stats`: Parquet footers) is priced lane by lane, as the scan
+    lays them out: the scan's capacity times each column's resident width,
+    a null lane where a footer counts nulls, and the live lane. A carrier
+    narrows the price only where the footer proves it (codec `_shrink_int`'s
+    steps): an integer or date column whose statistics' range fits a
+    narrower integer, a dictionary column's ids by the row count (a table
+    has no more distinct strings than rows). A float64 column is priced at
+    8 bytes — whether the codec ships it narrower (whole numbers, decimals),
+    or a dictionary holds few strings, is known only once the values are
+    read, and a price never follows what another query's scan found. So the
+    price is an upper bound of what the scan holds, exact for the columns a
+    footer bounds. A provider without such metadata (CSV, a
+    MemTable, DBAPI) keeps the conservative price of its whole size times
+    `bytes_expansion`."""
+    stats_of = getattr(provider, "lane_stats", None)
+    stats = stats_of() if stats_of is not None else None
+    if stats is None:
+        nb = estimated_bytes(provider)
+        if nb is None:
+            return None
+        return int(nb * getattr(provider, "bytes_expansion", 1.0))
+    from igloo_tpu.exec.batch import round_capacity
+    from igloo_tpu.exec.codec import encoded_enabled, narrow_int_dtype
+    rows, cols = stats
+    narrow = encoded_enabled()
+    per_lane = 1                                    # the live lane: bool
+    for f in provider.schema():
+        if projection is not None and f.name not in projection:
+            continue
+        lo, hi, nulls = cols.get(f.name, (None, None, True))
+        if f.dtype.is_string:
+            lo, hi = 0, rows - 1            # dictionary ids
+        lane = f.dtype.device_dtype()
+        # lo > hi: a column without a value, the narrowest carrier
+        carrier = narrow_int_dtype(lo, hi, lane) \
+            if narrow and lo is not None else None
+        per_lane += (carrier or lane).itemsize + bool(nulls)
+    return round_capacity(rows) * per_lane
+
+
+def scan_prices(plan: L.LogicalPlan) -> dict:
+    """{id(scan): `estimated_lane_bytes` of it} for every scan of `plan`
+    that has a provider (None: nobody can size it). One routing decision
+    prices each scan ONCE — a price costs a `head` of every file, and on a
+    slow file system that is a tenth of a millisecond — and hands the
+    prices to `chunk_count`."""
+    return {id(sc): estimated_lane_bytes(sc.provider, sc.projection)
+            for sc in L.walk_plan(plan)
+            if isinstance(sc, L.Scan) and sc.provider is not None}
+
+
+def priced_scans(plan: L.LogicalPlan) -> int:
+    """What the scans of `plan` are priced at, in all (a scan nobody can
+    size counts nothing)."""
+    return sum(v or 0 for v in scan_prices(plan).values())
+
+
+def chunk_count(plan: L.LogicalPlan, budget_bytes: int,
+                prices: Optional[dict] = None) -> int:
+    """How many chunks the largest scan priced over `budget_bytes` needs
+    (0 = one program). `prices`: `scan_prices(plan)`, where the caller has
+    them already. `budget_bytes` is what one program may scan: the
+    resident share of the device (exec/cache.py hbm_budgets) — columns that
+    fit it are cached once and every later query hits them — or the number a
+    caller was given (`QueryEngine(chunk_budget_bytes=)`, the demotion
+    ladder). Only scans that the fragment planner can actually stream —
     i.e. feeding a DECOMPOSABLE aggregate through scan/filter/project nodes —
     count: chunking anything else just unions the chunks back into one batch
     and pays fragment overhead for no memory bound (see module docstring)."""
@@ -77,14 +148,15 @@ def chunk_count(plan: L.LogicalPlan, budget_bytes: int) -> int:
         for sc in L.walk_plan(node.input):
             if isinstance(sc, L.Scan) and sc.provider is not None and \
                     sc.partition is None:
-                nbytes = estimated_lane_bytes(sc.provider)
+                nbytes = prices[id(sc)] if prices is not None else \
+                    estimated_lane_bytes(sc.provider, sc.projection)
                 try:
                     parts = sc.provider.num_partitions()
                 except Exception:
                     parts = 1
                 if nbytes is not None and nbytes > budget_bytes and parts > 1:
                     # the chunk count is DERIVED from the budget (how many
-                    # budget-sized pieces the table decodes into); the only
+                    # budget-sized pieces the scan's lanes come to); the only
                     # clamp left is the provider's own partition granularity,
                     # and hitting it means per-chunk memory exceeds the
                     # budget — warn instead of silently un-bounding (the old
@@ -113,7 +185,7 @@ class LocalChunkExecutor:
         self.chunks = max(2, chunks)
 
     def execute_to_arrow(self, plan: L.LogicalPlan) -> pa.Table:
-        from igloo_tpu.catalog import MemTable
+        from igloo_tpu.catalog import EphemeralTable
         from igloo_tpu.cluster import serde
         from igloo_tpu.cluster.fragment import FRAG_PREFIX, DistributedPlanner
         from igloo_tpu.exec.executor import Executor
@@ -134,7 +206,10 @@ class LocalChunkExecutor:
             def get(self, name: str):
                 key = name.lower()
                 if key.startswith(FRAG_PREFIX):
-                    return MemTable(results[key[len(FRAG_PREFIX):]])
+                    # a chunk's result lives for this execution and its name
+                    # holds a per-query id: keyed by position (exec/fused.py
+                    # `_c_scan`), so a repeated chunked query finds its merge
+                    return EphemeralTable(results[key[len(FRAG_PREFIX):]])
                 return base.get(name)
 
         overlay = _Overlay()

@@ -111,21 +111,32 @@ class WidenSpec:
         return (self.lane, self.scale != 1.0, self.scale, bool(self.offset))
 
 
+def narrow_int_dtype(lo: int, hi: int, lane: np.dtype):
+    """The carrier `_shrink_int` gives integers of `lane` that span
+    [lo, hi]: the narrowest step their RANGE fits, None when none is
+    narrower than the lane. What a column's bounds say of its resident
+    width before its values are read (exec/chunked.py estimated_lane_bytes)."""
+    for nd, (nlo, nhi) in _INT_STEPS:
+        nd_ = np.dtype(nd)
+        if nd_.itemsize >= lane.itemsize:
+            return None
+        if hi - lo <= nhi - nlo:
+            return nd_
+    return None
+
+
 def _shrink_int(v: np.ndarray, lane: np.dtype):
     """Offset-shrink an integer array; None when it cannot shrink."""
     if v.size == 0:
         return v.astype(np.int8), WidenSpec(lane.name)
     lo, hi = int(v.min()), int(v.max())
-    for nd, (nlo, nhi) in _INT_STEPS:
-        nd_ = np.dtype(nd)
-        if nd_.itemsize >= lane.itemsize:
-            return None
-        span = hi - lo
-        if span <= nhi - nlo:
-            # center the carrier range when an offset is needed at all
-            off = 0 if (nlo <= lo and hi <= nhi) else lo - nlo
-            return (v - off).astype(nd), WidenSpec(lane.name, offset=off)
-    return None
+    nd = narrow_int_dtype(lo, hi, lane)
+    if nd is None:
+        return None
+    nlo, nhi = np.iinfo(nd).min, np.iinfo(nd).max
+    # center the carrier range when an offset is needed at all
+    off = 0 if (nlo <= lo and hi <= nhi) else lo - nlo
+    return (v - off).astype(nd), WidenSpec(lane.name, offset=off)
 
 
 _FLOAT_SCALES = (1.0, 100.0, 10000.0)
@@ -402,14 +413,17 @@ def host_widen(spec: WidenSpec, vals: np.ndarray, carg=None) -> np.ndarray:
     return vals.astype(lane, copy=False)
 
 
-# --- measured carrier ratio: plan pricing in carrier bytes -------------------
-# The chunked/GRACE budget math and serving's predict_hbm_bytes estimate plans
-# in WIDE lane bytes (chunked.estimated_lane_bytes). Once a provider's columns
-# have actually shipped, the observed narrow/wide ratio is remembered PER
-# PROVIDER INSTANCE and those estimators scale by it — so more queries admit
-# concurrently and effective partitions grow per HBM budget. Keyed weakly so a
-# dropped provider cannot pin its entry; unmeasured providers price at 1.0
-# (estimates never shrink on faith).
+# --- measured carrier ratio: whole tables in carrier bytes -------------------
+# The GRACE trigger and the optimizer's join order estimate a WHOLE table in
+# wide lane bytes (chunked.table_lane_bytes). Once a provider's columns have
+# actually shipped, the observed narrow/wide ratio is remembered PER PROVIDER
+# INSTANCE — ONE number, written by the last scan of it, whatever columns
+# that scan read — and those estimators scale by it, so effective partitions
+# grow per HBM budget. The price of a SCAN (chunked.estimated_lane_bytes: the
+# chunked tier, serving's predict_hbm_bytes) does not read it: a routing
+# decision must not depend on which query ran last. Keyed weakly so a dropped
+# provider cannot pin its entry; unmeasured providers price at 1.0 (estimates
+# never shrink on faith).
 
 import weakref
 
@@ -431,9 +445,9 @@ def record_carrier_ratio(provider, narrow_bytes: int,
 
 def reset_carrier_ratios() -> None:
     """Forget every measured ratio — restores the price-wide-until-measured
-    cold state. For tests and A/B bench runs that need plan pricing (and so
-    chunked/GRACE/admission routing) independent of which queries ran
-    earlier in the process."""
+    cold state. For tests and A/B bench runs that need whole-table sizes (and
+    so GRACE routing and join order) independent of which queries ran earlier
+    in the process."""
     with _RATIO_LOCK:
         _CARRIER_RATIOS.clear()
 

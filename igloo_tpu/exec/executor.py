@@ -146,8 +146,9 @@ def col_meta(cols) -> tuple[list, list]:
 
 def _note_carrier_ratio(provider, batch: DeviceBatch) -> None:
     """Record the observed HBM carrier/wide byte ratio of a freshly scanned
-    batch against its provider instance, so the chunked/GRACE/serving budget
-    math (chunked.estimated_lane_bytes) prices this table in carrier bytes."""
+    batch against its provider instance, so the GRACE trigger and the
+    optimizer's join order (chunked.table_lane_bytes) size this table in
+    carrier bytes."""
     if provider is None or not batch.columns:
         return
     from igloo_tpu.exec.codec import record_carrier_ratio
@@ -567,13 +568,17 @@ class Executor:
         if pre is not None:
             return pre
         stable = getattr(plan.provider, "stable_row_order", False)
-        if self._batch_cache is None or not stable:
+        # a provider that lives for ONE execution (catalog.EphemeralTable: a
+        # fragment's dependency, a chunk's partial result) is read once,
+        # under a name no later query asks for: one upload, no cache entry
+        once = getattr(plan.provider, "ephemeral", False)
+        if self._batch_cache is None or not stable or once:
             # whole-batch path: providers without deterministic row order
             # (e.g. DBAPI SELECTs with no ORDER BY) must never stitch columns
             # from separate reads; they get one read per (projection) and a
             # whole-batch cache entry.
             key = snap = None
-            if self._batch_cache is not None:
+            if self._batch_cache is not None and not once:
                 from igloo_tpu.exec.cache import provider_snapshot, \
                     read_identity
                 key = (plan.table,
@@ -590,7 +595,7 @@ class Executor:
                     table = table.select(plan.projection)
                 batch = from_arrow(table, schema=plan.schema)
             _note_carrier_ratio(plan.provider, batch)
-            if self._batch_cache is not None:
+            if key is not None:
                 self._batch_cache.put(key, batch, snap)
             return batch
         # COLUMN-granular HBM cache: entries are per (table, what was read,

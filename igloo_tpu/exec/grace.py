@@ -192,7 +192,7 @@ def find_grace_join(plan: L.LogicalPlan, budget_bytes: int):
     """Locate a GRACE-v2-eligible over-budget join tree. Returns a GracePlan
     or None when the plan does not qualify (caller takes the normal path)."""
     from igloo_tpu.cluster.fragment import _DECOMPOSABLE
-    from igloo_tpu.exec.chunked import estimated_lane_bytes
+    from igloo_tpu.exec.chunked import table_lane_bytes
     path: list[L.LogicalPlan] = []
     node = plan
     agg: Optional[L.Aggregate] = None
@@ -217,7 +217,7 @@ def find_grace_join(plan: L.LogicalPlan, budget_bytes: int):
         total = 0
         for sc in L.walk_plan(leaf.node):
             if isinstance(sc, L.Scan) and sc.provider is not None:
-                b = estimated_lane_bytes(sc.provider)
+                b = table_lane_bytes(sc.provider)
                 if b is not None:
                     total += b
                     if b > budget_bytes:

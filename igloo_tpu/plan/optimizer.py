@@ -390,7 +390,7 @@ def reorder_adaptive_joins(plan: L.LogicalPlan) -> L.LogicalPlan:
     Effective size is OBSERVED output cardinality x estimated row width when
     the AdaptiveStats store (exec/hints.py) holds an observation for the
     subtree's structural fingerprint — post-filter cardinality bakes the
-    filter's real selectivity in — and `estimated_lane_bytes` of the
+    filter's real selectivity in — and `table_lane_bytes` of the
     subtree's scans otherwise. First run: estimates; later runs: observed
     (one recompile ever, thanks to the canonical shape families of
     docs/compile_cache.md).
@@ -467,13 +467,13 @@ def _est_subtree_lane_bytes(p: L.LogicalPlan) -> Optional[int]:
     """Estimated decoded device-lane bytes of the scans under `p`; None when
     any scan is unsized (then written order stands — no guess is better than
     a wrong one)."""
-    from igloo_tpu.exec.chunked import estimated_lane_bytes
+    from igloo_tpu.exec.chunked import table_lane_bytes
     total = 0
     for n in L.walk_plan(p):
         if isinstance(n, L.Scan):
             if n.provider is None:
                 return None
-            nb = estimated_lane_bytes(n.provider)
+            nb = table_lane_bytes(n.provider)
             if nb is None:
                 return None
             total += nb

@@ -98,7 +98,9 @@ def test_chunk_cap_derived_from_budget(big):
     eng.register_table("t", ParquetTable(path))
     plan = eng.plan("SELECT s, SUM(v) AS sv FROM t GROUP BY s")
     prov = eng.catalog.get("t")
-    nbytes = estimated_lane_bytes(prov)
+    # the price of the plan's scan: the two columns it reads, not the file
+    nbytes = estimated_lane_bytes(prov, ["s", "v"])
+    assert nbytes < estimated_lane_bytes(prov)
     parts = prov.num_partitions()  # 14 row groups
     # budget small enough that the NEED exceeds the provider's partitions:
     # the count clamps to `parts` and the warning counter fires
