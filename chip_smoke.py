@@ -209,7 +209,8 @@ def path_counters(delta: dict) -> dict:
     """Which executors and repair paths a query took (a fused program whose
     deferred flag fired re-runs staged, quietly — this is where it shows)."""
     return {k: v for k, v in sorted(delta.items()) if v and k.startswith(
-        ("fused.", "join.", "engine.", "codec.decimal_canary", "topk.",
+        ("fused.", "join.", "engine.", "codec.decimal_canary", "codec.f32pair",
+         "codec.f64_wide", "topk.",
          "grace.", "serving.demoted", "coordinator."))}
 
 

@@ -186,7 +186,11 @@ class DeviceColumn:
     operators that only move/mask/gather rows (filters via masks, compaction,
     resize, exchange staging) keep the carrier untouched. `carrier_arg` is the
     0-d runtime payload (real offset / scale divisor) matching the CANONICAL
-    spec in `carrier` — see codec.upload_columns for why it is runtime data."""
+    spec in `carrier` — see codec.upload_columns for why it is runtime data.
+    For an f32-pair carrier (`carrier.pair`, PR 37) it is the LOW half of the
+    float64 lane instead: a `[capacity]` f32 lane beside `values`, the high
+    half. A pair is the form a column is RESIDENT and read in; whatever moves
+    rows goes through `map_rows`, which widens it first."""
     dtype: DataType
     values: jax.Array              # [capacity], carrier dtype when `carrier` is set
     nulls: Optional[jax.Array]     # [capacity] bool, True = null; None = no nulls
@@ -203,8 +207,43 @@ class DeviceColumn:
     def capacity(self) -> int:
         return self.values.shape[0]
 
+    @property
+    def is_pair(self) -> bool:
+        """An f32-pair carrier: `carrier_arg` is a per-row lane."""
+        return self.carrier is not None and self.carrier.pair
+
+    @property
+    def carrier_nbytes(self) -> int:
+        """Resident bytes of the value lane in the form it is held: the
+        carrier's, and 8 B a lane for an f32 pair (both halves, once)."""
+        if self.is_pair:
+            return self.values.nbytes + self.carrier_arg.nbytes
+        return self.values.nbytes
+
+    @property
+    def nbytes(self) -> int:
+        """Resident bytes of every per-row lane: the carrier and the nulls."""
+        return self.carrier_nbytes + (
+            self.nulls.nbytes if self.nulls is not None else 0)
+
     def with_nulls(self, nulls: Optional[jax.Array]) -> "DeviceColumn":
         return replace(self, nulls=nulls)
+
+    def map_rows(self, fn) -> "DeviceColumn":
+        """The column with `fn` (a gather, a slice, a pad: anything that maps
+        a `[n]` lane to an `[m]` one, whatever its dtype) applied to each of
+        its per-row lanes. THE way to move rows: a narrow carrier rides along
+        untouched. An f32 pair does not: it is widened first (in-trace: inside
+        one program the wide value is the same two f32 arrays the chip's split
+        wrote before, intermediates the compiler may keep in fast memory) and
+        leaves as a wide lane — gathering its halves as two parameters out of
+        HBM cost a q3 20 ms of 204 on the v5e (PERF.md, PR 37). Bounds are
+        dropped, as every row mover did."""
+        col = materialize(self) if self.is_pair else self
+        return replace(
+            col, values=fn(col.values),
+            nulls=fn(col.nulls) if col.nulls is not None else None,
+            bounds=None)
 
 
 def wide_values(col: DeviceColumn) -> jax.Array:
@@ -215,6 +254,8 @@ def wide_values(col: DeviceColumn) -> jax.Array:
     spec = col.carrier
     if spec is None:
         return col.values
+    if spec.pair:
+        return spec.widen(col.values, lo_arg=col.carrier_arg)
     if spec.scale != 1.0:
         return spec.widen(col.values, scale_arg=col.carrier_arg)
     if spec.offset:
@@ -257,12 +298,7 @@ class DeviceBatch:
         return int(jnp.sum(self.live))
 
     def nbytes(self) -> int:
-        total = self.live.nbytes
-        for c in self.columns:
-            total += c.values.nbytes
-            if c.nulls is not None:
-                total += c.nulls.nbytes
-        return total
+        return self.live.nbytes + sum(c.nbytes for c in self.columns)
 
     # ---- construction -------------------------------------------------------
 
@@ -282,8 +318,9 @@ class DeviceBatch:
 
 jax.tree_util.register_pytree_node(
     DeviceColumn,
-    # carrier_arg is a leaf (0-d runtime payload; a None simply vanishes from
-    # the leaf list), the canonical WidenSpec is static aux (frozen/hashable)
+    # carrier_arg is a leaf (0-d runtime payload, or a pair's per-row low
+    # half; a None simply vanishes from the leaf list), the canonical
+    # WidenSpec is static aux (frozen/hashable)
     # so the compile cache keys on carrier form — wide vs int8-offset vs
     # scaled-decimal columns compile distinct programs, as they must.
     lambda c: ((c.values, c.nulls, c.carrier_arg),
@@ -549,8 +586,15 @@ def to_arrow(batch: DeviceBatch) -> pa.Table:
          [c.nulls for c in batch.columns],
          [c.carrier_arg for c in batch.columns]))
     from igloo_tpu.utils.stats import record_fetch
-    record_fetch((host_live, host_vals, host_nulls))
+    record_fetch((host_live, host_vals, host_nulls,
+                  pair_halves(batch, host_cargs)))
     return arrow_from_host(batch, host_live, host_vals, host_nulls, host_cargs)
+
+
+def pair_halves(batch: DeviceBatch, host_cargs) -> list:
+    """Of a batch's fetched carrier args, the per-row ones (a pair's low
+    half): what a fetch's byte count adds to values and nulls."""
+    return [hc for c, hc in zip(batch.columns, host_cargs) if c.is_pair]
 
 
 def arrow_from_host(batch: DeviceBatch, host_live, host_vals, host_nulls,
@@ -575,7 +619,8 @@ def arrow_from_host(batch: DeviceBatch, host_live, host_vals, host_nulls,
         vals = hv[idx]
         nulls = hn[idx] if hn is not None else None
         if c.carrier is not None:
-            vals = host_widen(c.carrier, vals, hc)
+            vals = host_widen(c.carrier, vals,
+                              hc[idx] if c.is_pair else hc)
         if f.dtype.is_string:
             d = c.dictionary.values if c.dictionary is not None and len(c.dictionary) else np.asarray([], dtype=object)
             if len(d):

@@ -30,6 +30,19 @@ narrowest-first:
   divided scales are skipped, and decimal columns ride as f32-round-trip or
   raw f64 lanes (PERF.md, PR 22).
 - float64 -> float32 round-trip: when ``v == f32(v)`` exactly (NaN-aware).
+- float64 -> f32 pair (PR 37), for float64 lanes no narrower carrier takes:
+  ``hi = f32(v)``, ``lo = f32(v - f64(hi))``, split once on the host and
+  resident as TWO rank-1 f32 lanes (`DeviceColumn.values` the high half,
+  `carrier_arg` the low one); widen = ``hi.astype(f64) + lo.astype(f64)``.
+  No byte is saved (8 B a lane either way). What is saved is a pass: a chip
+  without float64 (the v5e) computes every f64 value as such a pair, so a
+  program that takes an ``f64[N]`` parameter opens with two X64Split
+  custom-calls that read the whole lane and write its halves before anything
+  else runs, on every execution of a column that never changes; over f32
+  parameters the consumer fusion reads the halves directly. It engages only
+  where the device's float64 IS that pair, which the second canary below
+  decides: yes on the v5e, no on XLA:CPU (real float64: the pair would lose
+  five bits), where columns ship as they always did.
 - everything else ships as the lane dtype unchanged.
 
 The reference engine has no analog (it streams Arrow RecordBatches in-process,
@@ -79,12 +92,16 @@ class WidenSpec:
     lane:   target numpy dtype name ('int64', 'float64', ...)
     offset: integer added after the cast (int paths; 0 for float paths)
     scale:  divisor applied after the cast (float paths; 1 = none)
+    pair:   the carrier is the HIGH f32 half of a float64 lane and its low
+            half rides beside it, row for row (`lo_arg`)
     """
     lane: str
     offset: int = 0
     scale: float = 1.0
+    pair: bool = False
 
-    def widen(self, a: jax.Array, scale_arg=None, offset_arg=None) -> jax.Array:
+    def widen(self, a: jax.Array, scale_arg=None, offset_arg=None,
+              lo_arg=None) -> jax.Array:
         """`scale_arg`/`offset_arg`, when given, must be RUNTIME 0-d arrays
         holding self.scale/self.offset. Scale: baking the divisor in as a
         constant lets XLA rewrite the divide into a multiply by the (inexact)
@@ -93,6 +110,10 @@ class WidenSpec:
         so baking it in would compile a fresh widen program per distinct min
         (one per chunk in the chunked executor)."""
         lane = jnp.dtype(self.lane)
+        if self.pair:
+            # on a chip that emulates float64 these two casts ARE the wide
+            # value's halves: no X64Split pass in front of the consumer
+            return a.astype(lane) + lo_arg.astype(lane)
         if self.scale != 1.0:
             s = (scale_arg.astype(lane) if scale_arg is not None
                  else lane.type(self.scale))
@@ -107,7 +128,11 @@ class WidenSpec:
 
     def key(self) -> tuple:
         """Static jit-cache key: everything EXCEPT the data-dependent payload
-        values (offset rides in at runtime; only its presence is static)."""
+        values (offset rides in at runtime; only its presence is static). A
+        pair keys apart from the f32 round-trip carrier, whose lane dtype it
+        shares; every other form keeps the key it had."""
+        if self.pair:
+            return (self.lane, "f32pair")
         return (self.lane, self.scale != 1.0, self.scale, bool(self.offset))
 
 
@@ -253,6 +278,117 @@ def shrink(np_vals: np.ndarray, lane: np.dtype):
     return None
 
 
+# --- f32-pair carrier --------------------------------------------------------
+# one-time on-device canary, in the decimal canary's idiom: None = not yet
+# run. A pair is lossless only where the device's float64 already IS the pair
+# (hi, lo) of f32 — where every f64 parameter is split into exactly these two
+# halves before the first operation reads it. The canary uploads probe values
+# once as float64 and once as host-split pairs, runs both through one emulated
+# operation with a RUNTIME operand (`x * one`: a constant would be folded and
+# the f64 buffer copied out untouched) and compares the results bit for bit.
+# On XLA:CPU (IEEE float64) the 53-bit probes differ and the verdict is no.
+_f32pair_canary_ok: Optional[bool] = None
+
+#: engage the pair only when the lane is long enough for a split pass to
+#: matter (as RLE_MIN_ROWS): under it the chip's two custom-calls move a few
+#: kilobytes, while a second array is one more transfer per upload — and a
+#: served merge fragment uploads its eight-row dependency table every query
+PAIR_MIN_ROWS = 1024
+
+_F32_TINY = np.finfo(np.float32).tiny
+_F32_MAX = np.finfo(np.float32).max
+
+
+def reset_f32pair_canary() -> None:
+    """Test-visible reset hook, as `reset_decimal_canary`."""
+    global _f32pair_canary_ok
+    with _canary_lock:
+        _f32pair_canary_ok = None
+
+
+#: rows a `split_pair` block holds: its five temporaries stay in the host's
+#: cache (the split of a 60 M-row column is then ~0.5 s, not ~4)
+_SPLIT_BLOCK = 1 << 16
+
+
+def _split_block(v: np.ndarray, hi: np.ndarray, lo: np.ndarray) -> bool:
+    np.copyto(hi, v, casting="same_kind")   # round to nearest even
+    rest = hi.astype(np.float64)
+    np.subtract(v, rest, out=rest)          # exact: at most 29 significant bits
+    np.copyto(lo, rest, casting="same_kind")
+    for half in (hi, lo):
+        a = np.abs(half)
+        # NaN and inf fail the first test, a subnormal the second
+        if not a.max() <= _F32_MAX or ((a < _F32_TINY) & (a > 0)).any():
+            return False
+    return not (hi.view(np.uint32) == 0x80000000).any()
+
+
+def split_pair(v: np.ndarray):
+    """float64 -> (hi, lo) float32 halves with ``f64(hi) + f64(lo)`` the
+    value a float64-emulating chip computes on, or None when some value has
+    no such pair: non-finite, past the f32 exponent range, a half that is a
+    subnormal f32 (the chip flushes those to zero), or a negative zero (its
+    halves sum to +0.0)."""
+    hi = np.empty(v.shape, dtype=np.float32)
+    lo = np.empty(v.shape, dtype=np.float32)
+    with np.errstate(over="ignore", invalid="ignore"):
+        for s in range(0, len(v), _SPLIT_BLOCK):
+            e = s + _SPLIT_BLOCK
+            if not _split_block(v[s:e], hi[s:e], lo[s:e]):
+                return None
+    return hi, lo
+
+
+def _f32pair_probes() -> np.ndarray:
+    rng = np.random.default_rng(37)
+    return np.concatenate([
+        # two-digit decimals over TPC-H's ranges (prices, discounts, taxes)
+        np.round(rng.uniform(900.0, 105000.0, 512), 2),
+        np.arange(0, 11) / 100.0,
+        -np.round(rng.uniform(0.0, 1000.0, 64), 2),
+        # 53 significant bits: what a pair (48) cannot hold, so a backend
+        # with a real float64 says no
+        1.0 + np.ldexp(1.0, -np.arange(1, 53)),
+        rng.uniform(-1e6, 1e6, 512),
+        np.asarray([0.0, 1 / 3, -1 / 3, np.pi, -np.e,
+                    # ties and near-ties of the high half's rounding
+                    1.0 + 2.0 ** -24, 1.0 + 2.0 ** -24 - 2.0 ** -52,
+                    1.0 + 3 * 2.0 ** -24, 1.0 + 3 * 2.0 ** -24 - 2.0 ** -50,
+                    # near the f32 exponent limits, both halves still normal
+                    3e38, -3e38, 1.2345678901234567e-30, 1e30]),
+    ])
+
+
+def _f32pair_ok() -> bool:
+    if _f32pair_canary_ok is not None:
+        return _f32pair_canary_ok
+    with _canary_lock:
+        return _f32pair_ok_locked()
+
+
+def _f32pair_ok_locked() -> bool:
+    global _f32pair_canary_ok
+    if _f32pair_canary_ok is None:
+        try:
+            v = _f32pair_probes()
+            hi, lo = split_pair(v)
+            one = jnp.asarray(np.float64(1.0))
+            wide = jax.jit(lambda x, m: x * m)(jnp.asarray(v), one)
+            pair = jax.jit(
+                lambda h, l, m: WidenSpec("float64", pair=True).widen(
+                    h, lo_arg=l) * m)(jnp.asarray(hi), jnp.asarray(lo), one)
+            ok = np.array_equal(np.asarray(wide).view(np.uint64),
+                                np.asarray(pair).view(np.uint64))
+        except Exception:
+            ok = False
+        _f32pair_canary_ok = ok
+        from igloo_tpu.utils import tracing
+        tracing.counter("codec.f32pair_canary_ok" if ok
+                        else "codec.f32pair_canary_fail")
+    return _f32pair_canary_ok
+
+
 def _pad_to(a: np.ndarray, cap: int) -> np.ndarray:
     if len(a) == cap:
         return a
@@ -318,6 +454,8 @@ def upload_columns(plans: list, device=None) -> list:
     preserved. `spec` is the CANONICAL WidenSpec (offset presence only — the
     real offset rides in `carrier_arg`, a 0-d device array, so distinct column
     minima share compiled programs); spec None means the lane shipped wide.
+    For an f32 pair `device_array` is the high half and `carrier_arg` the low
+    one, a `[capacity]` lane like it (8 B a lane between them, counted so).
     The narrow array is what stays in HBM: operators widen in-jit through
     `batch.wide_values` (XLA fuses the cast/divide into the consumer), so HBM
     residency and every downstream byte cost scale with carrier width.
@@ -345,7 +483,22 @@ def upload_columns(plans: list, device=None) -> list:
         shrunk = shrink(arr, np.dtype(lane)) \
             if (enc and lane is not None) else None
         if shrunk is None:
-            out[i] = (put(_pad_to(arr, cap)), None, None)
+            # a float64 lane no narrower carrier took: the two f32 halves the
+            # chip computes on, where the canary says they are the lane
+            f64 = lane is not None and arr.dtype == np.float64
+            pair = split_pair(arr) \
+                if (enc and f64 and arr.size >= PAIR_MIN_ROWS
+                    and _f32pair_ok()) else None
+            if pair is not None:
+                hi, lo = pair
+                out[i] = (put(_pad_to(hi, cap)),
+                          WidenSpec("float64", pair=True),
+                          put(_pad_to(lo, cap)))
+                tracing.counter("codec.f32pair_columns")
+            else:
+                out[i] = (put(_pad_to(arr, cap)), None, None)
+                if f64:
+                    tracing.counter("codec.f64_wide_columns")
             if lane is not None:
                 decoded_bytes += cap * np.dtype(lane).itemsize
                 carrier_bytes += cap * arr.dtype.itemsize
@@ -403,8 +556,13 @@ def host_widen(spec: WidenSpec, vals: np.ndarray, carg=None) -> np.ndarray:
     output boundary (batch.arrow_from_host). Bit-identical to the device
     widen: the offset path is exact integer addition, the scale path replays
     the very IEEE-f64 divide `_shrink_float` verified elementwise, and the
-    cast paths (f32->f64, int8->int64) are exact by construction."""
+    cast paths (f32->f64, int8->int64, an f32 pair's two halves: their sum
+    has at most 48 significant bits) are exact by construction."""
     lane = np.dtype(spec.lane)
+    if spec.pair:
+        # `carg` is the low half, row for row with `vals`: the sum is the
+        # double the chip's own X64Combine hands back for these halves
+        return vals.astype(lane) + carg.astype(lane)
     if spec.scale != 1.0:
         return vals.astype(lane) / lane.type(spec.scale)
     if spec.offset:

@@ -33,7 +33,7 @@ from igloo_tpu.exec.aggregate import (
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
-    host_decode_column, round_capacity, to_arrow, wide_values,
+    host_decode_column, pair_halves, round_capacity, to_arrow, wide_values,
 )
 from igloo_tpu.exec.expr_compile import (
     Compiled, ConstPool, Env, ExprCompiler, _unify_dicts,
@@ -155,14 +155,19 @@ def _note_carrier_ratio(provider, batch: DeviceBatch) -> None:
     narrow = wide = 0
     for f, c in zip(batch.schema, batch.columns):
         wide += c.capacity * np.dtype(f.dtype.device_dtype()).itemsize
-        narrow += c.values.nbytes
+        narrow += c.carrier_nbytes
     record_carrier_ratio(provider, narrow, wide)
     if stats.detail_active():
         # EXPLAIN ANALYZE: which scans ride carriers and how hard — resident
-        # vs would-be-wide bytes, per scan op
+        # vs would-be-wide bytes, per scan op; the float64 columns held as
+        # f32 pairs (as wide as they were: what they save is the chip's
+        # split pass) by name
         stats.annotate(encoded_lanes=sum(1 for c in batch.columns
                                          if c.carrier is not None),
-                       carrier_bytes=narrow, decoded_bytes=wide)
+                       carrier_bytes=narrow, decoded_bytes=wide,
+                       f32pair_columns=[
+                           f.name for f, c in zip(batch.schema, batch.columns)
+                           if c.is_pair])
 
 
 # per-query D2H accounting at the executor's fetch sites
@@ -422,7 +427,8 @@ class Executor:
                      [c.nulls for c in spec.columns],
                      [c.carrier_arg for c in spec.columns]))
         with tracing.span("fused.result"):
-            record_fetch((host_live, host_vals, host_nulls))
+            record_fetch((host_live, host_vals, host_nulls,
+                          pair_halves(spec, host_cargs)))
             stats.set_rows(int(n))
             for sid, v in stats_h.items():
                 self._cache[("nhint", comp.stat_keys[sid])] = int(v)
@@ -474,7 +480,8 @@ class Executor:
                      [c.values for c in batch.columns],
                      [c.nulls for c in batch.columns],
                      [c.carrier_arg for c in batch.columns]))
-            record_fetch((host_live, host_vals, host_nulls))
+            record_fetch((host_live, host_vals, host_nulls,
+                          pair_halves(batch, host_cargs)))
             self._record_stats(stat_pairs, svals)
             fired = self._fired_deferred(deferred, flags)
             if fired:
@@ -496,7 +503,8 @@ class Executor:
                  [c.values for c in spec.columns],
                  [c.nulls for c in spec.columns],
                  [c.carrier_arg for c in spec.columns]))
-        record_fetch((host_live, host_vals, host_nulls))
+        record_fetch((host_live, host_vals, host_nulls,
+                      pair_halves(spec, host_cargs)))
         self._record_stats(stat_pairs, svals)
         fired = self._fired_deferred(deferred, flags)
         if fired:
@@ -658,10 +666,8 @@ class Executor:
                        for f in missing]
             new_cols = device_columns(decoded, missing, cap)
             for f, col in zip(missing, new_cols):
-                nbytes = col.values.nbytes + (
-                    col.nulls.nbytes if col.nulls is not None else 0)
                 self._batch_cache.put_entry(base + ("col", f.name),
-                                            (col, n, under), snap, nbytes,
+                                            (col, n, under), snap, col.nbytes,
                                             plan.table)
                 cached[f.name] = (col, n, under)
             if live is None:

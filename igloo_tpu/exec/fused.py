@@ -469,11 +469,8 @@ class FusedCompiler:
                 ctx.flags[ofid] = n > want
                 perm = K.compact_perm(ok)[:want]
                 live = jnp.take(ok, perm)
-                p_cols = [replace(c, values=jnp.take(c.values, perm),
-                                  nulls=jnp.take(c.nulls, perm)
-                                  if c.nulls is not None else None,
-                                  dictionary=None, bounds=None)
-                          for c in pb.columns]
+                p_cols = [replace(c.map_rows(lambda a: jnp.take(a, perm)),
+                                  dictionary=None) for c in pb.columns]
                 nbidx = jnp.clip(jnp.take(bidx, perm), 0, bb.capacity - 1)
                 b_cols = K.gather_batch(bb, nbidx)
                 l_cols, r_cols = (b_cols, p_cols) if swapped \

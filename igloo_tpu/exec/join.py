@@ -450,48 +450,25 @@ def expand_phase(left: DeviceBatch, right: DeviceBatch, p: _Probe,
         parts_live.append(ru_live)
 
     # concatenate parts (static shapes: match_cap + cap_l? + cap_r?)
-    n_cols = len(parts_cols[0])
-    out_cols = []
-    for ci in range(n_cols):
-        vals = jnp.concatenate([pc[ci].values for pc in parts_cols])
-        any_nulls = any(pc[ci].nulls is not None for pc in parts_cols)
-        if any_nulls:
-            nulls = jnp.concatenate([
-                pc[ci].nulls if pc[ci].nulls is not None
-                else jnp.zeros((pc[ci].values.shape[0],), dtype=bool)
-                for pc in parts_cols])
-        else:
-            nulls = None
-        proto = parts_cols[0][ci]
-        # per-column carriers are consistent across parts (every part of a
-        # column gathers — or null-pads in carrier dtype — from the same
-        # source batch), so the concat output keeps the proto's spec/arg
-        out_cols.append(replace(proto, values=vals, nulls=nulls, bounds=None))
+    out_cols = K.concat_columns(parts_cols)
     out_live = jnp.concatenate(parts_live)
     if len(parts_live) > 1:
         # outer joins: compact the concatenated parts into contiguous rows.
         # Inner joins skip this — their single part stays MASK-SCATTERED (see
         # above; anything that later needs compaction, e.g. resize_batch,
         # must compact first) and the argsort here costs a ~2M-lane sort
-        perm = K.compact_perm(out_live)
-        out_cols = [replace(c, values=jnp.take(c.values, perm),
-                            nulls=jnp.take(c.nulls, perm)
-                            if c.nulls is not None else None)
-                    for c in out_cols]
-        out_live = jnp.take(out_live, perm)
+        return K.apply_perm(DeviceBatch(out_schema, out_cols, out_live),
+                            K.compact_perm(out_live))
     return DeviceBatch(out_schema, out_cols, out_live)
 
 
 def _null_cols(batch: DeviceBatch, cap: int) -> list[DeviceColumn]:
-    cols = []
-    for c in batch.columns:
-        # zeros in the CARRIER dtype (concat parts must agree); an offset
-        # carrier widens pad zeros to its offset, but every pad lane is null
-        # here — masked at output, bit-identical
-        vals = jnp.zeros((cap,), dtype=c.values.dtype)
-        cols.append(replace(c, values=vals,
-                            nulls=jnp.ones((cap,), dtype=bool), bounds=None))
-    return cols
+    # zeros in the CARRIER dtype (concat parts must agree; wide for an f32
+    # pair, as every part that moved rows is); an offset carrier widens pad
+    # zeros to its offset, but every pad lane is null here — masked at
+    # output, bit-identical
+    return [c.map_rows(lambda a: jnp.zeros((cap,), dtype=a.dtype))
+            .with_nulls(jnp.ones((cap,), dtype=bool)) for c in batch.columns]
 
 
 def choose_match_capacity(total: int) -> int:
@@ -683,24 +660,8 @@ def direct_join_phase(probe: DeviceBatch, build: DeviceBatch,
 
     if len(parts_cols) == 1:
         return DeviceBatch(out_schema, parts_cols[0], parts_live[0]), dup
-    out_cols = []
-    for ci in range(len(parts_cols[0])):
-        vals = jnp.concatenate([pc[ci].values for pc in parts_cols])
-        any_nulls = any(pc[ci].nulls is not None for pc in parts_cols)
-        if any_nulls:
-            nulls = jnp.concatenate([
-                pc[ci].nulls if pc[ci].nulls is not None
-                else jnp.zeros((pc[ci].values.shape[0],), dtype=bool)
-                for pc in parts_cols])
-        else:
-            nulls = None
-        proto = parts_cols[0][ci]
-        # per-column carriers are consistent across parts (every part of a
-        # column gathers — or null-pads in carrier dtype — from the same
-        # source batch), so the concat output keeps the proto's spec/arg
-        out_cols.append(replace(proto, values=vals, nulls=nulls, bounds=None))
     out_live = jnp.concatenate(parts_live)
-    return DeviceBatch(out_schema, out_cols, out_live), dup
+    return DeviceBatch(out_schema, K.concat_columns(parts_cols), out_live), dup
 
 
 def verify_extra_keys(ok: jax.Array, probe: DeviceBatch, build: DeviceBatch,

@@ -25,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from igloo_tpu import types as T
-from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
+from igloo_tpu.exec.batch import (
+    DeviceBatch, DeviceColumn, DictInfo, materialize,
+)
 
 # splitmix64 constants (public-domain finalizer)
 _C1 = np.int64(np.uint64(0xBF58476D1CE4E5B9).astype(np.int64))
@@ -445,11 +447,9 @@ def compact_perm(live: jax.Array) -> jax.Array:
 
 
 def _take_column(c: DeviceColumn, idx: jax.Array) -> DeviceColumn:
-    # replace() keeps the carrier spec/arg: a row gather permutes carrier
-    # lanes as happily as wide ones (bounds dropped)
-    return replace(c, values=jnp.take(c.values, idx),
-                   nulls=jnp.take(c.nulls, idx) if c.nulls is not None
-                   else None, bounds=None)
+    # the carrier spec/arg ride along: a row gather permutes carrier lanes
+    # as happily as wide ones (bounds dropped; an f32 pair leaves wide)
+    return c.map_rows(lambda a: jnp.take(a, idx))
 
 
 def apply_perm(batch: DeviceBatch, perm: jax.Array) -> DeviceBatch:
@@ -473,6 +473,29 @@ def gather_batch(batch: DeviceBatch, idx: jax.Array,
     return cols
 
 
+def concat_columns(parts_cols: list) -> list[DeviceColumn]:
+    """Concatenate, column by column, the parts of a join's output (static
+    shapes). Per-column carriers are consistent across parts — every part of
+    a column gathers, or null-pads in carrier dtype, from the same source
+    batch — so the output keeps the first part's spec/arg; but for an f32
+    pair, which a part that moved rows no longer is (`map_rows`): those
+    columns concatenate wide. A part without a null lane contributes
+    all-False where another part has one."""
+    out = []
+    for parts in zip(*parts_cols):
+        if any(p.is_pair for p in parts):
+            parts = [materialize(p) for p in parts]
+        nulls = None
+        if any(p.nulls is not None for p in parts):
+            nulls = jnp.concatenate([
+                p.nulls if p.nulls is not None
+                else jnp.zeros((p.capacity,), dtype=bool) for p in parts])
+        out.append(replace(
+            parts[0], values=jnp.concatenate([p.values for p in parts]),
+            nulls=nulls, bounds=None))
+    return out
+
+
 def compact_to(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     """Compact live rows to the front AND resize to `capacity` in one step,
     slicing the permutation BEFORE the column gathers so every gather is
@@ -484,11 +507,7 @@ def compact_to(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     perm = compact_perm(batch.live)
     if capacity < perm.shape[0]:
         perm = perm[:capacity]
-    cols = []
-    for c in batch.columns:
-        vals = jnp.take(c.values, perm)
-        nulls = jnp.take(c.nulls, perm) if c.nulls is not None else None
-        cols.append(replace(c, values=vals, nulls=nulls, bounds=None))
+    cols = [_take_column(c, perm) for c in batch.columns]
     live = jnp.take(batch.live, perm)
     if capacity > perm.shape[0]:
         return resize_batch(DeviceBatch(batch.schema, cols, live), capacity)
@@ -511,11 +530,9 @@ def resize_batch(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     shrinking."""
     if capacity == batch.capacity:
         return batch
-    cols = []
-    for c in batch.columns:
-        vals = resize_to(c.values, capacity)
-        nulls = resize_to(c.nulls, capacity, fill=False) if c.nulls is not None else None
-        # carrier survives a resize: the zero pad is dead lanes (masked), and
-        # a zero carrier widening to the offset is still a masked lane
-        cols.append(replace(c, values=vals, nulls=nulls, bounds=None))
+    # carrier survives a resize: the zero pad is dead lanes (masked), and
+    # a zero carrier widening to the offset is still a masked lane (a zero
+    # fill is False in a null lane)
+    cols = [c.map_rows(lambda a: resize_to(a, capacity))
+            for c in batch.columns]
     return DeviceBatch(batch.schema, cols, resize_to(batch.live, capacity, fill=False))

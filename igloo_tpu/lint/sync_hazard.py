@@ -61,11 +61,11 @@ HOT_PREFIXES = ("igloo_tpu/exec/", "igloo_tpu/parallel/")
 # engine's DOCUMENTED sync choke points: each either is the single
 # result-fetch round trip a query must pay, or trades one scalar readback
 # for a compile/shape decision that cannot be made on device. The
-# interprocedural migration shrank this list from 14 to 9: functions whose
-# only sync was the ``num_live()`` count primitive (`Executor._exec`,
-# `_adaptive_input`, `_maybe_shrink`, `ShardedExecutor._observed_live`)
-# are now covered by sanctioned routing through the `DeviceBatch.num_live`
-# entry itself.
+# interprocedural migration shrank this list from 14 to 9 (PR 37 added the
+# pair canary): functions whose only sync was the ``num_live()`` count
+# primitive (`Executor._exec`, `_adaptive_input`, `_maybe_shrink`,
+# `ShardedExecutor._observed_live`) are now covered by sanctioned routing
+# through the `DeviceBatch.num_live` entry itself.
 CHOKE_POINTS = {
     ("igloo_tpu/exec/batch.py", "DeviceBatch.num_live"):
         "THE count-sync primitive: one int readback; every call site "
@@ -96,6 +96,11 @@ CHOKE_POINTS = {
         "on device before trusting it (round-5 advisor item; the locked "
         "slow path of _scaled_decimal_ok — the lock-free fast read never "
         "syncs).",
+    ("igloo_tpu/exec/codec.py", "_f32pair_ok_locked"):
+        "one-time per-process canary: is the device's float64 the pair of "
+        "f32 halves the host splits it into (PR 37; the locked slow path "
+        "of _f32pair_ok, asked only by an upload that has a float64 lane "
+        "no narrower carrier took — the lock-free fast read never syncs).",
 }
 
 _SOURCE_PREFIXES = ("jnp.", "jax.lax.", "jax.nn.", "jax.numpy.")

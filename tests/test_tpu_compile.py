@@ -3,7 +3,8 @@
 The TPU compiler is installed with jaxlib and compiles for a chip that is
 described, not attached (`jax.experimental.topologies`). These tests compile
 two plain XLA stages of the main path at the shapes TPC-H SF1 runs them at,
-and q1's small-domain aggregate, for one chip of a `v5e:2x2` host. A kernel written for Mosaic (ROADMAP A8)
+and q1's small-domain aggregate (also over f32-pair leaves, with a q6-shaped
+masked sum: ISSUE 37), for one chip of a `v5e:2x2` host. A kernel written for Mosaic (ROADMAP A8)
 is held to the compiler here, the same way: lower it with `one_chip`
 shardings and look for `tpu_custom_call` in `compiled.as_text()`. The six
 hand-written kernels this file used to hold to the compiler were all refused
@@ -92,13 +93,17 @@ def test_gather_stage_compiles(one_chip):
 
 # --- q1's aggregate: the one-pass small-domain reduce (ISSUE 33; ~10-20 s) --
 
-def _q1_shaped_aggregate():
+def _q1_shaped_aggregate(pair: bool = False):
     """`aggregate_batch` as q1's scan fragment calls it, as a function of
     plain lanes: two dictionary keys without null lanes (3 x 2 values), four
     float64 columns, the eight aggregates of q1, each argument compiled from
     its bound expression as the compilers do (five distinct ones: the AVGs
-    repeat three of the SUMs'), `seg_dims` ((4, 0), (3, 0))."""
+    repeat three of the SUMs'), `seg_dims` ((4, 0), (3, 0)). With `pair` the
+    three columns that ride as raw float64 on the chip (price, disc, tax;
+    qty is an int8 carrier there) are f32-pair carriers instead (ISSUE 37):
+    `fn` then takes their low halves after `live`."""
     from igloo_tpu import types as T
+    from igloo_tpu.exec.codec import WidenSpec
     from igloo_tpu.exec.aggregate import AggSpec, aggregate_batch
     from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
     from igloo_tpu.exec.expr_compile import ExprCompiler
@@ -144,6 +149,11 @@ def _q1_shaped_aggregate():
         lanes = [flag_ids, status_ids, qty, price, disc, tax]
         cols = [DeviceColumn(d, v, None, dic)
                 for d, v, dic in zip(dtypes, lanes, dicts)]
+        if pair:
+            lows, consts = consts[:3], consts[3:]
+            cols[3:] = [DeviceColumn(T.FLOAT64, c.values, None, None, None,
+                                     WidenSpec("float64", pair=True), lo)
+                        for c, lo in zip(cols[3:], lows)]
         out = aggregate_batch(DeviceBatch(in_schema, cols, live), groups,
                               specs, out_schema, consts,
                               seg_dims=((4, 0), (3, 0)))
@@ -186,6 +196,64 @@ def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
     reduces = [n for n, _ in fusions if "reduce" in n]
     assert 1 <= len(reduces) <= Q1_LANES + 2, reduces
     assert c.cost_analysis()["bytes accessed"] < Q1_PARENT_BYTES / 10
+
+
+# --- a resident float64 column as its two f32 halves (ISSUE 37) -------------
+
+def _lane_splits(compiled) -> int:
+    """X64SplitHigh / X64SplitLow custom-calls that write a whole lane (the
+    scalars of the constants pool are split too, on both sides: not
+    counted)."""
+    return len(re.findall(
+        r"= f32\[%d\]\S* custom-call\(.*custom_call_target=\"X64Split" % LANES,
+        compiled.as_text()))
+
+
+def test_q1_aggregate_over_pair_leaves_opens_with_no_split(one_chip):
+    """The chip has no float64: a program splits every `f64[N]` parameter
+    into its f32 halves (two custom-calls, each a pass over the lane) before
+    anything reads it. Over f32-pair leaves q1's aggregate holds no split;
+    over float64 leaves it holds two per column — if a later jax stops
+    splitting, that half fails and the carrier has become dead weight."""
+    fn, consts = _q1_shaped_aggregate()
+    head = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)]
+    wide = _lower_and_compile(
+        fn, head + [((LANES,), jnp.float64)] * 3 + [((LANES,), jnp.bool_)] +
+        consts, one_chip)
+    assert _lane_splits(wide) == 2 * 4  # qty, price, disc, tax
+    fn, consts = _q1_shaped_aggregate(pair=True)
+    pair = _lower_and_compile(
+        fn, head + [((LANES,), jnp.float32)] * 3 + [((LANES,), jnp.bool_)] +
+        [((LANES,), jnp.float32)] * 3 + consts, one_chip)
+    assert _lane_splits(pair) == 2  # qty alone, a float64 leaf in this harness
+
+
+def test_q6_masked_sum_over_pair_leaves_opens_with_no_split(one_chip):
+    """q6's shape: a predicate over one float64 column and a masked sum of
+    the product of two, decoded through `wide_values` as every operator
+    decodes."""
+    from igloo_tpu import types as T
+    from igloo_tpu.exec.batch import DeviceColumn, wide_values
+    from igloo_tpu.exec.codec import WidenSpec
+
+    def q6(live, lo, hi, *lanes):
+        if len(lanes) == 2:
+            cols = [DeviceColumn(T.FLOAT64, v, None) for v in lanes]
+        else:
+            cols = [DeviceColumn(T.FLOAT64, h, None, None, None,
+                                 WidenSpec("float64", pair=True), l)
+                    for h, l in zip(lanes[:2], lanes[2:])]
+        price, disc = (wide_values(c) for c in cols)
+        keep = live & (disc >= lo) & (disc <= hi)
+        return jnp.sum(jnp.where(keep, price * disc, 0.0))
+
+    head = [((LANES,), jnp.bool_), ((), jnp.float64), ((), jnp.float64)]
+    wide = _lower_and_compile(q6, head + [((LANES,), jnp.float64)] * 2,
+                              one_chip)
+    pair = _lower_and_compile(q6, head + [((LANES,), jnp.float32)] * 4,
+                              one_chip)
+    assert _lane_splits(wide) == 2 * 2
+    assert _lane_splits(pair) == 0
 
 
 # --- a literal is an argument: one lowering per shape (ISSUE 34; ~1 s each) -
