@@ -161,11 +161,16 @@ class DistributedClient:
         attempt = 0
         while True:
             try:
-                with tracing.span("client.wait"):
-                    reader = self._client.do_get(
-                        flight.Ticket(ticket.encode()),
-                        _call_options(timeout_s=timeout))
-                    return reader.read_all()
+                with tracing.span("client.wait") as wait:
+                    try:
+                        reader = self._client.do_get(
+                            flight.Ticket(ticket.encode()),
+                            _call_options(timeout_s=timeout))
+                        return reader.read_all()
+                    finally:
+                        # a kind of its own: the coordinator's end of this
+                        # call is `rpc.server_us.do_get`
+                        rpc.count_call("client.do_get", wait.elapsed_s)
             except flight.FlightError as ex:
                 msg = str(ex)
                 if serving.BUSY_MARKER in msg:
@@ -233,9 +238,12 @@ class DistributedClient:
     def _action(self, name: str, payload: Optional[dict] = None) -> dict:
         body = json.dumps(payload).encode() if payload is not None else b""
         try:
-            results = list(self._client.do_action(
-                flight.Action(name, body),
-                _call_options(timeout_s=self._policy.call_timeout_s)))
+            # `client.action.<name>`: a harness's or an operator's admin
+            # calls are told from the calls a query makes
+            with rpc.call(f"client.action.{name}"):
+                results = list(self._client.do_action(
+                    flight.Action(name, body),
+                    _call_options(timeout_s=self._policy.call_timeout_s)))
         except flight.FlightError as ex:
             raise IglooError(_strip_flight(str(ex))) from None
         return json.loads(results[0].body.to_pybytes()) if results else {}

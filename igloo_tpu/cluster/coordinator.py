@@ -393,15 +393,20 @@ class DistributedExecutor:
                     if not ready:
                         raise IglooError(
                             "circular dependency in fragment graph")
-                    futs = {pool.submit(self._dispatch, f, dict(completed),
-                                        metrics, deadline, token): f
-                            for f in ready}
                     dead: set[str] = set()
                     lost_deps: set[str] = set()
                     busy: list = []
                     # this thread blocked on the dispatch pool: the
-                    # fragments' own time is the workers' spans
+                    # fragments' own time is the workers' spans. From the
+                    # submit on: the pool's thread and the worker's run
+                    # Python at once, and the milliseconds this thread then
+                    # waits for the interpreter lock before it reaches
+                    # as_completed are theirs, not `query` self time
                     with tracing.span("coordinator.await_fragments"):
+                        futs = {pool.submit(self._dispatch, f,
+                                            dict(completed), metrics,
+                                            deadline, token): f
+                                for f in ready}
                         for fut in cf.as_completed(futs):
                             f = futs[fut]
                             try:
@@ -570,6 +575,15 @@ class DistributedExecutor:
             if metrics.get("_finalized"):
                 return
             metrics["_finalized"] = True
+        # after the last batch, before the client's stream ends (with
+        # `coordinator.release`: benchmark `release_ms`)
+        with tracing.span("coordinator.finalize"):
+            self._publish_finished(qid, metrics, t_start, sql, error,
+                                   completed)
+
+    def _publish_finished(self, qid: str, metrics: dict, t_start: float,
+                          sql: str, error: Optional[BaseException],
+                          completed: bool) -> None:
         # retire the stitched trace exactly once (the _finalized guard),
         # whatever the outcome — a partial trace of a failed or abandoned
         # query is exactly what the timeline is FOR
@@ -701,6 +715,16 @@ class DistributedExecutor:
     def _dispatch(self, f: QueryFragment, completed: dict[str, str],
                   metrics: dict, deadline: Optional[float] = None,
                   token: Optional[CancelToken] = None) -> None:
+        # the pool thread's own time around the call: request build and
+        # encode, reply decode, FRAGMENT_STATS.parse, the span tree's
+        # stitching, bookkeeping. The `rpc` attempt is its child; `dispatch`
+        # (group wait) is no thread-local span and takes nothing from it
+        with tracing.span("coordinator.dispatch_fragment", frag=f.id):
+            self._dispatch_fragment(f, completed, metrics, deadline, token)
+
+    def _dispatch_fragment(self, f: QueryFragment, completed: dict[str, str],
+                           metrics: dict, deadline: Optional[float],
+                           token: Optional[CancelToken]) -> None:
         if token is not None and token.cancelled:
             raise QueryCancelledError("query cancelled")
         # remember the target BEFORE the call: a timed-out dispatch keeps
@@ -906,16 +930,17 @@ class DistributedExecutor:
         # its store grows a tombstone for the late put
         addrs = set(completed.values()) | \
             {f.worker for f in frags.values()} | set(dispatched)
-        for addr in addrs:
-            try:
-                # short bound, no retries: release is best-effort cleanup and
-                # often targets the very worker that just died
-                flight_action(addr, "release",
-                              protocol.RELEASE.build(ids=ids),
-                              policy=self._policy().with_(retries=0),
-                              timeout_s=10.0)
-            except Exception:
-                pass  # worker gone; nothing to release
+        with tracing.span("coordinator.release", workers=len(addrs)):
+            for addr in addrs:
+                try:
+                    # short bound, no retries: release is best-effort cleanup
+                    # and often targets the very worker that just died
+                    flight_action(addr, "release",
+                                  protocol.RELEASE.build(ids=ids),
+                                  policy=self._policy().with_(retries=0),
+                                  timeout_s=10.0)
+                except Exception:
+                    pass  # worker gone; nothing to release
 
 
 class _WorkerDied(Exception):
@@ -1413,131 +1438,133 @@ class CoordinatorServer(flight.FlightServerBase):
     # --- Flight methods (full surface; reference implements 2 of 9) ---
 
     def do_action(self, context, action):
-        faults.inject(f"coordinator.do_action.{action.type}")
-        body = action.body.to_pybytes() if action.body is not None else b""
-        req = json.loads(body) if body else {}
-        if action.type == "cancel_query":
-            ok = self.executor.cancel(protocol.CANCEL_QUERY.parse(req)["qid"])
-            return [json.dumps({"cancelled": ok}).encode()]
-        if action.type == "active_queries":
-            return [json.dumps(
-                {"queries": self.executor.active_queries()}).encode()]
-        if action.type == "register_worker":
-            info = serde.worker_info_from_json(req)
-            self.membership.register(info["id"], info["addr"],
-                                     devices=info["devices"],
-                                     slots=info["slots"])
-            w = self.membership.by_addr(info["addr"])
-            if w is not None:
-                try:
-                    self._sync_worker_tables(w)
-                except Exception:
-                    pass
-            # propagate the persistent compile-cache setting + entry listing:
-            # the worker adopts the setting when it has none of its own and
-            # pre-warms by pulling entries it is missing (compile_cache_get),
-            # so a fresh worker starts with every program the cluster has
-            # ever compiled (docs/compile_cache.md)
-            import os
-            from igloo_tpu import compile_cache
-            return [json.dumps({"compile_cache": {
-                "setting": os.environ.get("IGLOO_TPU_COMPILE_CACHE", "1"),
-                "entries": compile_cache.entry_names(
-                    min_age_s=compile_cache.TRANSFER_MIN_AGE_S),
-            }}).encode()]
-        if action.type == "compile_cache_get":
-            # raw entry bytes by XLA cache filename (NOT JSON — workers use
-            # rpc.flight_action_raw); empty body = no such entry
-            from igloo_tpu import compile_cache
-            data = compile_cache.read_entry(
-                protocol.COMPILE_CACHE_GET.parse(req)["name"])
-            return [data if data is not None else b""]
-        if action.type == "compile_cache_put":
-            # worker pushing a freshly compiled entry back to the cluster
-            from igloo_tpu import compile_cache
-            put = protocol.COMPILE_CACHE_PUT.parse(req)
-            stored = compile_cache.write_entry(
-                put["name"], compile_cache.decode_entry(put["data"]))
-            return [json.dumps({"stored": stored}).encode()]
-        if action.type == "heartbeat":
-            info = serde.worker_info_from_json(req)
-            # a legacy payload WITHOUT the topology fields must not reset
-            # the recorded devices to the codec's default of 1
-            ok = self.membership.heartbeat(
-                info["id"], info["addr"],
-                devices=info["devices"] if "devices" in req else None,
-                slots=info["slots"])
-            # journal events riding the beat (cluster/events.py; dedup by
-            # eid keeps in-process fleets and heartbeat retries honest)
-            events.ingest(info["events"], worker=info["id"])
-            return [json.dumps({"ok": ok}).encode()]
-        if action.type == "register_table":
-            rt = protocol.REGISTER_TABLE.parse(req)
-            provider = serde.provider_from_spec(rt["spec"])
-            self.register_table(rt["name"], provider)
-            return [b"{}"]
-        if action.type == "cluster_status":
-            return [json.dumps({
-                "workers": [{"id": w.worker_id, "addr": w.addr,
-                             "last_seen": w.last_seen,
-                             "devices": w.devices, "slots": w.slots}
-                            for w in self.membership.live()],
-                "tables": sorted(self.engine.catalog.names()),
-            }).encode()]
-        if action.type == "last_metrics":
-            with self.executor._totals_lock:
-                pub = self.executor.last_metrics
-            return [json.dumps(pub).encode()]
-        if action.type == "trace":
-            # stitched query timeline by trace_id or qid (neither = most
-            # recent); Chrome-trace/Perfetto JSON by default, the raw span
-            # record with {"format": "raw"} (raw bytes — flight_action_raw)
-            tq = protocol.TRACE_REQUEST.parse(req)
-            rec = flight_recorder.get_record(tq["trace_id"], tq["qid"])
-            if rec is None:
-                raise flight.FlightServerError(
-                    f"no such trace: {tq['trace_id'] or tq['qid'] or '<last>'}")
-            if tq["format"] == "raw":
-                return [json.dumps(rec).encode()]
-            return [json.dumps(flight_recorder.to_chrome_trace(rec)).encode()]
-        if action.type == "serving_status":
-            # admission queue / slot / HBM-reservation snapshot
-            return [json.dumps(self.admission.snapshot()).encode()]
-        if action.type == "metrics":
-            # coordinator process registry + worker-aggregated fragment
-            # stats, Prometheus text (raw bytes — rpc.flight_action_raw)
-            live_w = self.membership.live()
-            extra = ["# TYPE igloo_workers_live gauge",
-                     f"igloo_workers_live {len(live_w)}",
-                     "# TYPE igloo_cluster_devices gauge",
-                     f"igloo_cluster_devices {sum(w.devices for w in live_w)}"]
-            extra.extend(self.executor.prometheus_lines())
-            extra.extend(events.prometheus_lines())
-            return [tracing.prometheus_text(extra_lines=extra).encode()]
-        if action.type == "ping":
-            return [json.dumps({"workers": len(self.membership.live())}).encode()]
-        if action.type == "poll_flight_info":
-            # body: JSON {"sql": "..."} (do_action parses all bodies as JSON)
-            info = self.get_flight_info(
-                context, flight.FlightDescriptor.for_command(
-                    protocol.POLL_FLIGHT_INFO.parse(req)["sql"]))
-            return [json.dumps({"progress": 1.0, "complete": True}).encode(),
-                    info.serialize()]
-        if action.type == "metrics_history":
-            return [json.dumps(protocol.METRICS_HISTORY.build(
-                samples=self._aggregate_metrics_history())).encode()]
-        if action.type == "events":
-            er = protocol.EVENTS_REQUEST.parse(req)
-            evs = events.events(min_severity=er["min_severity"] or "info",
-                                limit=er["limit"] if er["limit"] else None)
-            return [json.dumps(
-                protocol.EVENTS_REPLY.build(events=evs)).encode()]
-        if action.type == "slow_queries":
-            return [json.dumps(protocol.SLOW_QUERIES_REPLY.build(
-                slow_queries=watch.slow_queries())).encode()]
-        if action.type == "watch_status":
-            return [json.dumps(self._watch_status()).encode()]
-        raise flight.FlightServerError(f"unknown action {action.type}")
+        with rpc.Served("coordinator.serve", rpc.action_kind(
+                action.type, protocol.COORDINATOR_ACTIONS)):
+            faults.inject(f"coordinator.do_action.{action.type}")
+            body = action.body.to_pybytes() if action.body is not None else b""
+            req = json.loads(body) if body else {}
+            if action.type == "cancel_query":
+                ok = self.executor.cancel(protocol.CANCEL_QUERY.parse(req)["qid"])
+                return [json.dumps({"cancelled": ok}).encode()]
+            if action.type == "active_queries":
+                return [json.dumps(
+                    {"queries": self.executor.active_queries()}).encode()]
+            if action.type == "register_worker":
+                info = serde.worker_info_from_json(req)
+                self.membership.register(info["id"], info["addr"],
+                                         devices=info["devices"],
+                                         slots=info["slots"])
+                w = self.membership.by_addr(info["addr"])
+                if w is not None:
+                    try:
+                        self._sync_worker_tables(w)
+                    except Exception:
+                        pass
+                # propagate the persistent compile-cache setting + entry listing:
+                # the worker adopts the setting when it has none of its own and
+                # pre-warms by pulling entries it is missing (compile_cache_get),
+                # so a fresh worker starts with every program the cluster has
+                # ever compiled (docs/compile_cache.md)
+                import os
+                from igloo_tpu import compile_cache
+                return [json.dumps({"compile_cache": {
+                    "setting": os.environ.get("IGLOO_TPU_COMPILE_CACHE", "1"),
+                    "entries": compile_cache.entry_names(
+                        min_age_s=compile_cache.TRANSFER_MIN_AGE_S),
+                }}).encode()]
+            if action.type == "compile_cache_get":
+                # raw entry bytes by XLA cache filename (NOT JSON — workers use
+                # rpc.flight_action_raw); empty body = no such entry
+                from igloo_tpu import compile_cache
+                data = compile_cache.read_entry(
+                    protocol.COMPILE_CACHE_GET.parse(req)["name"])
+                return [data if data is not None else b""]
+            if action.type == "compile_cache_put":
+                # worker pushing a freshly compiled entry back to the cluster
+                from igloo_tpu import compile_cache
+                put = protocol.COMPILE_CACHE_PUT.parse(req)
+                stored = compile_cache.write_entry(
+                    put["name"], compile_cache.decode_entry(put["data"]))
+                return [json.dumps({"stored": stored}).encode()]
+            if action.type == "heartbeat":
+                info = serde.worker_info_from_json(req)
+                # a legacy payload WITHOUT the topology fields must not reset
+                # the recorded devices to the codec's default of 1
+                ok = self.membership.heartbeat(
+                    info["id"], info["addr"],
+                    devices=info["devices"] if "devices" in req else None,
+                    slots=info["slots"])
+                # journal events riding the beat (cluster/events.py; dedup by
+                # eid keeps in-process fleets and heartbeat retries honest)
+                events.ingest(info["events"], worker=info["id"])
+                return [json.dumps({"ok": ok}).encode()]
+            if action.type == "register_table":
+                rt = protocol.REGISTER_TABLE.parse(req)
+                provider = serde.provider_from_spec(rt["spec"])
+                self.register_table(rt["name"], provider)
+                return [b"{}"]
+            if action.type == "cluster_status":
+                return [json.dumps({
+                    "workers": [{"id": w.worker_id, "addr": w.addr,
+                                 "last_seen": w.last_seen,
+                                 "devices": w.devices, "slots": w.slots}
+                                for w in self.membership.live()],
+                    "tables": sorted(self.engine.catalog.names()),
+                }).encode()]
+            if action.type == "last_metrics":
+                with self.executor._totals_lock:
+                    pub = self.executor.last_metrics
+                return [json.dumps(pub).encode()]
+            if action.type == "trace":
+                # stitched query timeline by trace_id or qid (neither = most
+                # recent); Chrome-trace/Perfetto JSON by default, the raw span
+                # record with {"format": "raw"} (raw bytes — flight_action_raw)
+                tq = protocol.TRACE_REQUEST.parse(req)
+                rec = flight_recorder.get_record(tq["trace_id"], tq["qid"])
+                if rec is None:
+                    raise flight.FlightServerError(
+                        f"no such trace: {tq['trace_id'] or tq['qid'] or '<last>'}")
+                if tq["format"] == "raw":
+                    return [json.dumps(rec).encode()]
+                return [json.dumps(flight_recorder.to_chrome_trace(rec)).encode()]
+            if action.type == "serving_status":
+                # admission queue / slot / HBM-reservation snapshot
+                return [json.dumps(self.admission.snapshot()).encode()]
+            if action.type == "metrics":
+                # coordinator process registry + worker-aggregated fragment
+                # stats, Prometheus text (raw bytes — rpc.flight_action_raw)
+                live_w = self.membership.live()
+                extra = ["# TYPE igloo_workers_live gauge",
+                         f"igloo_workers_live {len(live_w)}",
+                         "# TYPE igloo_cluster_devices gauge",
+                         f"igloo_cluster_devices {sum(w.devices for w in live_w)}"]
+                extra.extend(self.executor.prometheus_lines())
+                extra.extend(events.prometheus_lines())
+                return [tracing.prometheus_text(extra_lines=extra).encode()]
+            if action.type == "ping":
+                return [json.dumps({"workers": len(self.membership.live())}).encode()]
+            if action.type == "poll_flight_info":
+                # body: JSON {"sql": "..."} (do_action parses all bodies as JSON)
+                info = self.get_flight_info(
+                    context, flight.FlightDescriptor.for_command(
+                        protocol.POLL_FLIGHT_INFO.parse(req)["sql"]))
+                return [json.dumps({"progress": 1.0, "complete": True}).encode(),
+                        info.serialize()]
+            if action.type == "metrics_history":
+                return [json.dumps(protocol.METRICS_HISTORY.build(
+                    samples=self._aggregate_metrics_history())).encode()]
+            if action.type == "events":
+                er = protocol.EVENTS_REQUEST.parse(req)
+                evs = events.events(min_severity=er["min_severity"] or "info",
+                                    limit=er["limit"] if er["limit"] else None)
+                return [json.dumps(
+                    protocol.EVENTS_REPLY.build(events=evs)).encode()]
+            if action.type == "slow_queries":
+                return [json.dumps(protocol.SLOW_QUERIES_REPLY.build(
+                    slow_queries=watch.slow_queries())).encode()]
+            if action.type == "watch_status":
+                return [json.dumps(self._watch_status()).encode()]
+            raise flight.FlightServerError(f"unknown action {action.type}")
 
     def _aggregate_metrics_history(self) -> list:
         """The fleet's sampler rings: this process's own plus every live
@@ -1600,18 +1627,30 @@ class CoordinatorServer(flight.FlightServerBase):
         return protocol.action_doc("coordinator")
 
     def get_flight_info(self, context, descriptor):
-        sql = self._descriptor_sql(descriptor)
-        # plan once for the schema — the reference executes the whole query
-        # here and AGAIN in do_get (crates/api/src/lib.rs:81-149)
-        schema = self._result_schema(sql)
-        endpoint = flight.FlightEndpoint(sql.encode(), [self._public_location()])
-        return flight.FlightInfo(schema, descriptor, [endpoint], -1, -1)
+        with rpc.Served("coordinator.serve", "get_flight_info"):
+            sql = self._descriptor_sql(descriptor)
+            # plan once for the schema — the reference executes the whole
+            # query here and AGAIN in do_get (crates/api/src/lib.rs:81-149)
+            schema = self._result_schema(sql)
+            endpoint = flight.FlightEndpoint(sql.encode(),
+                                             [self._public_location()])
+            return flight.FlightInfo(schema, descriptor, [endpoint], -1, -1)
 
     def get_schema(self, context, descriptor):
-        return flight.SchemaResult(self._result_schema(
-            self._descriptor_sql(descriptor)))
+        with rpc.Served("coordinator.serve", "get_schema"):
+            return flight.SchemaResult(self._result_schema(
+                self._descriptor_sql(descriptor)))
 
     def do_get(self, context, ticket):
+        # the server end of the client's call: around the `query` scope the
+        # ticket's parse, the Trace, the publish, the stream response. The
+        # relay after the handler's return has spans of its own (`fetch`,
+        # `coordinator.finalize`, `coordinator.release`); the call's clock
+        # runs on to the relay's end
+        with rpc.Served("coordinator.serve", "do_get") as served:
+            return self._do_get(ticket, served)
+
+    def _do_get(self, ticket, served: "rpc.Served"):
         faults.inject("coordinator.do_get")
         raw = ticket.ticket.decode()
         try:
@@ -1663,17 +1702,22 @@ class CoordinatorServer(flight.FlightServerBase):
             # rpc.flight_stream_response so dictionary-bearing result schemas
             # get their dictionary batches written without costing plain
             # schemas their Flight error statuses
-            return rpc.flight_stream_response(
-                out[0], faults.wrap_stream("coordinator.do_get", out[1]))
+            return rpc.flight_stream_response(out[0], served.stream(
+                faults.wrap_stream("coordinator.do_get", out[1]), own=False))
         return flight.RecordBatchStream(out)
 
     def do_put(self, context, descriptor, reader, writer):
-        faults.inject("coordinator.do_put")
-        name = self._descriptor_table(descriptor)
-        table = reader.read_all()
-        self.register_table(name, table)
+        with rpc.Served("coordinator.serve", "do_put"):
+            faults.inject("coordinator.do_put")
+            name = self._descriptor_table(descriptor)
+            table = reader.read_all()
+            self.register_table(name, table)
 
     def do_exchange(self, context, descriptor, reader, writer):
+        with rpc.Served("coordinator.serve", "do_exchange"):
+            self._do_exchange(descriptor, reader, writer)
+
+    def _do_exchange(self, descriptor, reader, writer):
         """Bidirectional exchange (reference proto flight.proto:127):
 
         - cmd descriptor: the command is SQL; any uploaded batches are

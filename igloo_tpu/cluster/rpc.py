@@ -31,6 +31,17 @@ new one — so the failure model above reads as it did when each attempt
 connected, and a first attempt to a peer just spoken to costs a round trip
 and no TCP + HTTP/2 set-up. The auth token rides in `call_options` per call:
 a kept connection carries none.
+
+CLOCKS: both ends of every call are timed, by kind — `do_get`, or
+`action.<name>` for a name of `protocol`'s action tables. The client end is
+`call()`: the `rpc` span of one attempt and, where it closes,
+`rpc.calls.<kind>` and `rpc.client_us.<kind>`. The server end is `Served`,
+opened on a handler's first line: a `*.serve` span over what the handler
+does around its request scope, and `rpc.server_us.<kind>`. Per kind, client
+less server is the wire: gRPC, Flight framing, the hand-off between threads.
+`DistributedClient` counts its own calls as `client.<kind>`, so that an
+operator's admin actions can be told from a query's
+(docs/observability.md#transport).
 """
 from __future__ import annotations
 
@@ -50,7 +61,7 @@ import pyarrow.flight as flight
 
 from igloo_tpu.cluster import faults
 from igloo_tpu.errors import DeadlineExceededError
-from igloo_tpu.utils import flight_recorder, tracing
+from igloo_tpu.utils import tracing
 
 AUTH_TOKEN_ENV = "IGLOO_TPU_AUTH_TOKEN"
 _HEADER = "x-igloo-token"
@@ -388,28 +399,111 @@ class _Lease:
             _close_quietly(self.client)
 
 
+# --- both ends of a call get a clock ------------------------------------------
+
+
+def count_call(what: str, seconds: float) -> None:
+    """One call of kind `what` ended at its CLIENT end after `seconds`."""
+    tracing.counter(f"rpc.calls.{what}")
+    tracing.counter(f"rpc.client_us.{what}", max(round(seconds * 1e6), 0))
+
+
+@contextlib.contextmanager
+def call(what: str, attempt: int = 0):
+    """The client end of one RPC attempt: the `rpc` span (attrs `what`, the
+    retry ordinal — retries and backoff against a flaky peer show on the
+    stitched trace; with no trace to record into the span still has its
+    profiler event and its counters) and, where it closes, the call counted
+    under its kind. A call nested in it (the probe inside a stream's open)
+    is counted under its own kind and left out of this one's time, as a
+    child is left out of a span's self time: the kinds add up."""
+    with tracing.span("rpc", what=what, attempt=attempt) as sp:
+        try:
+            yield sp
+        finally:
+            count_call(what, sp.elapsed_s - sum(
+                c.end - c.start for c in sp.children if c.end))
+
+
+def action_kind(name: str, table: dict) -> str:
+    """`action.<name>` for a name of the serving side's action table: the
+    kinds are a closed set, so a peer's misspelt action makes no counter."""
+    return f"action.{name}" if name in table else "action.unknown"
+
+
+class Served:
+    """The server end of one Flight call: `with Served(span, what):` on the
+    handler's first line. Its body is a span `span` (attr `what`); a request
+    scope opened inside becomes its child (`tracing.note_child`), so the
+    span's self time is what the handler does around the scope — decode,
+    parse, encode, an action without a scope whole. Where the block ends,
+    its duration goes to `rpc.server_us.<what>`, the twin of the caller's
+    `rpc.client_us.<what>`; a handler that hands Flight a batch generator
+    passes it through `stream`, and the clock runs on to the generator's
+    exhaustion or close."""
+
+    __slots__ = ("span", "what", "t0", "t_body", "_cm", "_streams")
+
+    def __init__(self, span: str, what: str):
+        self.span = span
+        self.what = what
+        self._streams = False
+
+    def __enter__(self) -> "Served":
+        self.t0 = time.perf_counter()
+        self._cm = tracing.span(self.span, what=self.what)
+        self._cm.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._cm.__exit__(*exc)
+        self.t_body = time.perf_counter()
+        if not self._streams or exc[0] is not None:
+            self._count(self.t_body)
+        return False
+
+    def _count(self, t1: float) -> None:
+        tracing.counter(f"rpc.server_us.{self.what}",
+                        max(round((t1 - self.t0) * 1e6), 0))
+
+    def stream(self, gen, own: bool):
+        """`gen`, with this call's clock running to its end. `own`: no other
+        span covers the serving of the stream, so the time from the
+        handler's return to the stream's end is `span` self time too,
+        recorded by its bounds (the generator runs on Flight's thread
+        between the handler's return and the call's end; a thread-local span
+        cannot stay open across that). A stream Flight never starts counts
+        nothing."""
+        self._streams = True
+
+        def timed():
+            try:
+                yield from gen
+            finally:
+                t1 = time.perf_counter()
+                self._count(t1)
+                if own:
+                    tracing.close_span(self.span, None, t1 - self.t_body)
+        return timed()
+
+
 def _run_attempts(addr: str, what: str, fn, policy: Optional[RpcPolicy],
                   deadline: Optional[float]):
     """The ONE retry loop: lease a connection per attempt (the first from
     the pool, every retry a new one), run `fn(lease)`, classify-then-retry
     with backoff, never past the caller's deadline. An attempt that raises
     discards its connection; one that returns leaves its lease to `fn`'s
-    caller, who releases it (an action: at once; a stream: when exhausted)."""
+    caller, who releases it (an action: at once; a stream: when exhausted).
+    Every attempt is a `call`: a failed one and its retry are two calls of
+    one kind."""
     policy = policy or default_policy()
     attempt = 0
-    # timeline: inside an active flight-recorder scope each ATTEMPT is a
-    # span (attrs carry the retry ordinal), so retries/backoff against a
-    # flaky peer are visible on the stitched trace; outside a scope the
-    # recorder stays entirely out of the way
-    traced = flight_recorder.current() is not None
     while True:
         check_deadline(deadline, what)
         lease = None
         ok = False
         try:
-            span_cm = tracing.span("rpc", what=what, attempt=attempt) \
-                if traced else contextlib.nullcontext()
-            with span_cm:
+            with call(what, attempt):
                 faults.inject(f"client.{what}")
                 lease = _Lease(addr, fresh=attempt > 0)
                 out = fn(lease)
@@ -496,11 +590,13 @@ def flight_actions_raw(addr: str, actions,
     lease = _Lease(addr)
     try:
         for name, payload in actions:
-            faults.inject(f"client.action.{name}")
-            body = json.dumps(payload).encode() if payload is not None else b""
-            results = list(lease.client.do_action(
-                flight.Action(name, body),
-                call_options(timeout_s=policy.call_timeout_s)))
+            with call(f"action.{name}"):
+                faults.inject(f"client.action.{name}")
+                body = json.dumps(payload).encode() \
+                    if payload is not None else b""
+                results = list(lease.client.do_action(
+                    flight.Action(name, body),
+                    call_options(timeout_s=policy.call_timeout_s)))
             yield results[0].body.to_pybytes() if results else b""
         lease.release()
     finally:
@@ -547,7 +643,11 @@ def flight_stream_batches(addr: str, ticket,
     already yielded cannot be un-consumed). A bounded `ping` probe
     (connect_timeout_s) catches a HUNG peer at open time; without it a
     worker that accepts TCP but never answers would hold do_get for the
-    full stream timeout (on a kept connection the probe is a round trip).
+    full stream timeout (on a kept connection the probe is a round trip; it
+    is a call of its own kind, `action.ping`, nested in the open's `rpc`).
+    The open's `rpc` span ends with the schema, but `rpc.client_us.do_get`
+    runs on to the end of the batch generator, so that it bounds the serving
+    side's `rpc.server_us.do_get` from above.
     The connection returns to the pool only when the generator is EXHAUSTED;
     a stream that raises, is closed early, or is ABANDONED (the weakref
     finalizer: a never-started generator's close() does not run its finally
@@ -558,8 +658,9 @@ def flight_stream_batches(addr: str, ticket,
     def open_stream(lease):
         c = lease.client
         probe_t = _effective_timeout(policy.connect_timeout_s, deadline)
-        list(c.do_action(flight.Action("ping", b""),
-                         call_options(timeout_s=probe_t)))
+        with call("action.ping"):
+            list(c.do_action(flight.Action("ping", b""),
+                             call_options(timeout_s=probe_t)))
         t = _effective_timeout(policy.stream_timeout_s, deadline)
         reader = c.do_get(flight.Ticket(raw), call_options(timeout_s=t))
         # the schema read is where a hung/failed do_get actually surfaces —
@@ -568,6 +669,7 @@ def flight_stream_batches(addr: str, ticket,
 
     lease, reader, schema = _run_attempts(addr, "do_get", open_stream,
                                           policy, deadline)
+    t_open = time.perf_counter()
 
     def gen():
         try:
@@ -577,6 +679,8 @@ def flight_stream_batches(addr: str, ticket,
             lease.release()
         finally:
             lease.discard()
+            tracing.counter("rpc.client_us.do_get",
+                            round((time.perf_counter() - t_open) * 1e6))
     g = gen()
     weakref.finalize(g, lease.discard)
     return schema, g

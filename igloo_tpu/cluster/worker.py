@@ -487,44 +487,49 @@ class WorkerServer(flight.FlightServerBase):
         return out
 
     def do_action(self, context, action):
-        faults.inject(f"worker.do_action.{action.type}")
-        body = action.body.to_pybytes() if action.body is not None else b""
-        req = json.loads(body) if body else {}
-        if action.type == "execute_fragment":
-            try:
-                out = self._handle_execute_fragment(req)
-            except protocol.ProtocolError as ex:
-                raise flight.FlightServerError(f"bad dispatch payload: {ex}")
-            return [json.dumps(out).encode()]
-        if action.type == "register_table":
-            rt = protocol.REGISTER_TABLE.parse(req)
-            provider = serde.provider_from_spec(rt["spec"])
-            self._catalog.register(rt["name"], provider)
-            self._batch_cache.invalidate_table(rt["name"].lower())
-            return [b"{}"]
-        if action.type == "release":
-            ids = protocol.RELEASE.parse(req)["ids"]
-            deps = [k for k in self._store.ids()
-                    if any(k.startswith(_dep_key(fid, None)) for fid in ids)]
-            self._store.release(ids + deps)
-            return [b"{}"]
-        if action.type == "ping":
-            own = [i for i in self._store.ids() if not i.startswith("__dep_")]
-            return [json.dumps({"worker": self.worker_id,
-                                "tables": sorted(self._catalog.names()),
-                                "fragments": len(own),
-                                "slots": self.slots,
-                                "mesh_devices": self.mesh_devices}).encode()]
-        if action.type == "metrics":
-            # Prometheus text exposition of this worker process's registry
-            # (raw bytes, not JSON — scrape via rpc.flight_action_raw)
-            return [tracing.prometheus_text().encode()]
-        if action.type == "metrics_history":
-            # this process's watchtower sampler ring; the coordinator's
-            # metrics_history action aggregates these across the fleet
-            return [json.dumps(protocol.METRICS_HISTORY.build(
-                samples=timeseries.samples())).encode()]
-        raise flight.FlightServerError(f"unknown action {action.type}")
+        # the server end of the call (cluster/rpc.py CLOCKS): what runs
+        # around `execute_fragment`'s scope — body decode, protocol parse,
+        # the span tree's copy, reply encode — and the small actions whole
+        with rpc.Served("worker.serve", rpc.action_kind(
+                action.type, protocol.WORKER_ACTIONS)):
+            faults.inject(f"worker.do_action.{action.type}")
+            body = action.body.to_pybytes() if action.body is not None else b""
+            req = json.loads(body) if body else {}
+            if action.type == "execute_fragment":
+                try:
+                    out = self._handle_execute_fragment(req)
+                except protocol.ProtocolError as ex:
+                    raise flight.FlightServerError(f"bad dispatch payload: {ex}")
+                return [json.dumps(out).encode()]
+            if action.type == "register_table":
+                rt = protocol.REGISTER_TABLE.parse(req)
+                provider = serde.provider_from_spec(rt["spec"])
+                self._catalog.register(rt["name"], provider)
+                self._batch_cache.invalidate_table(rt["name"].lower())
+                return [b"{}"]
+            if action.type == "release":
+                ids = protocol.RELEASE.parse(req)["ids"]
+                deps = [k for k in self._store.ids()
+                        if any(k.startswith(_dep_key(fid, None)) for fid in ids)]
+                self._store.release(ids + deps)
+                return [b"{}"]
+            if action.type == "ping":
+                own = [i for i in self._store.ids() if not i.startswith("__dep_")]
+                return [json.dumps({"worker": self.worker_id,
+                                    "tables": sorted(self._catalog.names()),
+                                    "fragments": len(own),
+                                    "slots": self.slots,
+                                    "mesh_devices": self.mesh_devices}).encode()]
+            if action.type == "metrics":
+                # Prometheus text exposition of this worker process's registry
+                # (raw bytes, not JSON — scrape via rpc.flight_action_raw)
+                return [tracing.prometheus_text().encode()]
+            if action.type == "metrics_history":
+                # this process's watchtower sampler ring; the coordinator's
+                # metrics_history action aggregates these across the fleet
+                return [json.dumps(protocol.METRICS_HISTORY.build(
+                    samples=timeseries.samples())).encode()]
+            raise flight.FlightServerError(f"unknown action {action.type}")
 
     def list_actions(self, context):
         # straight from the registry: the flight-actions checker holds this
@@ -532,29 +537,33 @@ class WorkerServer(flight.FlightServerBase):
         return protocol.action_doc("worker")
 
     def do_get(self, context, ticket):
-        faults.inject("worker.do_get")
-        try:
-            frag_id, bucket, nbuckets = exchange.parse_ticket(ticket.ticket)
-        except protocol.ProtocolError as ex:
-            raise flight.FlightServerError(f"bad exchange ticket: {ex}")
-        try:
-            schema, batches = self._store.stream(frag_id, bucket, nbuckets)
-        except KeyError:
-            raise flight.FlightServerError(f"no such fragment: {frag_id}")
-        except ValueError as ex:
-            raise flight.FlightServerError(f"bad bucket request: {ex}")
+        with rpc.Served("worker.serve", "do_get") as served:
+            faults.inject("worker.do_get")
+            try:
+                frag_id, bucket, nbuckets = exchange.parse_ticket(
+                    ticket.ticket)
+            except protocol.ProtocolError as ex:
+                raise flight.FlightServerError(f"bad exchange ticket: {ex}")
+            try:
+                schema, batches = self._store.stream(frag_id, bucket,
+                                                     nbuckets)
+            except KeyError:
+                raise flight.FlightServerError(f"no such fragment: {frag_id}")
+            except ValueError as ex:
+                raise flight.FlightServerError(f"bad bucket request: {ex}")
 
-        def counted():
-            for b in batches:
-                tracing.counter("exchange.rows", b.num_rows)
-                tracing.counter("exchange.bytes", b.nbytes)
-                yield b
-        # encoded partition slices carry dictionary fields, which
-        # GeneratorStream would silently drop — rpc.flight_stream_response
-        # picks the stream shape that keeps both dictionaries and Flight
-        # error statuses intact
-        return rpc.flight_stream_response(
-            schema, faults.wrap_stream("worker.do_get", counted()))
+            def counted():
+                for b in batches:
+                    tracing.counter("exchange.rows", b.num_rows)
+                    tracing.counter("exchange.bytes", b.nbytes)
+                    yield b
+            # encoded partition slices carry dictionary fields, which
+            # GeneratorStream would silently drop —
+            # rpc.flight_stream_response picks the stream shape that keeps
+            # both dictionaries and Flight error statuses intact. No other
+            # span covers the serving of a stored result: it is this one's
+            return rpc.flight_stream_response(schema, served.stream(
+                faults.wrap_stream("worker.do_get", counted()), own=True))
 
 
 class Worker:

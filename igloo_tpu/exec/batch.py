@@ -517,7 +517,7 @@ def host_decode_column(arr: pa.ChunkedArray, f: Field,
 
 
 def device_columns(decoded: list, fields: list, cap: int,
-                   device=None) -> list[DeviceColumn]:
+                   device=None, clock=None) -> list[DeviceColumn]:
     """Upload host-decoded columns as DeviceColumns, narrowed losslessly
     (exec/codec.py) — and kept narrow: the carrier array IS the resident
     `values` lane, with the WidenSpec riding along so operators widen at the
@@ -530,7 +530,7 @@ def device_columns(decoded: list, fields: list, cap: int,
         plans.append((np_vals, lane, cap))
         if null_mask is not None:
             plans.append((null_mask, None, cap))
-    dev = upload_columns(plans, device=device)
+    dev = upload_columns(plans, device=device, clock=clock)
     cols: list[DeviceColumn] = []
     i = 0
     for f, (np_vals, null_mask, dinfo, bounds) in zip(fields, decoded):
@@ -552,13 +552,15 @@ def from_arrow(
     dictionaries: Optional[dict[str, DictInfo]] = None,
     device=None,
     null_fields: Optional[set] = None,
+    clock=None,
 ) -> DeviceBatch:
     """pyarrow Table -> DeviceBatch (host decode -> narrowed device_put into
     HBM -> on-device widen, one dispatch for the whole batch). Columns named
     in `null_fields` always get a null lane (all-False when the data has no
     nulls): the GRACE partition pipeline forces one shape per leaf across all
     partitions so null-free buckets key the same compiled programs as bucket
-    siblings that do carry nulls."""
+    siblings that do carry nulls. `clock` is handed to
+    `codec.upload_columns`."""
     from igloo_tpu.exec.codec import live_lane
     if schema is None:
         schema = schema_from_arrow(table.schema)
@@ -570,7 +572,8 @@ def from_arrow(
         decoded = [(v, np.zeros(n, dtype=bool)
                     if nm is None and f.name in null_fields else nm, di, b)
                    for f, (v, nm, di, b) in zip(schema, decoded)]
-    cols = device_columns(decoded, list(schema), cap, device=device)
+    cols = device_columns(decoded, list(schema), cap, device=device,
+                          clock=clock)
     return DeviceBatch(schema, cols, live_lane(cap, n, device=device))
 
 

@@ -54,6 +54,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -441,8 +442,11 @@ def _rle_expand_jit(runs_cap: int, cap: int, dtype_name: str):
     return jax.jit(fn)
 
 
-def upload_columns(plans: list, device=None) -> list:
+def upload_columns(plans: list, device=None, clock=None) -> list:
     """Upload a batch of columns, keeping carriers RESIDENT on device.
+    `clock`, where given, gets the seconds spent handing arrays to the
+    device added to its `put_s` (a scan's miss path tells its H2D from its
+    codec by it: exec/executor.py `_ScanLoadClock`).
 
     `plans` is a list of (np_array, lane_dtype | None, capacity); lane None
     means the array ships as-is after padding (bool masks). Narrowing is
@@ -471,7 +475,12 @@ def upload_columns(plans: list, device=None) -> list:
     def put(a):
         nonlocal h2d
         h2d += getattr(a, "nbytes", 0)
-        return raw_put(a)
+        if clock is None:
+            return raw_put(a)
+        t0 = time.perf_counter()
+        out = raw_put(a)
+        clock.put_s += time.perf_counter() - t0
+        return out
 
     from igloo_tpu.utils import tracing
     enc = encoded_enabled()

@@ -17,6 +17,7 @@ reuse executables across QueryEngine.execute calls.
 """
 from __future__ import annotations
 
+import time
 from typing import Callable, Optional
 
 import jax
@@ -61,6 +62,49 @@ import os as _os  # noqa: E402
 _LOG_COMPILES = _os.environ.get("IGLOO_TPU_LOG_COMPILES", "") == "1"
 
 _SENTINEL = object()  # "use the plan's projection" marker for read_scan_table
+
+
+class _ScanLoadClock:
+    """The three parts of a scan's miss path, timed from inside
+    `program.scan_load` and added to counters where the load ends — no child
+    spans: the span's self time keeps its meaning. `scan_load.read_us`: the
+    provider's read and the Arrow decode, to `read_done`; `scan_load.h2d_us`:
+    handing the arrays to the device (`put_s`, which `codec.upload_columns`
+    adds to) and, for a load that enters a cache (`wait`), waiting until
+    they are there — a `device_put` returns in 0.4 ms whatever its size and
+    the copy ends later (43 ms for 256 MB, 0.37 ms for eight rows: chip
+    probe, PR 38), so without the wait the span closes with the copy in
+    flight and the next program's first wait pays for it. An `ephemeral`
+    provider's load (a fragment's dependency, every query) does not wait:
+    its one consumer is dispatched next and waits for the device anyway,
+    and the copy's tail overlaps that dispatch's host work. `scan_load
+    .codec_us`: from the read's end to the last array handed over, less
+    `put_s` — host decode, the codec's proofs and narrowing, the f32 pair's
+    split, padding. `scan_load.columns` counts the columns loaded. What is
+    left of the span (cache bookkeeping, the live lane) is in none of the
+    three."""
+
+    __slots__ = ("t0", "t_read", "put_s")
+
+    def __init__(self):
+        self.t0 = self.t_read = time.perf_counter()
+        self.put_s = 0.0
+
+    def read_done(self) -> None:
+        self.t_read = time.perf_counter()
+
+    def done(self, arrays, columns: int, wait: bool = True) -> None:
+        t1 = t_put = time.perf_counter()
+        if wait:
+            jax.block_until_ready(arrays)
+            t1 = time.perf_counter()
+        tracing.counter("scan_load.read_us",
+                        round((self.t_read - self.t0) * 1e6))
+        tracing.counter("scan_load.codec_us", max(round(
+            (t_put - self.t_read - self.put_s) * 1e6), 0))
+        tracing.counter("scan_load.h2d_us",
+                        round((self.put_s + t1 - t_put) * 1e6))
+        tracing.counter("scan_load.columns", columns)
 
 
 def read_scan_table(plan: L.Scan, projection=_SENTINEL) -> pa.Table:
@@ -598,10 +642,13 @@ class Executor:
                 if hit is not None:
                     return hit
             with tracing.span("program.scan_load", table=plan.table):
+                clock = _ScanLoadClock()
                 table = read_scan_table(plan)
                 if plan.projection is not None:
                     table = table.select(plan.projection)
-                batch = from_arrow(table, schema=plan.schema)
+                clock.read_done()
+                batch = from_arrow(table, schema=plan.schema, clock=clock)
+                clock.done(batch, len(plan.schema), wait=not once)
             _note_carrier_ratio(plan.provider, batch)
             if key is not None:
                 self._batch_cache.put(key, batch, snap)
@@ -651,7 +698,9 @@ class Executor:
         # resident table never opens it
         with tracing.span("program.scan_load", table=plan.table,
                           columns=len(proj)):
+            clock = _ScanLoadClock()
             table = read_scan_table(plan, projection=proj).select(proj)
+            clock.read_done()
             n = table.num_rows
             if (known_n is not None and n != known_n) or \
                     (live_n is not None and n != live_n):
@@ -664,7 +713,8 @@ class Executor:
                           if v is not None))
             decoded = [host_decode_column(table.column(f.name), f)
                        for f in missing]
-            new_cols = device_columns(decoded, missing, cap)
+            new_cols = device_columns(decoded, missing, cap, clock=clock)
+            clock.done(new_cols, len(missing))
             for f, col in zip(missing, new_cols):
                 self._batch_cache.put_entry(base + ("col", f.name),
                                             (col, n, under), snap, col.nbytes,

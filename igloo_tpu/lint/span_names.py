@@ -3,7 +3,8 @@
 Sibling of the ``metric-names`` rule for the distributed-tracing layer
 (utils/flight_recorder.py): every span name used in code — a
 ``tracing.span(...)`` / ``<trace>.span(...)`` first argument, a
-``<trace>.add_span(...)`` first argument, or a
+``<trace>.add_span(...)`` first argument, a Flight handler's
+``rpc.Served(...)`` first argument, or a
 ``flight_recorder.request_scope(...)`` name (second argument) — must be
 covered by the "Span catalog" table in docs/observability.md. Timeline names
 drive Perfetto grouping and the trace tests exactly the way metric names
@@ -32,7 +33,7 @@ RULE = "span-names"
 # lowercase words, dots, underscores and '+' ("bind+optimize")
 _NAME = r"([a-z][a-z0-9_+.{}-]*)"
 SPAN_CALL_RE = re.compile(
-    r"(?<![\w.])(?:[\w.]+\.)?(?:span|add_span)\(\s*(f?)[\"']"
+    r"(?<![\w.])(?:[\w.]+\.)?(?:span|add_span|Served)\(\s*(f?)[\"']"
     + _NAME + r"[\"']")
 SCOPE_CALL_RE = re.compile(
     r"(?<![\w.])(?:[\w.]+\.)?request_scope\(\s*[^,()]*,\s*(f?)[\"']"

@@ -62,7 +62,7 @@ HOT_PREFIXES = ("igloo_tpu/exec/", "igloo_tpu/parallel/")
 # result-fetch round trip a query must pay, or trades one scalar readback
 # for a compile/shape decision that cannot be made on device. The
 # interprocedural migration shrank this list from 14 to 9 (PR 37 added the
-# pair canary): functions whose only sync was the ``num_live()`` count
+# pair canary, PR 38 the scan load's wait): functions whose only sync was the ``num_live()`` count
 # primitive (`Executor._exec`, `_adaptive_input`, `_maybe_shrink`,
 # `ShardedExecutor._observed_live`) are now covered by sanctioned routing
 # through the `DeviceBatch.num_live` entry itself.
@@ -101,6 +101,12 @@ CHOKE_POINTS = {
         "f32 halves the host splits it into (PR 37; the locked slow path "
         "of _f32pair_ok, asked only by an upload that has a float64 lane "
         "no narrower carrier took — the lock-free fast read never syncs).",
+    ("igloo_tpu/exec/executor.py", "_ScanLoadClock.done"):
+        "a scan's MISS path only, and only a load that enters a cache (a "
+        "resident table never runs it, a fragment's dependency table does "
+        "not wait): waits once, inside `program.scan_load`, for the columns "
+        "just handed to the device, so that `scan_load.h2d_us` and the span "
+        "hold the copy and the next program's first wait does not (PR 38).",
 }
 
 _SOURCE_PREFIXES = ("jnp.", "jax.lax.", "jax.nn.", "jax.numpy.")

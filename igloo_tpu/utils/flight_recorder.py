@@ -23,8 +23,9 @@ Consumption paths (docs/observability.md#distributed-tracing):
 Knobs: ``IGLOO_TRACE=0`` kills the recorder (spans still exist thread-local,
 nothing is stitched or retained); ``IGLOO_TRACE_RING`` sizes the ring
 (default 32 traces). Neither touches the profiler bridge or the
-``span_us.*`` self-time counters: every span made here leaves through
-``tracing.close_span`` like a thread-local one. Overhead with the recorder
+``span_us.*`` self-time counters, nor the ``rpc`` span and the ``rpc.*``
+counters of a call's two ends (cluster/rpc.py): every span made here leaves
+through ``tracing.close_span`` like a thread-local one. Overhead with the recorder
 ON is a few tens of microseconds per query (id generation + one flatten + a
 ring append) plus ~5 us per span — scripts/trace_smoke.py holds the whole to
 <2% of a 5 ms query on the CPU; PERF.md §6 has what it costs on the chip.
@@ -228,7 +229,9 @@ class _RequestScope:
     `trace=None` still resets the thread-local state — the hygiene applies
     whether or not anything is recorded — and the root still has its
     profiler event and its `span_us.<name>` (self time: the scope's duration
-    minus the span roots opened inside it). Class-based: this sits on the
+    minus the span roots opened inside it). A span open on the thread
+    around the scope takes the root as its child (`tracing.note_child`), so
+    the scope is in nobody's self time twice. Class-based: this sits on the
     per-query hot path."""
 
     __slots__ = ("trace", "name", "proc", "parent_id", "keep_roots",
@@ -265,6 +268,9 @@ class _RequestScope:
         _tls.trace, _tls.root_id, _tls.proc = self._prev
         _tls.scopes -= 1
         tracing.close_span(self.name, self._annotation, p1 - self._p0, roots)
+        # the span around the scope (a Flight handler's) leaves the scope
+        # out of its self time
+        tracing.note_child(self.name, self._p0, p1, self._root_id or "")
         trace = self.trace
         if trace is not None:
             tid = _tid()

@@ -438,6 +438,20 @@ def current_span_id() -> Optional[str]:
     return stack[-1].span_id if stack else None
 
 
+def note_child(name: str, start: float, end: float,
+               span_id: str = "") -> None:
+    """Tell the span open on this thread, if there is one, that
+    [`start`, `end`] (`perf_counter` instants) was a span kept somewhere
+    else: a request scope swaps the thread's stack, so its root never
+    becomes a child of the span around the scope (a Flight handler's
+    `*.serve`) by itself, and that span's self time would hold the whole
+    query a second time."""
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        stack[-1].children.append(Span(name, start, end, span_id=span_id,
+                                       parent_id=stack[-1].span_id))
+
+
 class _SpanCtx:
     """Class-based span context (a @contextmanager generator costs ~2x as
     much, and spans sit on per-operator and per-RPC paths)."""
