@@ -23,10 +23,12 @@ import numpy as np
 
 from igloo_tpu import types as T
 from igloo_tpu.exec import kernels as K
-from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn, DictInfo
+from igloo_tpu.exec.batch import (
+    DeviceBatch, DeviceColumn, DictInfo, f32_halves,
+)
 from igloo_tpu.exec.expr_compile import Compiled, Env
 from igloo_tpu.plan import logical as L
-from igloo_tpu.plan.expr import AggFunc, fingerprint
+from igloo_tpu.plan.expr import AggFunc, Column, fingerprint
 from igloo_tpu.utils import tracing
 
 
@@ -109,11 +111,37 @@ def seg_dims_for(groups: list[Compiled],
     return tuple(dims)
 
 
+def pair_sums_for(seg_dims: Optional[tuple], aggs: list[AggSpec]) -> bool:
+    """Whether `_direct_aggregate` folds its float64 SUM/AVG lanes as f32
+    pairs (kernels.pair_add): where the one-pass arm reduces them
+    (SMALL_NSEG segments or fewer) and the device's float64 IS that pair —
+    the verdict of the codec's one-time canary, asked here, while a program
+    is planned, and only by a plan that has such a lane. Host-side decision:
+    callers fold the result into their jit cache key, as `seg_dims`."""
+    if seg_dims is None or _segment_space(seg_dims)[1] > K.SMALL_NSEG or \
+            not any(a.func in (AggFunc.SUM, AggFunc.AVG) and
+                    _acc_dtype(a) is jnp.float64 for a in aggs):
+        return False
+    from igloo_tpu.exec import codec
+    return codec._f32pair_ok()
+
+
+def _segment_space(seg_dims: tuple) -> tuple:
+    """(key combinations, output capacity) of a direct aggregate: the
+    capacity holds them and the slot dead rows land in, padded."""
+    from igloo_tpu.exec.batch import round_capacity
+    prod = 1
+    for d, _off in seg_dims:
+        prod *= d
+    return prod, round_capacity(prod + 1)
+
+
 def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
                     aggs: list[AggSpec], out_schema: T.Schema,
                     consts: tuple = (),
                     seg_dims: Optional[tuple] = None,
-                    pack_spec: Optional[tuple] = None):
+                    pack_spec: Optional[tuple] = None,
+                    pair_sums: bool = False):
     # seg_dims entries are (bucket_count, value_offset) pairs — see
     # seg_dims_for
     """Pure, jit-traceable: DeviceBatch -> DeviceBatch of one row per group.
@@ -126,7 +154,8 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
     multi-lane lex_argsort chain to a single sort pass — when every key packs
     (all-integer group-bys) the whole chain becomes one argsort, and a
     q18-shaped 5-key group-by with one float key sorts 3 lanes instead of
-    10+."""
+    10+. `pair_sums` (pair_sums_for, part of the caller's cache key too):
+    the direct path's float64 sums fold as f32 pairs."""
     env = Env.from_batch(batch, consts)
     cap = batch.capacity
     live = batch.live
@@ -144,7 +173,7 @@ def aggregate_batch(batch: DeviceBatch, groups: list[Compiled],
 
     if seg_dims is not None and len(seg_dims) == len(groups):
         return _direct_aggregate(env, groups, gvals, gnulls, aggs, out_schema,
-                                 live, seg_dims)
+                                 live, seg_dims, pair_sums)
 
     # sort path. With a pack_spec, the indexed keys fuse into ONE packed lane
     # (NULL is a digit, so no separate null lanes for them). Grouping never
@@ -358,6 +387,15 @@ def _reduce_one(spec: AggSpec, env: Env, perm, seg, s_live, cap,
     return DeviceColumn(spec.out_dtype, out_val, all_null, spec.out_dict)
 
 
+def _resident_halves(spec: AggSpec, env: Env):
+    """The f32 halves of a SUM/AVG argument that is a bare column whose
+    resident form holds them (batch.f32_halves), else None."""
+    e = spec.arg.expr
+    if not isinstance(e, Column) or env.columns is None:
+        return None
+    return f32_halves(env.columns[e.index])
+
+
 def _sum_column(spec: AggSpec, total, n_valid, all_null) -> DeviceColumn:
     """SUM / AVG output from a group's total and its count of non-NULLs."""
     if spec.func is AggFunc.AVG:
@@ -462,7 +500,8 @@ def _feasible_segments(seg_dims: tuple, gnulls: list) -> list:
 def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
                       aggs: list[AggSpec], out_schema: T.Schema,
                       live: jax.Array,
-                      seg_dims: tuple) -> DeviceBatch:  # ((count, offset), ...)
+                      seg_dims: tuple,  # ((count, offset), ...)
+                      pair_sums: bool = False) -> DeviceBatch:
     """Direct grouping for small indexable keys (see seg_dims_for): segment
     id = mixed-radix combination of (NULL?0:key+1) digits. Skips the
     full-capacity lex sort; output capacity = padded segment count (small).
@@ -473,13 +512,14 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
     expression it was compiled from) and accumulator dtype, one best-value
     lane per MIN/MAX; MIN/MAX take a second such round for the winning
     position. At SMALL_NSEG segments or fewer that is one pass over the
-    lanes, into the feasible segments only; above, one scatter per lane."""
-    from igloo_tpu.exec.batch import round_capacity
+    lanes, into the feasible segments only; above, one scatter per lane.
+
+    With `pair_sums` (pair_sums_for) a float64 sum lane is folded as its two
+    f32 halves: those of the column itself where the argument is a bare
+    column resident in a form that holds them (batch.f32_halves: no decode,
+    no split), else split in-trace from the computed float64 value."""
     cap = live.shape[0]
-    prod = 1
-    for d, _off in seg_dims:
-        prod *= d
-    nseg = round_capacity(prod + 1)
+    prod, nseg = _segment_space(seg_dims)
     dead = nseg - 1  # dead rows land here; >= prod, never a real key combo
     seg = jnp.zeros((cap,), dtype=jnp.int32)
     for v, nl, (d, off) in zip(gvals, gnulls, seg_dims):
@@ -528,8 +568,15 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
             acc = _acc_dtype(spec)
             lkey = ("sum", akey, np.dtype(acc).name)
             if lkey not in lanes:
-                lanes[lkey] = (jnp.where(valid, v.astype(acc),
-                                         jnp.zeros((), acc)), "sum")
+                halves = _resident_halves(spec, env) \
+                    if pair_sums and acc is jnp.float64 else None
+                if halves is not None:
+                    zero = jnp.zeros((), jnp.float32)
+                    lanes[lkey] = (tuple(jnp.where(valid, h, zero)
+                                         for h in halves), "sum")
+                else:
+                    lanes[lkey] = (jnp.where(valid, v.astype(acc),
+                                             jnp.zeros((), acc)), "sum")
         elif spec.func in (AggFunc.MIN, AggFunc.MAX):
             op = "min" if spec.func is AggFunc.MIN else "max"
             lkey = (op, akey)
@@ -539,7 +586,7 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
                                          hi if op == "min" else lo), op)
         plans.append((akey, lkey))
     found = dict(zip(lanes, K.seg_reduce(list(lanes.values()), seg, nseg,
-                                         seg_ids)))
+                                         seg_ids, pair_sums)))
 
     # round 2: a row index holding each MIN/MAX's winning lane value, for an
     # exact gather of the original value (a NaN winner comes back as NaN,
@@ -558,6 +605,10 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
     if seg_ids is not None:
         tracing.counter("agg.onepass_segments", len(seg_ids))
         tracing.counter("agg.onepass_lanes", len(lanes) + len(pos_lanes))
+        if pair_sums:
+            tracing.counter("agg.pair_sum_lanes", sum(
+                1 for v, op in lanes.values() if op == "sum" and
+                (isinstance(v, tuple) or v.dtype == jnp.float64)))
 
     counts = found["live"]
     group_mask = (counts > 0) & (jnp.arange(nseg) < prod)

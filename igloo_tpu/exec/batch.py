@@ -263,6 +263,25 @@ def wide_values(col: DeviceColumn) -> jax.Array:
     return spec.widen(col.values)
 
 
+def f32_halves(col: DeviceColumn):
+    """The (hi, lo) float32 halves of a float64 column, where the form it is
+    RESIDENT in holds them with no arithmetic: an f32 pair's two lanes; a
+    carrier that float32 holds exactly and that widens by a cast alone (the
+    f32 round trip, an integer of at most 16 bits), with a zero low half.
+    None for every other form. What a pair-folded SUM reads in place of
+    `wide_values` (kernels.seg_reduce): no decode and no split."""
+    spec = col.carrier
+    if spec is None or spec.lane != "float64":
+        return None
+    if spec.pair:
+        return col.values, col.carrier_arg
+    if spec.scale == 1.0 and not spec.offset and \
+            col.values.dtype in (jnp.float32, jnp.int8, jnp.int16):
+        hi = col.values.astype(jnp.float32)
+        return hi, jnp.zeros_like(hi)
+    return None
+
+
 def materialize(col: DeviceColumn) -> DeviceColumn:
     """Eagerly widen a column to its engine lane (carrier dropped). Boundary
     escape hatch for code paths that cannot carry the carrier metadata —

@@ -29,8 +29,8 @@ from igloo_tpu import types as T
 from igloo_tpu.errors import ExecError, NotSupportedError, PlanError
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
-    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
-    uncompacted_filter,
+    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg,
+    pair_sums_for, seg_dims_for, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
@@ -839,15 +839,18 @@ class Executor:
             pack_spec = K.plan_group_packing(groups, comp.pool)
             if pack_spec is not None:
                 tracing.counter("pack.agg")
+        pair_sums = pair_sums_for(seg_dims, specs)
         fp = ("agg", expr_fingerprint(gres + ares),
               tuple((a.func, a.dtype) for a in aggs),
               batch_proto_key(batch), out_schema,
-              comp.pool.signature(), tuple(comp.marks), seg_dims, pack_spec)
+              comp.pool.signature(), tuple(comp.marks), seg_dims, pack_spec) \
+            + (("pair_sums",) if pair_sums else ())
 
         def build():
             def fn(b: DeviceBatch, consts):
                 return aggregate_batch(b, groups, specs, out_schema, consts,
-                                       seg_dims=seg_dims, pack_spec=pack_spec)
+                                       seg_dims=seg_dims, pack_spec=pack_spec,
+                                       pair_sums=pair_sums)
             return fn
         out = self._jitted("agg", fp, build, pool=comp.pool)(
             *self._bind(comp.pool, batch))

@@ -34,10 +34,15 @@ class Env:
     batch, indexed the same way the binder resolved Column.index, plus the const
     pool arrays (dictionary-derived LUTs) for this execution."""
 
-    def __init__(self, values: list, nulls: list, consts: tuple = ()):
+    def __init__(self, values: list, nulls: list, consts: tuple = (),
+                 columns: Optional[list] = None):
         self.values = values
         self.nulls = nulls
         self.consts = consts
+        # the batch's columns as they are resident (carriers and all), for a
+        # consumer that can read a carrier's own form (batch.f32_halves);
+        # None on an Env built from bare lanes
+        self.columns = columns
 
     @staticmethod
     def from_batch(batch: DeviceBatch, consts: tuple = ()) -> "Env":
@@ -47,7 +52,7 @@ class Env:
         # reads lanes through this Env inside a jitted program, so the widen
         # fuses into the consumer and no wide lane ever materializes in HBM.
         return Env([wide_values(c) for c in batch.columns],
-                   [c.nulls for c in batch.columns], consts)
+                   [c.nulls for c in batch.columns], consts, batch.columns)
 
 
 class ConstPool:

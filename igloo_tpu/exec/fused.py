@@ -56,8 +56,8 @@ import jax.numpy as jnp
 from igloo_tpu import types as T
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
-    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg, seg_dims_for,
-    uncompacted_filter,
+    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg,
+    pair_sums_for, seg_dims_for, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     MIN_CAPACITY, DeviceBatch, DeviceColumn, round_capacity,
@@ -545,16 +545,18 @@ class FusedCompiler:
             pack_spec = K.plan_group_packing(groups, self.pool)
             if pack_spec is not None:
                 tracing.counter("pack.agg")
+        pair_sums = pair_sums_for(seg_dims, specs)
         fp = ("agg", E.shape(gres + ares),
               tuple((a.func, a.dtype) for a in plan.aggs),
-              plan.schema, seg_dims, pack_spec)
+              plan.schema, seg_dims, pack_spec) + \
+            (("pair_sums",) if pair_sums else ())  # keys without it stay put
         self._push(fp)
         out_schema = plan.schema
 
         def fn(leaves, consts, ctx):
             return aggregate_batch(cfn(leaves, consts, ctx), groups, specs,
                                    out_schema, consts, seg_dims=seg_dims,
-                                   pack_spec=pack_spec)
+                                   pack_spec=pack_spec, pair_sums=pair_sums)
         if not groups:
             cap = MIN_CAPACITY
         elif seg_dims is not None:

@@ -360,16 +360,59 @@ def group_segments(sorted_lanes: list, sorted_nulls: list,
 # HBM, so above the threshold its O(nseg * N) work loses to the O(N) scatter.
 SMALL_NSEG = 64
 
-# Operands of one variadic reduce; a longer list of pairs is cut into several.
-# The bound exists because at 64 operands the TPU compiler stops fusing the
-# selects into the reduce and writes every operand to HBM. Under it a whole
-# Q1 (6 lanes x 6 feasible segments) is ONE fusion: no segment mask and no
+# (lane, segment) accumulators of one variadic reduce; a longer list is cut
+# into several. An accumulator is one operand, or an f32 pair's two: a pair
+# counts ONCE, as the float64 operand it stands for always was two f32 words
+# to the chip's compiler. The bound exists because at 64 float64 operands the
+# TPU compiler stops fusing the selects into the reduce and writes every
+# operand to HBM. Under it a whole Q1 (6 lanes x 6 feasible segments; five of
+# the lanes pairs: 66 f32 operands) is ONE fusion: no segment mask and no
 # product reaches HBM. Measured on a v5e at 2^26 lanes (PERF.md §6, PR 33):
 # that Q1 takes 28.4 ms as one reduce, 29.7 as two of 18, 31.0 as six of 6;
 # 288 operands (6 lanes x 48 segments) take 142 ms under this bound and 122
 # under 16 or 24 — a shape no query of the benchmark has; lower the bound
-# with a measurement of one that does.
+# with a measurement of one that does. With its five float64 sums folded as
+# pairs that Q1 is still one fusion in the chip's op list, 9.70 ms a q1 for
+# 15.22 (PERF.md §5, PR 39); tests/test_tpu_compile.py holds the compiler
+# to it without a chip.
 MAX_REDUCE_OPERANDS = 48
+
+
+def two_sum(a, b):
+    """Error-free addition (Knuth): s + e == a + b exactly, in a's dtype."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def pair_add(x, y):
+    """x + y of (hi, lo) f32 pairs, each standing for f64(hi) + f64(lo): the
+    double-single sum a SUM needs, 13 f32 operations and 2 for the guard
+    where the chip's general float64 add takes 27. The last two lines
+    renormalise (|lo| <= ulp(hi) / 2): without them `lo` grows through a long
+    fold and rounds at its own size (2^20 sequential charges: over 1e-11 of
+    their sum for under 1e-12 with them; tests/test_kernels.py holds the
+    difference). A non-finite `hi` (a +-Inf or NaN element, an overflow)
+    makes two_sum's error term NaN, which `h = s + t` would carry into `hi`:
+    the guard keeps it out, `hi` then reads what a plain f32 sum reads, and
+    `lo` is NaN and of no account (`_widen_pair`)."""
+    s, e = two_sum(x[0], y[0])
+    t = e + x[1] + y[1]
+    t = jnp.where(t == t, t, jnp.zeros((), t.dtype))
+    h = s + t
+    return h, t - (h - s)
+
+
+def split_f32_pair(v: jax.Array):
+    """float64 -> its (hi, lo) f32 halves, in-trace (codec.split_pair is the
+    host's): on a chip whose float64 IS this pair, the halves it holds."""
+    hi = v.astype(jnp.float32)
+    return hi, (v - hi.astype(v.dtype)).astype(jnp.float32)
+
+
+def _widen_pair(hi, lo):
+    wide = hi.astype(jnp.float64)
+    return jnp.where(jnp.isfinite(hi), wide + lo.astype(jnp.float64), wide)
 
 
 class _SegOp(NamedTuple):
@@ -388,7 +431,7 @@ _SEG_OPS = {
 
 
 def seg_reduce(lanes: list, seg: jax.Array, nseg: int,
-               seg_ids=None) -> list:
+               seg_ids=None, pair_sums: bool = False) -> list:
     """Segment-reduce several lanes over ONE segment lane. `lanes` is a list
     of (values, op) with op in "sum" / "min" / "max"; returns one [nseg] array
     per lane, in the lane's dtype. `seg_ids` (static ints, default every id)
@@ -396,25 +439,49 @@ def seg_reduce(lanes: list, seg: jax.Array, nseg: int,
     they are reduced, every other slot reads the op's identity (0 / dtype max
     / dtype min) — what an empty segment reads; above, a scatter per lane
     fills every slot. Per element the arithmetic is where(seg == i, v, identity)
-    folded in the lane's own dtype; only the association order is XLA's."""
+    folded in the lane's own dtype; only the association order is XLA's.
+
+    A float64 "sum" lane may be given as its (hi, lo) f32 halves, and with
+    `pair_sums` every float64 "sum" lane is split into them in-trace: the
+    one-pass arm folds the halves with `pair_add` and widens each segment's
+    pair once, into a float64 [nseg]. The caller asks for it where, and only
+    where, the device's float64 is that pair (codec._f32pair_ok, read while
+    the program is planned): there the fold is the float64 sum in fewer
+    operations; on a real float64 it would lose five bits."""
     if nseg > SMALL_NSEG:
-        return [_SEG_OPS[op].scatter(v, seg, num_segments=nseg)
-                for v, op in lanes]
+        return [_SEG_OPS[op].scatter(
+                    _widen_pair(*v) if isinstance(v, tuple) else v, seg,
+                    num_segments=nseg) for v, op in lanes]
+    # a lane's operands: one, or a folded float64 sum's two halves
+    halves = [v if isinstance(v, tuple) else
+              split_f32_pair(v) if pair_sums and op == "sum"
+              and v.dtype == jnp.float64 else (v,) for v, op in lanes]
     ids = list(range(nseg)) if seg_ids is None else list(seg_ids)
-    idents = [_SEG_OPS[op].identity(v.dtype) for v, op in lanes]
+    idents = [tuple(_SEG_OPS[op].identity(a.dtype) for a in h)
+              for h, (_, op) in zip(halves, lanes)]
+    folds = [pair_add if len(h) == 2 else
+             (lambda x, y, f=_SEG_OPS[op].fold: (f(x[0], y[0]),))
+             for h, (_, op) in zip(halves, lanes)]
     pairs = [(k, i) for k in range(len(lanes)) for i in ids]
     found = {}
     for at in range(0, len(pairs), MAX_REDUCE_OPERANDS):
         chunk = pairs[at:at + MAX_REDUCE_OPERANDS]
-        fold = [_SEG_OPS[lanes[k][1]].fold for k, _ in chunk]
+        # operands, identities and results are lists of 1- or 2-tuples: one
+        # per accumulator (lax.reduce flattens the tree)
         outs = jax.lax.reduce(
-            [jnp.where(seg == i, lanes[k][0], idents[k]) for k, i in chunk],
+            [tuple(jnp.where(seg == i, a, z)
+                   for a, z in zip(halves[k], idents[k])) for k, i in chunk],
             [idents[k] for k, _ in chunk],
-            lambda xs, ys: [f(x, y) for f, x, y in zip(fold, xs, ys)],
+            lambda xs, ys, chunk=chunk: [folds[k](x, y) for (k, _), x, y
+                                         in zip(chunk, xs, ys)],
             (0,))
         found.update(zip(chunk, outs))
-    return [jnp.stack([found.get((k, i), idents[k]) for i in range(nseg)])
-            for k in range(len(lanes))]
+    out = []
+    for k, h in enumerate(halves):
+        cols = [jnp.stack([found.get((k, i), idents[k])[j]
+                           for i in range(nseg)]) for j in range(len(h))]
+        out.append(cols[0] if len(h) == 1 else _widen_pair(*cols))
+    return out
 
 
 def seg_sum(vals: jax.Array, seg: jax.Array, nseg: int) -> jax.Array:

@@ -534,9 +534,11 @@ class ShardedExecutor(Executor):
                 final_fields.append(T.Field(f"f{idx}", a.dtype, True))
         final_schema = T.Schema(final_fields)
 
-        from igloo_tpu.exec.aggregate import seg_dims_for
+        from igloo_tpu.exec.aggregate import pair_sums_for, seg_dims_for
         sdims = seg_dims_for(groups)
         fdims = seg_dims_for(final_groups)
+        spair = pair_sums_for(sdims, partial_specs)
+        fpair = pair_sums_for(fdims, final_specs)
         local_cap = batch.capacity // n
         # partial output capacity: direct-scatter partials are segment-count
         # sized, so shuffle buckets and final capacities shrink with them
@@ -584,11 +586,12 @@ class ShardedExecutor(Executor):
 
         def local_fn(b, consts):
             partial = aggregate_batch(b, groups, partial_specs, partial_schema,
-                                      consts, seg_dims=sdims)
+                                      consts, seg_dims=sdims, pair_sums=spair)
             dest = self._group_dest(partial, k, n)
             shuffled, ovf1 = shuffle_batch_local(partial, dest, n, bucket, ROWS)
             final = aggregate_batch(shuffled, final_groups, final_specs,
-                                    final_schema, (), seg_dims=fdims)
+                                    final_schema, (), seg_dims=fdims,
+                                    pair_sums=fpair)
             out = self._fixup_final(final, final_plan, k, out_schema)
             # bound the output capacity (speculative: overflow -> exact re-run)
             perm = K.compact_perm(out.live)
@@ -603,7 +606,7 @@ class ShardedExecutor(Executor):
               tuple((a.func, a.dtype) for a in aggs),
               batch_proto_key(batch), out_schema,
               comp.pool.signature(), tuple(comp.marks), n, bucket,
-              out_cap_local, sdims, fdims)
+              out_cap_local, sdims, fdims, spair, fpair)
         out, overflow = self._jitted_shard_map(
             "shagg", fp, local_fn, out_specs=(P(ROWS), P()))(
             strip_dicts(batch), comp.pool.device_args())

@@ -198,6 +198,37 @@ def test_row_movers_give_the_wide_runs_rows(verdicts, name, compiler):
     _assert_rows_close(got, want)
 
 
+@pytest.mark.parametrize("compiler", ["fused", "staged"])
+def test_q1_folds_its_float_sums_as_pairs_where_float64_is_a_pair(verdicts,
+                                                                  compiler):
+    """ISSUE 39: under the chip's verdicts q1's five distinct float64 sums
+    (the AVGs repeat three of them) are folded as f32 pairs — quantity from
+    its int8 carrier, price and discount from their resident halves, the two
+    products split in-trace — and the rows are the wide run's within 1e-12;
+    under XLA:CPU's own verdict no lane is, and the counter is absent."""
+    from igloo_tpu.bench.tpch import QUERIES as TPCH, gen_tables
+    lineitem = gen_tables(sf=0.002)["lineitem"]
+
+    def q1():
+        e = QueryEngine()
+        e.register_table("lineitem", lineitem)
+        with tracing.counter_delta() as d:
+            if compiler == "fused":
+                return e.execute(TPCH["q1"]), d
+            from igloo_tpu.exec.executor import Executor
+            ex = Executor(e._jit_cache, batch_cache=e.batch_cache)
+            return ex._staged_to_arrow(e.plan(TPCH["q1"])), d
+
+    verdicts(False)
+    want, d0 = q1()
+    assert d0.get("agg.onepass_lanes") and "agg.pair_sum_lanes" not in d0
+    verdicts(True)
+    got, d1 = q1()
+    assert d1.get("agg.pair_sum_lanes") == 5
+    assert d1.get("codec.f32pair_columns") >= 2
+    _assert_rows_close(got, want)
+
+
 def test_explain_analyze_names_the_pair_columns(verdicts):
     verdicts(True)
     fact, _dim = _tables()

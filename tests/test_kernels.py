@@ -555,6 +555,168 @@ def test_seg_reduce_duplicate_lanes_and_chunked_operands(monkeypatch):
     assert np.array_equal(np.asarray(whole[1]), np.asarray(cut[1]))
 
 
+# --- float64 sums folded as f32 pairs (ISSUE 39) ----------------------------
+
+_PAIR_LANES = 1 << 20
+_PAIR_IDS = [5, 6, 7, 9, 10, 11]   # q1's feasible ids of a padded 16
+_PAIR_BOUND = 1e-12                # relative to sum(|x|), at 2^20 lanes
+
+
+def _pair_case(name):
+    """(values, segment lane) of one case: TPC-H-shaped lanes, mixed signs,
+    magnitudes over twenty decades, structure cases, non-finite rows."""
+    rng = np.random.default_rng(39)
+    n = _PAIR_LANES
+    price = np.round(rng.uniform(900.0, 105000.0, n), 2)
+    disc = rng.integers(0, 11, n) / 100.0
+    tax = rng.integers(0, 9, n) / 100.0
+    seg = np.where(rng.random(n) < 0.98, rng.choice(_PAIR_IDS, n),
+                   15).astype(np.int32)
+    charge = price * (1 - disc) * (1 + tax)
+    if name == "price":
+        v = price
+    elif name == "disc_price":
+        v = price * (1 - disc)
+    elif name == "charge":
+        v = charge
+    elif name == "discounts":
+        v = disc
+    elif name == "mixed_signs":
+        v = rng.standard_normal(n) * 1e4
+    elif name == "twenty_decades":
+        v = rng.standard_normal(n) * 10.0 ** rng.integers(-10, 10, n)
+    elif name == "empty_segment":
+        v = charge
+        seg = np.where(seg == _PAIR_IDS[2], 15, seg).astype(np.int32)
+    elif name == "all_dead":
+        v = charge
+        seg = np.full(n, 15, np.int32)
+    else:
+        v = charge.copy()
+        rows = np.flatnonzero(seg == _PAIR_IDS[1])
+        v[rows[:4]] = {"pos_inf": [np.inf, 1.0, np.inf, 2.0],
+                       "nan": [np.nan, 1.0, 2.0, 3.0],
+                       "both_infs": [np.inf, 1.0, -np.inf, 2.0]}[name]
+    return v, seg
+
+
+def _np_halves(v):
+    """The f32 halves a pair carrier would hold; a non-finite row keeps its
+    value in `hi` beside a zero, as the f32 round-trip carrier holds it."""
+    with np.errstate(invalid="ignore", over="ignore"):
+        hi = v.astype(np.float32)
+        lo = (v - hi.astype(np.float64)).astype(np.float32)
+    return hi, np.where(np.isfinite(hi), lo, np.float32(0))
+
+
+@pytest.mark.parametrize("route", ["split_in_trace", "halves_given"])
+@pytest.mark.parametrize("case", [
+    "price", "disc_price", "charge", "discounts", "mixed_signs",
+    "twenty_decades", "empty_segment", "all_dead", "pos_inf", "nan",
+    "both_infs"])
+def test_pair_fold_equals_the_exact_sum(case, route):
+    """`seg_reduce`'s pair fold, engaged as the chip's canary would engage
+    it, against `math.fsum` of the values the halves stand for: within 1e-12
+    of sum(|x|) at 2^20 lanes in XLA:CPU's order, for halves split in-trace
+    and for halves handed over. A lane on which the float64 arm itself errs
+    more (the discounts: eleven distinct values, whose roundings into ANY
+    accumulator do not cancel) is held to 1.5 x that arm's error. A segment
+    with a non-finite row reads what the float64 arm reads; a segment or a
+    lane without rows reads 0."""
+    import math
+    import jax
+    import jax.numpy as jnp
+    from igloo_tpu.exec import kernels as K
+    v, seg = _pair_case(case)
+    hi, lo = _np_halves(v)
+    with np.errstate(invalid="ignore"):
+        x = np.where(np.isfinite(v), hi.astype(np.float64) + lo, v)
+    segj = jnp.asarray(seg)
+    [wide] = jax.jit(lambda a, s: K.seg_reduce(
+        [(a, "sum")], s, 16, _PAIR_IDS))(jnp.asarray(x), segj)
+    if route == "split_in_trace":
+        [got] = jax.jit(lambda a, s: K.seg_reduce(
+            [(a, "sum")], s, 16, _PAIR_IDS, pair_sums=True))(
+                jnp.asarray(x), segj)
+    else:
+        [got] = jax.jit(lambda h, l, s: K.seg_reduce(
+            [((h, l), "sum")], s, 16, _PAIR_IDS))(
+                jnp.asarray(hi), jnp.asarray(lo), segj)
+    assert got.shape == (16,) and got.dtype == jnp.float64
+    got, wide = np.asarray(got), np.asarray(wide)
+    for i in range(16):
+        rows = x[seg == i] if i in _PAIR_IDS else x[:0]
+        if not np.isfinite(rows).all():
+            assert np.array_equal(got[i], wide[i], equal_nan=True), (i, got[i])
+        elif len(rows) == 0:
+            assert got[i] == 0.0
+        else:
+            want, mag = math.fsum(rows), math.fsum(np.abs(rows))
+            bound = max(_PAIR_BOUND, 1.5 * abs(wide[i] - want) / mag)
+            assert abs(got[i] - want) / mag <= bound, (i, got[i], want)
+
+
+def test_pair_fold_needs_its_renormalising_lines(monkeypatch):
+    """`pair_add`'s last two lines are not an ornament: folded without them
+    (two_sum of the high halves, the low halves added up), 2^20 charges in
+    ONE segment miss the bound the renormalised fold keeps on the same
+    lane — `lo` grows and rounds at its own size."""
+    import math
+    import jax.numpy as jnp
+    from igloo_tpu.exec import kernels as K
+    v, _ = _pair_case("charge")
+    hi, lo = _np_halves(v)
+    x = hi.astype(np.float64) + lo
+    want, mag = math.fsum(x), math.fsum(np.abs(x))
+    seg = jnp.zeros(len(v), jnp.int32)
+    lanes = [((jnp.asarray(hi), jnp.asarray(lo)), "sum")]
+
+    def err():
+        [got] = K.seg_reduce(lanes, seg, 8, [0])
+        return abs(float(got[0]) - want) / mag
+    assert err() <= _PAIR_BOUND
+
+    def unrenormalised(x, y):
+        s, e = K.two_sum(x[0], y[0])
+        return s, e + x[1] + y[1]
+    monkeypatch.setattr(K, "pair_add", unrenormalised)
+    assert err() > 10 * _PAIR_BOUND
+
+
+def test_pair_fold_counts_a_pair_once_against_the_operand_bound(monkeypatch):
+    """Five pair lanes and a count over six segments are 36 accumulators
+    (66 f32 operands): ONE `lax.reduce` under the bound of 48; cut at a
+    lower bound, the same numbers. Above SMALL_NSEG halves handed over are
+    widened and scattered."""
+    import jax
+    import jax.numpy as jnp
+    from igloo_tpu.exec import kernels as K
+    rng = np.random.default_rng(3)
+    n = 4096
+    seg = jnp.asarray(rng.choice(_PAIR_IDS, n).astype(np.int32))
+    vs = [rng.uniform(1.0, 1e5, n) for _ in range(5)]
+    lanes = [(jnp.asarray(v), "sum") for v in vs] + \
+        [(jnp.ones(n, jnp.int32), "sum")]
+
+    def run(s):
+        return K.seg_reduce(lanes, s, 16, _PAIR_IDS, pair_sums=True)
+    assert str(jax.make_jaxpr(run)(seg)).count(" reduce[") == 1
+    whole = run(seg)
+    monkeypatch.setattr(K, "MAX_REDUCE_OPERANDS", 7)
+    assert str(jax.make_jaxpr(lambda s: run(s))(seg)).count(" reduce[") == 6
+    for a, b, v in zip(whole, run(seg), vs + [np.ones(n)]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-12)
+        want = [v[np.asarray(seg) == i].sum() for i in _PAIR_IDS]
+        np.testing.assert_allclose(np.asarray(a)[_PAIR_IDS], want, rtol=1e-12)
+    hi, lo = _np_halves(vs[0])
+    big = jnp.asarray(rng.integers(0, 128, n).astype(np.int32))
+    [got] = K.seg_reduce([((jnp.asarray(hi), jnp.asarray(lo)), "sum")], big,
+                         128)
+    want = jax.ops.segment_sum(jnp.asarray(hi.astype(np.float64) + lo), big,
+                               num_segments=128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-14)
+
+
 @pytest.mark.parametrize("wrapper,op", [("seg_sum", "sum"), ("seg_min", "min"),
                                         ("seg_max", "max")])
 @pytest.mark.parametrize("nseg", [64, 128])

@@ -93,7 +93,7 @@ def test_gather_stage_compiles(one_chip):
 
 # --- q1's aggregate: the one-pass small-domain reduce (ISSUE 33; ~10-20 s) --
 
-def _q1_shaped_aggregate(pair: bool = False):
+def _q1_shaped_aggregate(pair: bool = False, pair_sums: bool = False):
     """`aggregate_batch` as q1's scan fragment calls it, as a function of
     plain lanes: two dictionary keys without null lanes (3 x 2 values), four
     float64 columns, the eight aggregates of q1, each argument compiled from
@@ -101,7 +101,9 @@ def _q1_shaped_aggregate(pair: bool = False):
     repeat three of the SUMs'), `seg_dims` ((4, 0), (3, 0)). With `pair` the
     three columns that ride as raw float64 on the chip (price, disc, tax;
     qty is an int8 carrier there) are f32-pair carriers instead (ISSUE 37):
-    `fn` then takes their low halves after `live`."""
+    `fn` then takes their low halves after `live`. With `pair_sums` the five
+    float64 sums fold as f32 pairs (ISSUE 39), as where the chip's canary
+    says its float64 is that pair."""
     from igloo_tpu import types as T
     from igloo_tpu.exec.codec import WidenSpec
     from igloo_tpu.exec.aggregate import AggSpec, aggregate_batch
@@ -156,12 +158,22 @@ def _q1_shaped_aggregate(pair: bool = False):
                         for c, lo in zip(cols[3:], lows)]
         out = aggregate_batch(DeviceBatch(in_schema, cols, live), groups,
                               specs, out_schema, consts,
-                              seg_dims=((4, 0), (3, 0)))
+                              seg_dims=((4, 0), (3, 0)), pair_sums=pair_sums)
         return [(c.values, c.nulls) for c in out.columns], out.live
     # the constants pool as PARAMETERS, as a dispatch passes it: q1's three
     # literals (the 1 of `1 - l_discount`, twice, and of `1 + l_tax`) are
     # scalars of one float64 vector (ISSUE 34), no constants of the trace
     return fn, [(a.shape, a.dtype) for a in comp.pool.device_args()]
+
+
+def _q1_shapes(pair, consts):
+    """The argument shapes of `_q1_shaped_aggregate(pair)`'s `fn`."""
+    head = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)]
+    if pair:
+        return head + [((LANES,), jnp.float32)] * 3 + \
+            [((LANES,), jnp.bool_)] + [((LANES,), jnp.float32)] * 3 + consts
+    return head + [((LANES,), jnp.float64)] * 3 + [((LANES,), jnp.bool_)] + \
+        consts
 
 
 # distinct lanes q1 hands to the one-pass reduce: five float64 sums (the AVGs
@@ -184,9 +196,7 @@ def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
     a tenth of the bytes the per-segment loop read."""
     fn, consts = _q1_shaped_aggregate()
     assert consts == [((3,), jnp.float64)]
-    shapes = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)] * 4 + \
-        [((LANES,), jnp.bool_)] + consts
-    c = _lower_and_compile(fn, shapes, one_chip)
+    c = _lower_and_compile(fn, _q1_shapes(False, consts), one_chip)
     text = c.as_text()
     entry = text[text.index("ENTRY"):]
     fusions = re.findall(r"^\s*(?:ROOT )?%?(\S*fusion\S*) = (.*?) fusion\(",
@@ -196,6 +206,52 @@ def test_q1_aggregate_is_one_pass_under_the_chips_compiler(one_chip):
     reduces = [n for n, _ in fusions if "reduce" in n]
     assert 1 <= len(reduces) <= Q1_LANES + 2, reduces
     assert c.cost_analysis()["bytes accessed"] < Q1_PARENT_BYTES / 10
+
+
+# --- q1's float64 sums folded as f32 pairs (ISSUE 39; ~25 s a half) ---------
+
+# folded / float64 `flops` of the whole aggregate (products, decodes, reduce)
+# compiled for the described chip at LANES = 2^20: 832.7 M / 1,089.6 M = 0.764
+# over float64 leaves, 837.9 M / 1,143.0 M = 0.733 over pair leaves (PR 39).
+# ISSUE 39 sized 0.70 from a fold of 13 operations a pair and segment; the
+# guard that keeps a NaN error term out of `hi` (kernels.pair_add) makes it 15
+Q1_FOLD_FLOPS_RATIO = 0.80
+
+
+def _reduce_arities(compiled) -> list:
+    """Accumulators of every `reduce(` instruction of the program, largest
+    first: q1's ONE variadic reduce over the lanes (6 s32 counts and 30
+    float64 sums, each two f32 words to this compiler either way: 66) and
+    the two-word count of live groups."""
+    return sorted((len(re.findall(r"\w+\[\]", m)) for m in re.findall(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) reduce\(", compiled.as_text(), re.M)),
+        reverse=True)
+
+
+@pytest.mark.parametrize("pair", [False, True], ids=["f64_leaves", "pairs"])
+def test_q1_pair_fold_is_one_cheaper_reduce_under_the_chips_compiler(
+        one_chip, pair):
+    """ISSUE 39's guard, for "the compiler stopped fusing" and for the ratio
+    the change was sized by: with the fold engaged q1's aggregate is still
+    ONE reduce fusion over the lanes (66 f32 operands), no `pred[LANES]` or
+    `f32[LANES]` operand of it reaches HBM (temporaries stay what the
+    float64 reduce's are: kilobytes), and XLA's own operation count is under
+    Q1_FOLD_FLOPS_RATIO of the float64 reduce's, compiled here beside it."""
+    compiled = {}
+    for fold in (False, True):
+        fn, consts = _q1_shaped_aggregate(pair=pair, pair_sums=fold)
+        compiled[fold] = _lower_and_compile(fn, _q1_shapes(pair, consts),
+                                            one_chip)
+    wide, folded = compiled[False], compiled[True]
+    lanes = Q1_SEGMENTS * (1 + 2 * (Q1_LANES - 1))
+    assert _reduce_arities(folded) == _reduce_arities(wide) == [lanes, 2]
+    entry = folded.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    assert not re.findall(r"= (?:pred|f32)\[%d\]\S* fusion\(" % LANES, entry)
+    assert folded.memory_analysis().temp_size_in_bytes <= \
+        wide.memory_analysis().temp_size_in_bytes + (1 << 20)
+    ratio = folded.cost_analysis()["flops"] / wide.cost_analysis()["flops"]
+    assert ratio <= Q1_FOLD_FLOPS_RATIO, ratio
 
 
 # --- a resident float64 column as its two f32 halves (ISSUE 37) -------------
@@ -216,15 +272,10 @@ def test_q1_aggregate_over_pair_leaves_opens_with_no_split(one_chip):
     over float64 leaves it holds two per column — if a later jax stops
     splitting, that half fails and the carrier has become dead weight."""
     fn, consts = _q1_shaped_aggregate()
-    head = [((LANES,), jnp.int32)] * 2 + [((LANES,), jnp.float64)]
-    wide = _lower_and_compile(
-        fn, head + [((LANES,), jnp.float64)] * 3 + [((LANES,), jnp.bool_)] +
-        consts, one_chip)
+    wide = _lower_and_compile(fn, _q1_shapes(False, consts), one_chip)
     assert _lane_splits(wide) == 2 * 4  # qty, price, disc, tax
     fn, consts = _q1_shaped_aggregate(pair=True)
-    pair = _lower_and_compile(
-        fn, head + [((LANES,), jnp.float32)] * 3 + [((LANES,), jnp.bool_)] +
-        [((LANES,), jnp.float32)] * 3 + consts, one_chip)
+    pair = _lower_and_compile(fn, _q1_shapes(True, consts), one_chip)
     assert _lane_splits(pair) == 2  # qty alone, a float64 leaf in this harness
 
 
