@@ -43,21 +43,46 @@ MONOLITHIC_SHARE = 1 / 8
 # where the backend reports no limit (XLA:CPU): the constants the engine had
 # before the budgets were derived, so that no CPU route changes
 UNLIMITED_BUDGETS = (1 << 30, 2 << 30)
+# - DIRECT TABLE: what ONE direct join's positional table (exec/join.py
+#   choose_direct_build) may take of the monolithic share. The table is
+#   one of the join's temporaries beside its probe lanes, so it gets half:
+#   on a v5e (monolithic 2.11 GB) 2^27 int32 slots (537 MB, TPC-H Q3's
+#   order keys at SF10 as the spec spaces them) and not 2^28 (1.07 GB).
+DIRECT_TABLE_SHARE = 1 / 2
+
+
+def _bytes_limit():
+    """The smallest local device's `bytes_limit` (a mesh row-shards evenly,
+    so the fullest chip is the tightest), or None where the backend reports
+    none. This starts the backend."""
+    import jax
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in jax.local_devices()]
+    if not limits or not all(limits):
+        return None
+    return min(limits)
 
 
 def hbm_budgets() -> tuple:
     """(resident, monolithic) bytes for this process's devices: the scan
     cache's budget, which is also the chunked tier's threshold, and the GRACE
-    trigger's, shared by `QueryEngine` and the cluster worker. The smallest local device decides (a mesh row-shards
-    evenly, so the fullest chip is the tightest). This starts the backend:
-    call it where a device is needed anyway, not at construction."""
-    import jax
-    limits = [(d.memory_stats() or {}).get("bytes_limit")
-              for d in jax.local_devices()]
-    if not limits or not all(limits):
+    trigger's, shared by `QueryEngine` and the cluster worker. This starts
+    the backend: call it where a device is needed anyway, not at
+    construction."""
+    limit = _bytes_limit()
+    if limit is None:
         return UNLIMITED_BUDGETS
-    limit = min(limits)
     return int(limit * RESIDENT_SHARE), int(limit * MONOLITHIC_SHARE)
+
+
+def direct_table_budget():
+    """Bytes one direct join's positional table may take on this process's
+    devices (`DIRECT_TABLE_SHARE` of the monolithic share), or None where
+    the backend reports no limit: the caller keeps its fixed size there."""
+    limit = _bytes_limit()
+    if limit is None:
+        return None
+    return int(limit * MONOLITHIC_SHARE * DIRECT_TABLE_SHARE)
 
 
 def scan_table_key(name: str) -> str:

@@ -426,6 +426,21 @@ class Executor:
         comp = FusedCompiler(self)
         with tracing.span("fused.plan"):
             run, key, meta = comp.compile(plan)
+            probe = comp.probe()
+        if probe is not None:
+            # a wide plan seen for the first time: learn its live counts from
+            # its probe (none of its rows), then compile the hinted program
+            # from them at once
+            tracing.counter("fused.probe")
+            jp = self._jitted("fused_probe", key, lambda: probe,
+                              pool=comp.pool)
+            *leaves, consts = self._bind(comp.pool, *comp.leaves)
+            counts = jp(leaves, consts)
+            with tracing.span("fused.fetch"):
+                self._record_hints(comp, jax.device_get(counts))
+            comp = FusedCompiler(self)
+            with tracing.span("fused.plan"):
+                run, key, meta = comp.compile(plan)
         stats.annotate(nodes=len(comp.fps), leaves=len(comp.leaves))
         # `nofuse` sentinel: armed in the persistent store before a
         # first-in-process fused compile, cleared on success. A process killed
@@ -474,12 +489,7 @@ class Executor:
             record_fetch((host_live, host_vals, host_nulls,
                           pair_halves(spec, host_cargs)))
             stats.set_rows(int(n))
-            for sid, v in stats_h.items():
-                self._cache[("nhint", comp.stat_keys[sid])] = int(v)
-                if self._hints is not None:
-                    self._hints.put(comp.stat_keys[sid], int(v))
-            if self._hints is not None:
-                self._hints.flush()
+            self._record_hints(comp, stats_h)
             fired = [comp.flag_tags[fid] for fid, v in flags_h.items()
                      if bool(v)]
             for tag in fired:
@@ -508,6 +518,16 @@ class Executor:
             return fn
         out = self._jitted("compact", fp, build)(big)
         return to_arrow(attach_dicts(out, meta.dicts, meta.bounds))
+
+    def _record_hints(self, comp: FusedCompiler, counts: dict) -> None:
+        """A fused program's fetched live counts ({stat id: count}) become
+        the cardinality hints its plan's next compile adopts."""
+        for sid, v in counts.items():
+            self._cache[("nhint", comp.stat_keys[sid])] = int(v)
+            if self._hints is not None:
+                self._hints.put(comp.stat_keys[sid], int(v))
+        if self._hints is not None:
+            self._hints.flush()
 
     def _staged_to_arrow(self, plan: L.LogicalPlan) -> pa.Table:
         from igloo_tpu.exec.batch import arrow_from_host

@@ -36,7 +36,11 @@ but pushes no `acompact` fingerprint and raises no flag, so its program key is
 the same before and after the hint exists and nothing is traced or compiled
 twice (`fused.compact_declined` counts the hints left unadopted). Joins,
 grouped aggregates, sorts, top-k, windows, distinct and limits read narrower
-inputs cheaper by more than the compaction costs and keep it.
+inputs cheaper by more than the compaction costs and keep it. A plan seen
+for the first time whose unhinted candidates are wider than
+`PROBE_CAPACITY` learns its counts from a probe (`FusedCompiler.probe`: the
+counts alone, none of the rows) and compiles its hinted program at once,
+instead of compiling and running a full-width program to throw away.
 
 Correctness flags collected across the program (direct-join duplicate keys,
 speculative join capacity overflow, compaction overflow) come back in the same
@@ -108,6 +112,16 @@ NodeFn = Callable
 ADAPTIVE_CAPACITY = 1 << 18
 # only compact when the hinted capacity shrinks the batch at least this much
 ADAPTIVE_SHRINK = 4
+# a plan with an unhinted compaction candidate wider than this first runs a
+# cardinality probe (FusedCompiler.probe) instead of its unhinted program:
+# at TPC-H SF10 (2^26-lane lineitem) q3's unhinted program took 157 s to
+# compile and 78 s to run on a v5e, only to learn the live counts its hinted
+# successor is compiled from. Above a candidate this wide a grouping node
+# records its count and never compacts by it: learning the count takes the
+# hinted program's first run, and adopting it a third program of the plan
+# (106 s to compile for q3 at SF10) for a narrower input to the top-k above.
+# At SF1 (2^23) neither applies.
+PROBE_CAPACITY = 1 << 24
 
 
 class FusedCompiler:
@@ -133,6 +147,12 @@ class FusedCompiler:
         # Filter nodes whose consumer asked them not to compact
         # (aggregate.uncompacted_filter): hints recorded, never adopted
         self.uncompacted: list = []
+        # stat ids of compaction candidates wider than PROBE_CAPACITY that
+        # have no hint yet, and of grouping nodes (left out of the probe);
+        # `wide`: a candidate wider than PROBE_CAPACITY was compiled
+        self.unhinted_wide: list = []
+        self.grouping_stats: set = set()
+        self.wide = False
 
     # --- side-channel ids -------------------------------------------------
 
@@ -152,6 +172,13 @@ class FusedCompiler:
     def _new_stat(self, key) -> int:
         self.stat_keys.append(key)
         return len(self.stat_keys) - 1
+
+    def _wide_candidate(self, sid: int, hint) -> None:
+        """A compaction candidate wider than PROBE_CAPACITY was compiled
+        (stat `sid`; `hint` None where it has none yet)."""
+        self.wide = True
+        if hint is None:
+            self.unhinted_wide.append(sid)
 
     def _hint(self, key) -> Optional[int]:
         v = self.ex._cache.get(("nhint", key))
@@ -183,7 +210,28 @@ class FusedCompiler:
 
         key = ("fused", tuple(self.fps), self.pool.signature(),
                tuple(self.marks), fetch_cap)
+        self._fn = fn
         return run, key, meta
+
+    def probe(self):
+        """The compiled plan's cardinality probe, or None when it has no
+        unhinted compaction candidate wider than PROBE_CAPACITY: a program
+        that returns the live counts the plan's run records ({stat id:
+        count}) and nothing else, so XLA drops every gather a count does not
+        read and the sorts above the filters and joins. The counts of
+        grouping nodes (aggregate, distinct) are left out — they would keep
+        the grouping's sort — and are recorded by the hinted program's first
+        run, never adopted (PROBE_CAPACITY). Call after compile()."""
+        if not self.unhinted_wide:
+            return None
+        fn, grouping = self._fn, frozenset(self.grouping_stats)
+
+        def run(leaves, consts):
+            ctx = Ctx()
+            fn(leaves, consts, ctx)
+            return {sid: n for sid, n in ctx.stats.items()
+                    if sid not in grouping}
+        return run
 
     # --- dispatch ---------------------------------------------------------
 
@@ -223,6 +271,12 @@ class FusedCompiler:
         hkey = (kind, tuple(self.hfps))
         sid = self._new_stat(hkey)
         hint = self._hint(hkey)
+        if kind in ("aggregate", "distinct"):
+            self.grouping_stats.add(sid)
+            if self.wide:           # see PROBE_CAPACITY
+                hint = None
+        elif compact and meta.capacity > PROBE_CAPACITY:
+            self._wide_candidate(sid, hint)
         want = round_capacity(max(hint, 1)) if hint is not None else None
         shrinks = want is not None \
             and want * ADAPTIVE_SHRINK <= meta.capacity
@@ -450,6 +504,8 @@ class FusedCompiler:
         want = round_capacity(max(hint, 1)) if hint is not None else None
         if want is not None and want * ADAPTIVE_SHRINK <= probe_cap:
             sid = self._new_stat(hkey)
+            if probe_cap > PROBE_CAPACITY:
+                self._wide_candidate(sid, hint)
             ofid = self._new_flag(("compact", hkey))
             self._push(("join_lazy",) + jfp[1:] +
                        (side, blo, tsize, ki, want, plan.schema),
@@ -480,6 +536,8 @@ class FusedCompiler:
 
         if jt is JoinType.INNER:
             sid = self._new_stat(hkey)
+            if probe_cap > PROBE_CAPACITY:
+                self._wide_candidate(sid, hint)
         else:
             sid = None
         if jt in (JoinType.SEMI, JoinType.ANTI):

@@ -490,8 +490,25 @@ def choose_match_capacity(total: int) -> int:
 # so there is no verify phase at all.
 # ---------------------------------------------------------------------------
 
-# widest positional table we will allocate (lanes; int32 => 64 MiB at the cap)
-DIRECT_RANGE_BUDGET = 1 << 24
+# what a positional table costs per slot: one int32 build-row id (direct_probe
+# allocates the table and nothing else of its size: the duplicate check is a
+# sum over `table >= 0`, one reduction that keeps no array)
+DIRECT_SLOT_BYTES = 4
+# where the backend reports no memory limit (XLA:CPU): the fixed widest table
+# the engine had before the limit was derived (64 MiB), so no CPU pick changes
+UNLIMITED_DIRECT_SLOTS = 1 << 24
+
+
+def direct_table_slots() -> int:
+    """The most slots a positional table may have on this process's devices:
+    its share of the chip's memory (exec/cache.py direct_table_budget) over
+    DIRECT_SLOT_BYTES. The fused compiler and the staged executor both pick
+    under it (choose_direct_build)."""
+    from igloo_tpu.exec.cache import direct_table_budget
+    budget = direct_table_budget()
+    if budget is None:
+        return UNLIMITED_DIRECT_SLOTS
+    return budget // DIRECT_SLOT_BYTES
 
 
 def _direct_key_ok(c: Compiled) -> bool:
@@ -507,7 +524,7 @@ def choose_direct_build(lks: list, rks: list, left_cap: int,
     (exec/capacity.canonical_direct_table) — size quantized to the capacity
     family and base grid-aligned, so the raw key bounds never become program
     constants and neighboring scale factors share one compiled join. A
-    (side, key) qualifies when the key's bounds span <= DIRECT_RANGE_BUDGET
+    (side, key) qualifies when its table fits `direct_table_slots()`
     and the side's row capacity could plausibly be unique over that range
     (cap <= its canonical table size: any padded batch whose live rows fit
     the range fits the table, whatever the family's padding ratio or
@@ -518,13 +535,18 @@ def choose_direct_build(lks: list, rks: list, left_cap: int,
     integer-family. The runtime duplicate check backstops a wrong pick;
     `banned` carries sides that PROVED duplicated on earlier runs (the
     ("nodirect", jfp_core, side) negative cache), so the other side still
-    gets its chance."""
+    gets its chance. Called once per join of a plan walk, so its counters
+    are per query: `join.direct_routes` and `join.direct_table_bytes` for a
+    pick, `join.direct_over_budget` for a join declined only for the size
+    of its table."""
     from igloo_tpu.exec.capacity import canonical_direct_table
     if join_type is JoinType.CROSS or not lks:
         return None
     if not all(_direct_key_ok(c) for c in lks + rks):
         return None
+    limit = direct_table_slots()
     options = []
+    over = False
     for side, keys, cap in (("right", rks, right_cap), ("left", lks, left_cap)):
         if side in banned:
             continue
@@ -533,17 +555,25 @@ def choose_direct_build(lks: list, rks: list, left_cap: int,
             if b is None:
                 continue
             rng = int(b[1]) - int(b[0]) + 1
-            if rng > DIRECT_RANGE_BUDGET:
+            if rng > limit:             # its table has at least rng slots
+                over = True
                 continue
             base, tsize = canonical_direct_table(int(b[0]), int(b[1]))
-            if cap <= tsize <= DIRECT_RANGE_BUDGET:
-                options.append((cap, rng, side, (base, tsize), i))
+            if cap > tsize:
+                continue
+            if tsize > limit:
+                over = True
+                continue
+            options.append((cap, rng, side, (base, tsize), i))
     if not options:
         tracing.counter("join.direct_ineligible")
+        if over:
+            tracing.counter("join.direct_over_budget")
         return None
     options.sort(key=lambda o: (o[0], o[1], o[2], o[4]))
     _, _, side, table, idx = options[0]
-    tracing.counter("join.direct_eligible")
+    tracing.counter("join.direct_routes")
+    tracing.counter("join.direct_table_bytes", table[1] * DIRECT_SLOT_BYTES)
     return side, table, idx
 
 

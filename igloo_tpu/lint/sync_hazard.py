@@ -84,7 +84,9 @@ CHOKE_POINTS = {
         "query come back in one readback at the end.",
     ("igloo_tpu/exec/executor.py", "Executor._fused_run"):
         "the fused path's single fetch: result + flags + cardinality "
-        "stats in one device_get (the whole point of fusion).",
+        "stats in one device_get (the whole point of fusion); on a wide "
+        "plan's first execution its probe's counts come first, once "
+        "(FusedCompiler.probe).",
     ("igloo_tpu/exec/executor.py", "Executor._staged_to_arrow"):
         "final fetch of the staged path (speculative compact + one "
         "device_get; overflow pays an exact refetch).",

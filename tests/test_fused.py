@@ -566,3 +566,88 @@ def test_chunked_global_partial_declines_too(small_adaptive, tmp_path):
         _same_answer(t, want)
     assert c.get("engine.chunked_route") and not c.get("jit.miss")
     assert c.get("fused.compact_declined") == c.get("fused.execute") >= 2
+
+
+# --- a wide plan seen for the first time probes its counts --------------------
+
+def _runs(probe_capacity: int, monkeypatch, fact, dim, n: int = 3):
+    """n executions of SQL on a fresh engine without persistent hints ->
+    ([counter deltas], [the program key its next execution compiles to],
+    the last answer)."""
+    monkeypatch.setattr(F, "PROBE_CAPACITY", probe_capacity)
+    e = QueryEngine()
+    e.hint_store = None
+    e.register_table("fact", fact)
+    e.register_table("dim", dim)
+    deltas, keys = [], []
+    for _ in range(n):
+        e.result_cache.clear()
+        with tracing.counter_delta() as d:
+            t = e.execute(SQL)
+        deltas.append(d.values())
+        keys.append(F.FusedCompiler(e._executor()).compile(e.plan(SQL))[1])
+    return deltas, keys, (t.column("s")[0].as_py(), t.column("c")[0].as_py())
+
+
+@pytest.mark.parametrize("match_every", [64, 1])
+def test_a_wide_plan_probes_its_counts_then_compiles_the_hinted_program(
+        monkeypatch, match_every):
+    """Below PROBE_CAPACITY (the default here) the first execution compiles
+    and runs the unhinted program and the second the hinted one; above it
+    the first execution runs the probe — the live counts alone — and
+    compiles the hinted program at once: the same program, the same answer,
+    one execution sooner. Where no hint shrinks anything (every row
+    matches) the hinted program is the unhinted one."""
+    fact, dim = _mk_tables(N_FACT, 1000, match_every=match_every)
+    want = _oracle(fact, dim)
+    plain, plain_keys, got = _runs(F.PROBE_CAPACITY, monkeypatch, fact, dim)
+    assert got == want
+    assert [d.get("jit.miss", 0) for d in plain] == [1, 1 if match_every > 1
+                                                     else 0, 0]
+    assert not any(d.get("fused.probe") for d in plain)
+    probed, probed_keys, got = _runs(F.ADAPTIVE_CAPACITY, monkeypatch, fact,
+                                     dim)
+    assert got == want
+    assert probed[0]["fused.probe"] == 1
+    assert probed[0]["jit.miss"] == 2          # the probe, the hinted program
+    assert probed[0]["fused.execute"] == 1     # one program ran the query
+    assert [d.get("jit.miss", 0) for d in probed[1:]] == [0, 0]
+    assert not any(d.get("fused.probe") for d in probed[1:])
+    assert probed_keys[0] == plain_keys[-1]
+    assert not any(d.get("fused.compact_repair") for d in plain + probed)
+
+
+GROUPED = ("SELECT w * 1.5 AS g, count(*) AS c FROM fact JOIN dim ON fk = k "
+           "GROUP BY w * 1.5 ORDER BY g")
+
+
+def test_above_a_wide_candidate_a_grouping_node_never_compacts(monkeypatch):
+    """100 groups over 2^20 joined lanes: the aggregate's hint would shrink
+    its output 8192-fold. Below PROBE_CAPACITY the unhinted program's run
+    learns it and the second execution compiles the program that compacts
+    by it; above it the probe leaves grouping counts out and the count the
+    hinted program records is never adopted, so the plan settles on the
+    program its first execution compiled, with the same rows."""
+    fact, dim = _mk_tables(N_FACT, 1000, match_every=1)
+    answers, default = [], F.PROBE_CAPACITY
+    for probe_capacity, misses in ((default, [1, 1, 0]),
+                                   (F.ADAPTIVE_CAPACITY, [2, 0, 0])):
+        monkeypatch.setattr(F, "PROBE_CAPACITY", probe_capacity)
+        e = QueryEngine()
+        e.hint_store = None
+        e.register_table("fact", fact)
+        e.register_table("dim", dim)
+        got = []
+        for _ in range(3):
+            e.result_cache.clear()
+            with tracing.counter_delta() as d:
+                t = e.execute(GROUPED)
+            got.append(d.get("jit.miss"))
+        comp = F.FusedCompiler(e._executor())
+        comp.compile(e.plan(GROUPED))
+        assert got == misses
+        compacted = [fp for fp in comp.fps if fp[0] == "acompact"]
+        assert bool(compacted) == (probe_capacity == default)
+        assert comp.wide == (probe_capacity != default)
+        answers.append(t.to_pydict())
+    assert answers[0] == answers[1] and len(answers[0]["g"]) == 100
