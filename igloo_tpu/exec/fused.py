@@ -70,8 +70,8 @@ from igloo_tpu.exec.expr_compile import (
     ConstPool, Env, ExprCompiler, rank_lane,
 )
 from igloo_tpu.exec.join import (
-    choose_direct_build, direct_join_phase, direct_probe, expand_phase,
-    make_key_hash_idxs, match_by_search, probe_phase,
+    choose_direct_build, direct_bitmap_probe, direct_join_phase, direct_probe,
+    expand_phase, make_key_hash_idxs, match_by_search, probe_phase,
 )
 from igloo_tpu.exec.sort_limit import (
     limit_batch, plan_topk, sort_batch, topk_batch,
@@ -511,14 +511,25 @@ class FusedCompiler:
                        (side, blo, tsize, ki, want, plan.schema),
                        hint_fp=("join_direct",) + jfp_core[1:] +
                        (plan.schema,))
+            # the full-width pass needs only whether a row matches: with
+            # one key and no residual it reads the table's occupancy bits
+            # (direct_bitmap_probe), and the row ids only after the
+            # compaction, at the hinted width
+            bitmap = not extra and residual is None
+            if bitmap:
+                tracing.counter("join.bitmap_probes")
 
             def fn(leaves, consts, ctx):
                 lb = lfn(leaves, consts, ctx)
                 rb = rfn(leaves, consts, ctx)
                 pb, bb = (rb, lb) if swapped else (lb, rb)
-                ok, bidx, dup = direct_probe(pb, bb, pkey, bkey, blo,
-                                             tsize, swapped, residual,
-                                             consts, extra)
+                if bitmap:
+                    ok, table, pslot, dup = direct_bitmap_probe(
+                        pb, bb, pkey, bkey, blo, tsize, consts)
+                else:
+                    ok, bidx, dup = direct_probe(pb, bb, pkey, bkey, blo,
+                                                 tsize, swapped, residual,
+                                                 consts, extra)
                 ctx.flags[fid] = dup
                 n = jnp.sum(ok.astype(jnp.int64))
                 ctx.stats[sid] = n
@@ -527,7 +538,11 @@ class FusedCompiler:
                 live = jnp.take(ok, perm)
                 p_cols = [replace(c.map_rows(lambda a: jnp.take(a, perm)),
                                   dictionary=None) for c in pb.columns]
-                nbidx = jnp.clip(jnp.take(bidx, perm), 0, bb.capacity - 1)
+                if bitmap:
+                    bidx = jnp.take(table, jnp.take(pslot, perm))
+                else:
+                    bidx = jnp.take(bidx, perm)
+                nbidx = jnp.clip(bidx, 0, bb.capacity - 1)
                 b_cols = K.gather_batch(bb, nbidx)
                 l_cols, r_cols = (b_cols, p_cols) if swapped \
                     else (p_cols, b_cols)

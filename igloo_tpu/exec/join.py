@@ -492,7 +492,8 @@ def choose_match_capacity(total: int) -> int:
 
 # what a positional table costs per slot: one int32 build-row id (direct_probe
 # allocates the table and nothing else of its size: the duplicate check is a
-# sum over `table >= 0`, one reduction that keeps no array)
+# sum over `table >= 0`, one reduction that keeps no array; the occupancy bits
+# direct_bitmap_probe reads are 1/32 of it)
 DIRECT_SLOT_BYTES = 4
 # where the backend reports no memory limit (XLA:CPU): the fixed widest table
 # the engine had before the limit was derived (64 MiB), so no CPU pick changes
@@ -590,27 +591,9 @@ def direct_probe(probe: DeviceBatch, build: DeviceBatch,
     `dup` is a device bool: True iff two valid build rows shared a slot
     (result must be discarded and the plan re-run on the exact path)."""
     bcap = build.capacity
-    bkey, bnull = build_key.fn(Env.from_batch(build, consts))
-    valid_b = build.live if bnull is None else (build.live & ~bnull)
-    slot = bkey.astype(jnp.int64) - lo
-    in_rng = (slot >= 0) & (slot < table_size)
-    valid_b = valid_b & in_rng
-    # invalid rows displace to the out-of-bounds slot -> dropped by the scatter
-    slot = jnp.where(valid_b, slot, table_size).astype(jnp.int32)
-    row_ids = jnp.arange(bcap, dtype=jnp.int32)
-    table = jnp.full((table_size,), -1, jnp.int32).at[slot].max(
-        row_ids, mode="drop")
-    # duplicate build keys: two rows target one slot -> fewer filled slots
-    # than valid rows. One O(table_size) reduction, no second scatter.
-    dup = jnp.sum((table >= 0).astype(jnp.int64)) < \
-        jnp.sum(valid_b.astype(jnp.int64))
-
-    pkey, pnull = probe_key.fn(Env.from_batch(probe, consts))
-    pslot = pkey.astype(jnp.int64) - lo
-    p_ok = (pslot >= 0) & (pslot < table_size) & probe.live
-    if pnull is not None:
-        p_ok = p_ok & ~pnull
-    bidx = jnp.take(table, jnp.clip(pslot, 0, table_size - 1).astype(jnp.int32))
+    table, dup = _direct_table(build, build_key, lo, table_size, consts)
+    p_ok, pslot = _probe_slots(probe, probe_key, lo, table_size, consts)
+    bidx = jnp.take(table, pslot)
     ok = p_ok & (bidx >= 0)
     safe_bidx = jnp.clip(bidx, 0, bcap - 1)
     ok = verify_extra_keys(ok, probe, build, safe_bidx, extra_keys, consts)
@@ -624,6 +607,83 @@ def direct_probe(probe: DeviceBatch, build: DeviceBatch,
         rv, rn = residual.fn(env)
         ok = ok & rv & (~rn if rn is not None else True)
     return ok, safe_bidx, dup
+
+
+def _direct_table(build: DeviceBatch, build_key: Compiled, lo: int,
+                  table_size: int, consts: tuple):
+    """The positional table (one scatter of build row ids at key - lo; -1
+    where no row lands) and the duplicate flag."""
+    bkey, bnull = build_key.fn(Env.from_batch(build, consts))
+    valid_b = build.live if bnull is None else (build.live & ~bnull)
+    slot = bkey.astype(jnp.int64) - lo
+    in_rng = (slot >= 0) & (slot < table_size)
+    valid_b = valid_b & in_rng
+    # invalid rows displace to the out-of-bounds slot -> dropped by the scatter
+    slot = jnp.where(valid_b, slot, table_size).astype(jnp.int32)
+    row_ids = jnp.arange(build.capacity, dtype=jnp.int32)
+    table = jnp.full((table_size,), -1, jnp.int32).at[slot].max(
+        row_ids, mode="drop")
+    # duplicate build keys: two rows target one slot -> fewer filled slots
+    # than valid rows. One O(table_size) reduction, no second scatter.
+    dup = jnp.sum((table >= 0).astype(jnp.int64)) < \
+        jnp.sum(valid_b.astype(jnp.int64))
+    return table, dup
+
+
+def _probe_slots(probe: DeviceBatch, probe_key: Compiled, lo: int,
+                 table_size: int, consts: tuple):
+    """(p_ok, slot): whether a probe row's key can match (live, not null,
+    inside the table) and its slot, clipped into the table, as int32."""
+    pkey, pnull = probe_key.fn(Env.from_batch(probe, consts))
+    pslot = pkey.astype(jnp.int64) - lo
+    p_ok = (pslot >= 0) & (pslot < table_size) & probe.live
+    if pnull is not None:
+        p_ok = p_ok & ~pnull
+    return p_ok, jnp.clip(pslot, 0, table_size - 1).astype(jnp.int32)
+
+
+def occupancy_words(table_size: int) -> int:
+    """Words of a positional table's occupancy bits: 32 slots a word,
+    rounded up to a power of two so that a slot's word and bit are a mask
+    and a shift."""
+    return 1 << max((table_size - 1).bit_length() - 5, 0)
+
+
+def occupancy_bits(table: jax.Array) -> jax.Array:
+    """Pack `table >= 0` into u32 words: slot s is bit s // W of word
+    s % W (W = occupancy_words). Packing then ORs 32 contiguous slices,
+    one pass over the table in one fusion; a word of 32 CONSECUTIVE slots
+    makes the TPU compiler relayout the table to 32-wide rows padded to
+    128 lanes (four times its size in temporaries)."""
+    words = occupancy_words(table.shape[0])
+    if table.shape[0] < 32 * words:
+        table = jnp.pad(table, (0, 32 * words - table.shape[0]),
+                        constant_values=-1)
+    bits = jnp.zeros((words,), jnp.uint32)
+    for b in range(32):
+        occ = table[b * words:(b + 1) * words] >= 0
+        bits = bits | (occ.astype(jnp.uint32) << b)
+    return bits
+
+
+def direct_bitmap_probe(probe: DeviceBatch, build: DeviceBatch,
+                        probe_key: Compiled, build_key: Compiled,
+                        lo: int, table_size: int, consts: tuple):
+    """`direct_probe` for a single-key join without a residual, for callers
+    that need the row ids only at a narrower width: the match mask is read
+    from the table's occupancy bits, not from the table. The bits of a
+    2^27-slot table are 16 MiB, which the TPU compiler keeps in the core's
+    own memory, where the table (537 MB) is read from HBM: 8.7 ns a probe
+    row against 25.7 on a v5e (PERF.md §6, step 0). Returns (ok, table, slot,
+    dup): `ok` is direct_probe's; a row's build row id is
+    `table[slot]`, read after the caller has narrowed `slot`."""
+    table, dup = _direct_table(build, build_key, lo, table_size, consts)
+    p_ok, pslot = _probe_slots(probe, probe_key, lo, table_size, consts)
+    words = occupancy_words(table_size)
+    shift = words.bit_length() - 1
+    word = jnp.take(occupancy_bits(table), pslot & (words - 1))
+    hit = (word >> (pslot >> shift).astype(jnp.uint32)) & 1
+    return p_ok & (hit != 0), table, pslot, dup
 
 
 def direct_join_phase(probe: DeviceBatch, build: DeviceBatch,

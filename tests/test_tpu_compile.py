@@ -345,3 +345,55 @@ def test_two_parameter_sets_lower_to_one_program(one_chip, q):
     # the dates, as the days since the epoch a constant would hold
     for days in {"q1": ("10471", "10493"), "q6": ("8766", "9496")}[q]:
         assert days not in t0
+
+
+# --- the lazy join's full-width probe reads a bit table (~20 s) ------------
+
+def test_bit_table_probe_at_sf10_widths_gathers_from_the_core(one_chip):
+    """The orders join of TPC-H q3 at SF10 over the spec's keys: 2^26 probe
+    lanes against a 2^27-slot positional table built from 2^24 build rows.
+    `direct_bitmap_probe` packs the table into 2^22 u32 words in one fusion
+    that the compiler places in the core's own memory (`S(1)`), and the
+    2^26-lane gather reads those words: no fusion gathers s32 lanes at
+    2^26 from the table, which is what cost 25.7 ns a lane against 8.7 from
+    a 16 MiB table on a v5e (PERF.md §6, step 0). `direct_probe`, compiled
+    beside it, writes those row ids: 256 MiB more of temporaries."""
+    from igloo_tpu import types as T
+    from igloo_tpu.exec.batch import DeviceBatch, DeviceColumn
+    from igloo_tpu.exec.expr_compile import Compiled
+    from igloo_tpu.exec.join import direct_bitmap_probe, direct_probe
+
+    probe_lanes, build_lanes, tsize = 1 << 26, 1 << 24, 1 << 27
+    schema = T.Schema([T.Field("k", T.INT64, False)])
+    key = Compiled(fn=lambda env: (env.values[0], None), dtype=T.INT64)
+
+    def batches(bkeys, blive, pkeys, plive):
+        return (DeviceBatch(schema, [DeviceColumn(T.INT64, pkeys, None)],
+                            plive),
+                DeviceBatch(schema, [DeviceColumn(T.INT64, bkeys, None)],
+                            blive))
+
+    def bits(*lanes):
+        ok, _table, _slot, dup = direct_bitmap_probe(*batches(*lanes), key,
+                                                     key, 0, tsize, ())
+        return ok, dup
+
+    def table(*lanes):
+        ok, _bidx, dup = direct_probe(*batches(*lanes), key, key, 0, tsize,
+                                      False, None, ())
+        return ok, dup
+
+    shapes = [((build_lanes,), jnp.int64), ((build_lanes,), jnp.bool_),
+              ((probe_lanes,), jnp.int64), ((probe_lanes,), jnp.bool_)]
+    c = _lower_and_compile(bits, shapes, one_chip)
+    entry = c.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    gathers = re.findall(r"= (\w+)\[%d\]\S* fusion\(.*kind=kCustom"
+                         % probe_lanes, entry)
+    assert gathers == ["u32"], gathers
+    packed = re.findall(r"= u32\[%d\]\{[^}]*\} fusion\(" % (tsize // 32),
+                        entry)
+    assert packed and all("S(1)" in p for p in packed), packed
+    parent = _lower_and_compile(table, shapes, one_chip)
+    assert c.memory_analysis().temp_size_in_bytes <= \
+        parent.memory_analysis().temp_size_in_bytes - probe_lanes * 4
