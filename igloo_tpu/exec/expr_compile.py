@@ -16,7 +16,6 @@ expressions therefore carry their output `DictInfo` statically (`Compiled.out_di
 """
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -275,7 +274,11 @@ def _gather_const(ids, lut):
     return jnp.take(lut, jnp.clip(ids, 0, lut.shape[0] - 1))
 
 
-def _like_to_regex(pattern: str) -> re.Pattern:
+def _like_to_re2(pattern: str, case_insensitive: bool) -> str:
+    """A LIKE pattern as an RE2 expression over the whole string: `%` any
+    run of characters (newlines too), `_` one character, every other
+    character itself (`\\x{..}`, whatever RE2 would make of it); ILIKE
+    folds case."""
     out = []
     for ch in pattern:
         if ch == "%":
@@ -283,8 +286,43 @@ def _like_to_regex(pattern: str) -> re.Pattern:
         elif ch == "_":
             out.append(".")
         else:
-            out.append(re.escape(ch))
-    return re.compile("^" + "".join(out) + "$", flags=re.DOTALL)
+            out.append(ch if ch.isascii() and ch.isalnum()
+                       else f"\\x{{{ord(ch):x}}}")
+    return ("(?si)" if case_insensitive else "(?s)") + "\\A" + \
+        "".join(out) + "\\z"
+
+
+def like_match(values, pattern: str, case_insensitive: bool) -> np.ndarray:
+    """bool[len(values)]: each string's SQL LIKE verdict, matched by Arrow's
+    RE2 over the whole array at once; a value that is not a string is
+    matched as its text. The one LIKE matcher of both tiers."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+    try:
+        arr = pa.array(values, type=pa.string())
+    except (pa.ArrowInvalid, pa.ArrowTypeError):
+        arr = pa.array([str(v) for v in values], type=pa.string())
+    return pc.match_substring_regex(
+        arr, _like_to_re2(pattern, case_insensitive)).fill_null(
+            False).to_numpy(zero_copy_only=False)
+
+
+def like_lut(d: DictInfo, pattern: str, case_insensitive: bool) -> np.ndarray:
+    """bool[len(d)]: each dictionary entry's LIKE verdict, memoized on the
+    DictInfo: a resident column's dictionary is the same object on every
+    plan walk, so the table is matched once and, being the same array,
+    padded and uploaded once by ConstPool's memos. A dictionary can hold an
+    entry a row (TPC-H's O_COMMENT: 15 M at SF10), so matching it again on
+    each walk would cost seconds a query."""
+    memo = getattr(d, "_like_verdicts", None)
+    if memo is None:
+        memo = {}
+        object.__setattr__(d, "_like_verdicts", memo)
+    key = (pattern, case_insensitive)
+    lut = memo.get(key)
+    if lut is None:
+        lut = memo[key] = like_match(d.values, pattern, case_insensitive)
+    return lut
 
 
 # --- date math (civil calendar <-> days since 1970-01-01; vectorized, int ops only,
@@ -726,13 +764,10 @@ class ExprCompiler:
         c = self.compile(e.operand)
         if not c.dtype.is_string:
             raise ExprCompileError("LIKE on non-string")
-        rx = _like_to_regex(e.pattern.lower() if e.case_insensitive else e.pattern)
         tracing.counter("program.literal_keyed")  # the pattern
         d = c.out_dict
-        lut = np.zeros(max(len(d) if d else 0, 1), dtype=bool)
-        for i, v in enumerate(d.values if d else []):
-            s = str(v).lower() if e.case_insensitive else str(v)
-            lut[i] = rx.match(s) is not None
+        lut = like_lut(d, e.pattern, e.case_insensitive) if d \
+            else np.zeros(1, dtype=bool)
         neg = e.negated
         lj = self.pool.add(lut)
 

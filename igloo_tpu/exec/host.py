@@ -26,7 +26,6 @@ division, date lanes in days / timestamps in microseconds.
 """
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass, replace
 from typing import Callable, Optional
 
@@ -36,6 +35,7 @@ import pyarrow as pa
 from igloo_tpu import types as T
 from igloo_tpu.errors import ExecError, PlanError
 from igloo_tpu.exec.batch import DictInfo, host_decode_column
+from igloo_tpu.exec.expr_compile import like_lut, like_match
 from igloo_tpu.plan import expr as E
 from igloo_tpu.plan import logical as L
 from igloo_tpu.sql.ast import JoinType
@@ -90,51 +90,6 @@ def _materialize_str(c: HCol) -> np.ndarray:
     if c.dict is None or len(c.dict) == 0:
         return np.full(len(c.values), "", dtype=object).astype(str)
     return c.dict.values.astype(str)[np.clip(c.values, 0, len(c.dict) - 1)]
-
-
-_LIKE_CACHE: dict = {}
-
-
-def _like_regex(pattern: str, case_insensitive: bool):
-    key = (pattern, case_insensitive)
-    rx = _LIKE_CACHE.get(key)
-    if rx is None:
-        parts = []
-        for ch in pattern:
-            if ch == "%":
-                parts.append(".*")
-            elif ch == "_":
-                parts.append(".")
-            else:
-                parts.append(re.escape(ch))
-        rx = re.compile("^" + "".join(parts) + "$",
-                        re.IGNORECASE if case_insensitive else 0)
-        _LIKE_CACHE[key] = rx
-    return rx
-
-
-def _vector_match(sv: np.ndarray, pattern: str, ci: bool) -> np.ndarray:
-    """Vectorized LIKE over string values (pandas' C matcher; a python re
-    loop over a TPC-H comment column is ~10x slower)."""
-    import pandas as pd
-    rx = _like_regex(pattern, ci)
-    return pd.Series(sv).str.match(rx).to_numpy(dtype=bool)
-
-
-def _like_lut(d: DictInfo, pattern: str, ci: bool) -> np.ndarray:
-    """Per-dictionary-entry LIKE results, memoized on the DictInfo object:
-    with the host scan cache holding dictionaries across queries, a repeated
-    filter costs one gather instead of a match over every entry."""
-    cache = getattr(d, "_like_luts", None)
-    if cache is None:
-        cache = {}
-        object.__setattr__(d, "_like_luts", cache)
-    key = (pattern, ci)
-    lut = cache.get(key)
-    if lut is None:
-        lut = _vector_match(d.values.astype(str), pattern, ci)
-        cache[key] = lut
-    return lut
 
 
 def _civil_from_days(days: np.ndarray):
@@ -1093,12 +1048,12 @@ class HostExecutor:
     def _e_like(self, e: E.Like, b):
         c = self._eval(e.operand, b)
         if c.dict is not None:
-            lut = _like_lut(c.dict, e.pattern, e.case_insensitive)
+            lut = like_lut(c.dict, e.pattern, e.case_insensitive)
             out = lut[np.clip(c.values, 0, max(len(c.dict) - 1, 0))] \
                 if len(c.dict) else np.zeros(b.n, dtype=bool)
         else:
-            out = _vector_match(_materialize_str(c), e.pattern,
-                                e.case_insensitive)
+            out = like_match(_materialize_str(c), e.pattern,
+                             e.case_insensitive)
         if e.negated:
             out = ~out
         return HCol(T.BOOL, out, c.nulls)
