@@ -485,6 +485,21 @@ def uncompacted_filter(plan: L.Aggregate) -> Optional[L.Filter]:
     return node if isinstance(node, L.Filter) else None
 
 
+def agg_out_bounds(aggs: list, input_capacity: int) -> list:
+    """Host-known (lo, hi) value bounds of an aggregate's output columns, one
+    per aggregate (`plan.expr.Aggregate`s): a COUNT or COUNT(*) of a
+    non-DISTINCT aggregate counts at most every lane of its input, so
+    (0, input capacity); every other output None. A GROUP BY or ORDER BY
+    over such a count then chooses as it does for any bounded integer key
+    (seg_dims_for, kernels.plan_group_packing / plan_prefix_packing): TPC-H
+    q13's count of customers per order count sorts one packed lane. THE one
+    place this rule lives: the fused compiler (FusedCompiler._c_aggregate)
+    and the staged one (Executor._aggregate) both read it."""
+    return [(0, int(input_capacity))
+            if a.func in (AggFunc.COUNT, AggFunc.COUNT_STAR) and not a.distinct
+            else None for a in aggs]
+
+
 def _feasible_segments(seg_dims: tuple, gnulls: list) -> list:
     """The segment ids of _direct_aggregate that can hold a live row: every
     digit combination, less digit 0 (the NULL bucket) of a key that reached

@@ -29,8 +29,8 @@ from igloo_tpu import types as T
 from igloo_tpu.errors import ExecError, NotSupportedError, PlanError
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
-    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg,
-    pair_sums_for, seg_dims_for, uncompacted_filter,
+    AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
+    minmax_order_arg, pair_sums_for, seg_dims_for, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
@@ -878,7 +878,9 @@ class Executor:
                        else "packed_sort" if pack_spec is not None
                        else "lex_sort")
         out = attach_dicts(out, [g.out_dict for g in groups] +
-                           [s.out_dict for s in specs])
+                           [s.out_dict for s in specs],
+                           [None] * len(groups) +
+                           agg_out_bounds(aggs, batch.capacity))
         return self._maybe_shrink(out)
 
     def _exec_distinct_aggregate(self, plan: L.Aggregate,

@@ -60,8 +60,8 @@ import jax.numpy as jnp
 from igloo_tpu import types as T
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
-    AggSpec, aggregate_batch, distinct_batch, minmax_order_arg,
-    pair_sums_for, seg_dims_for, uncompacted_filter,
+    AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
+    minmax_order_arg, pair_sums_for, seg_dims_for, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     MIN_CAPACITY, DeviceBatch, DeviceColumn, round_capacity,
@@ -643,7 +643,7 @@ class FusedCompiler:
                             [g.out_dict for g in groups] +
                             [s.out_dict for s in specs],
                             [g.out_bounds for g in groups] +
-                            [None] * len(specs), cap)
+                            agg_out_bounds(plan.aggs, meta.capacity), cap)
         return fn, out_meta
 
     def _c_distinct(self, plan: L.Distinct):

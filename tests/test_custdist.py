@@ -8,9 +8,11 @@ that equals the benchmark's pandas reference (`benchmark/oracle/
 tpch_pandas.py q13`); the customers without orders (every custkey that is a
 multiple of 3, clause 4.2) are in its `c_count = 0` row; an INNER join's
 answer is refused by the same comparison. Each plan walk counts the
-positional join (`join.direct_routes`) and the count per customer's scatter
-(`agg.direct_scatter`) once, and matches the LIKE pattern against the
-comment's dictionary only on the first."""
+positional join (`join.direct_routes`), the count per customer's scatter
+(`agg.direct_scatter`), the second GROUP BY's packed lane (`pack.agg`: the
+count carries the bound its input's capacity gives it) and the ORDER BY's
+(`pack.sort`) once, and matches the LIKE pattern against the comment's
+dictionary only on the first. q1, q3 and q6 keep their own decisions."""
 import os
 
 import numpy as np
@@ -22,7 +24,8 @@ from igloo_tpu.utils import tracing
 from test_direct_table_budget import BENCH, bench_module
 
 TABLES = ("customer", "orders", "lineitem")
-COUNTERS = ("join.direct_routes", "agg.direct_scatter")
+COUNTERS = ("join.direct_routes", "agg.direct_scatter", "pack.agg",
+            "pack.sort")
 
 
 def query_text(name: str) -> str:
@@ -113,9 +116,13 @@ def test_an_inner_join_answer_is_refused(staged):
 
 
 @pytest.mark.parametrize("name,sf,counts", [
-    ("q13", 0.5, {"join.direct_routes": 1, "agg.direct_scatter": 1}),
-    ("q1", 0.05, {"agg.direct_scatter": 1}),
-    ("q3", 0.05, {"join.direct_routes": 2}),
+    ("q13", 0.5, {"join.direct_routes": 1, "agg.direct_scatter": 1,
+                  "pack.agg": 1, "pack.sort": 1}),
+    ("q1", 0.05, {"agg.direct_scatter": 1, "pack.sort": 1}),
+    ("q3", 0.05, {"join.direct_routes": 2, "pack.agg": 1}),
+    ("q13", 0.05, {"join.direct_routes": 1, "agg.direct_scatter": 1,
+                   "pack.agg": 1, "pack.sort": 1}),
+    ("q6", 0.05, {}),
 ])
 def test_counters_once_per_plan_walk(staged, name, sf, counts):
     eng = engine(staged(sf))
