@@ -9,8 +9,10 @@ sort-based segment reduction — one fused XLA computation, static shapes:
          -> segment_sum/min/max over static segment count (= capacity)
 
 Output capacity equals input capacity; row `i` of the output is group `i`
-(compacted to the front, `live` marks real groups). No hashing: grouping equality
-is exact lane comparison after the sort, so no collision handling is needed.
+(compacted to the front, `live` marks real groups; a direct-scatter aggregate
+wider than SMALL_NSEG segments leaves its groups at their segment ids:
+groups_in_place). No hashing: grouping equality is exact lane comparison
+after the sort, so no collision handling is needed.
 """
 from __future__ import annotations
 
@@ -485,6 +487,24 @@ def uncompacted_filter(plan: L.Aggregate) -> Optional[L.Filter]:
     return node if isinstance(node, L.Filter) else None
 
 
+def groups_in_place(seg_dims: Optional[tuple]) -> bool:
+    """Whether a direct-scatter aggregate over `seg_dims` (seg_dims_for's
+    verdict) leaves its groups where their segment ids put them, `live` its
+    group mask, instead of compacting them to the front: where its segment
+    space is wider than K.SMALL_NSEG, the width at which seg_reduce
+    scatters. There the compaction is a sort and a gather per column over
+    the whole space (TPC-H q13 at SF10 on a v5e: one sort and two gathers at
+    2^22 lanes, 67 ms of a 2.09 s query), and no consumer needs it: every
+    operator reads `live`, as it does over a Filter's lanes, and the
+    compaction was stable, so the live groups keep their order (segment-id
+    order). At or under the width it moves at most 64 lanes, and the
+    program keeps its form (TPC-H q1). A function of `seg_dims`, which every
+    caller's program key holds already; the executors ask it once per
+    aggregate of a plan walk for the counter `agg.groups_in_place`."""
+    return seg_dims is not None and \
+        _segment_space(seg_dims)[1] > K.SMALL_NSEG
+
+
 def agg_out_bounds(aggs: list, input_capacity: int) -> list:
     """Host-known (lo, hi) value bounds of an aggregate's output columns, one
     per aggregate (`plan.expr.Aggregate`s): a COUNT or COUNT(*) of a
@@ -532,7 +552,11 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
     With `pair_sums` (pair_sums_for) a float64 sum lane is folded as its two
     f32 halves: those of the column itself where the argument is a bare
     column resident in a form that holds them (batch.f32_halves: no decode,
-    no split), else split in-trace from the computed float64 value."""
+    no split), else split in-trace from the computed float64 value.
+
+    Above SMALL_NSEG segments the groups stay where their segment ids put
+    them, live where a row reached them (groups_in_place); at or under it
+    they are compacted to the front."""
     cap = live.shape[0]
     prod, nseg = _segment_space(seg_dims)
     dead = nseg - 1  # dead rows land here; >= prod, never a real key combo
@@ -663,6 +687,8 @@ def _direct_aggregate(env: Env, groups: list[Compiled], gvals, gnulls,
         else:
             out_cols.append(_sum_column(spec, found[lkey], n_valid, all_null))
 
+    if groups_in_place(seg_dims):
+        return DeviceBatch(out_schema, out_cols, group_mask)
     # compact live groups to the front (segment-id order = NULL-first
     # dictionary-rank order); aggregate output row order is not semantic
     perm_small = K.compact_perm(group_mask)

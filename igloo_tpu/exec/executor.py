@@ -30,7 +30,8 @@ from igloo_tpu.errors import ExecError, NotSupportedError, PlanError
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
-    minmax_order_arg, pair_sums_for, seg_dims_for, uncompacted_filter,
+    groups_in_place, minmax_order_arg, pair_sums_for, seg_dims_for,
+    uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
@@ -860,6 +861,8 @@ class Executor:
             if pack_spec is not None:
                 tracing.counter("pack.agg")
         pair_sums = pair_sums_for(seg_dims, specs)
+        if groups_in_place(seg_dims):
+            tracing.counter("agg.groups_in_place")
         fp = ("agg", expr_fingerprint(gres + ares),
               tuple((a.func, a.dtype) for a in aggs),
               batch_proto_key(batch), out_schema,

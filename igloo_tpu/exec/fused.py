@@ -61,7 +61,8 @@ from igloo_tpu import types as T
 from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
-    minmax_order_arg, pair_sums_for, seg_dims_for, uncompacted_filter,
+    groups_in_place, minmax_order_arg, pair_sums_for, seg_dims_for,
+    uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     MIN_CAPACITY, DeviceBatch, DeviceColumn, round_capacity,
@@ -619,6 +620,8 @@ class FusedCompiler:
             if pack_spec is not None:
                 tracing.counter("pack.agg")
         pair_sums = pair_sums_for(seg_dims, specs)
+        if groups_in_place(seg_dims):
+            tracing.counter("agg.groups_in_place")
         fp = ("agg", E.shape(gres + ares),
               tuple((a.func, a.dtype) for a in plan.aggs),
               plan.schema, seg_dims, pack_spec) + \

@@ -534,9 +534,13 @@ class ShardedExecutor(Executor):
                 final_fields.append(T.Field(f"f{idx}", a.dtype, True))
         final_schema = T.Schema(final_fields)
 
-        from igloo_tpu.exec.aggregate import pair_sums_for, seg_dims_for
+        from igloo_tpu.exec.aggregate import (
+            groups_in_place, pair_sums_for, seg_dims_for)
         sdims = seg_dims_for(groups)
         fdims = seg_dims_for(final_groups)
+        for d in (sdims, fdims):
+            if groups_in_place(d):
+                tracing.counter("agg.groups_in_place")
         spair = pair_sums_for(sdims, partial_specs)
         fpair = pair_sums_for(fdims, final_specs)
         local_cap = batch.capacity // n
