@@ -65,9 +65,6 @@ class SourceWatcher:
                 prev = self._seen.get(name)
                 if prev is not None and prev != tok:
                     self.engine.batch_cache.invalidate_table(name)
-                    host = getattr(self.engine, "host_cache", None)
-                    if host is not None:
-                        host.invalidate_table(name)
                     changed.append(name)
                 self._seen[name] = tok
             callbacks = list(self._callbacks)

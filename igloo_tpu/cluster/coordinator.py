@@ -1231,7 +1231,7 @@ class CoordinatorServer(flight.FlightServerBase):
                 if not _is_oom(ex):
                     raise
                 out = self._demote_ladder(sql, deadline, t_start,
-                                          permit.priority, level=1)
+                                          permit.priority)
         return (out.schema, iter(out.to_batches())) if stream else out
 
     def _try_oversized_distributed(self, plan, sql: str, stream: bool,
@@ -1315,11 +1315,11 @@ class CoordinatorServer(flight.FlightServerBase):
                      deadline: Optional[float], t_start: float,
                      permit: "serving.Permit"):
         """Entry for queries pre-flagged by the HBM gate: straight onto the
-        ladder's first rung."""
+        ladder."""
         with stats.serving_context(queue_wait_s=permit.wait_s,
                                    priority=permit.priority):
             out = self._demote_ladder(sql, deadline, t_start,
-                                      permit.priority, level=1)
+                                      permit.priority)
         # publish: a demoted query must overwrite last_metrics (clients —
         # and the kill-switch A/B — would otherwise read the PREVIOUS
         # query's oversized/fragment attribution as this one's)
@@ -1331,27 +1331,17 @@ class CoordinatorServer(flight.FlightServerBase):
         return (out.schema, iter(out.to_batches())) if stream else out
 
     def _demote_ladder(self, sql: str, deadline: Optional[float],
-                       t_start: float, priority: int, level: int):
-        """The graceful-degradation ladder: rung 1 re-runs locally with a
-        chunk budget constrained to the serving HBM budget (forcing the
-        chunked/GRACE out-of-core tiers); rung 2 forces the numpy host
-        tier. Each rung bumps `serving.demoted` + the query-log `demoted`
-        column; an OOM on the last rung surfaces."""
+                       t_start: float, priority: int):
+        """The graceful-degradation ladder's one rung: re-run locally with
+        a chunk budget constrained to the serving HBM budget (forcing the
+        chunked/GRACE out-of-core tiers). It bumps `serving.demoted` + the
+        query-log `demoted` column; an OOM there surfaces as the error it
+        is."""
         self._check_local_deadline(deadline, sql, t_start, priority)
         tracing.counter("serving.demoted")
-        events.emit("query_demoted", severity="warn", rung=level)
+        events.emit("query_demoted", severity="warn")
         stats.mark_demoted()
-        budget = self._demote_budget()
-        if level <= 1:
-            try:
-                with self.engine.demoted(budget_bytes=budget):
-                    return self.engine.execute(sql)
-            except Exception as ex:
-                if not _is_oom(ex):
-                    raise
-                return self._demote_ladder(sql, deadline, t_start, priority,
-                                           level=2)
-        with self.engine.demoted(budget_bytes=budget, force_host=True):
+        with self.engine.demoted(budget_bytes=self._demote_budget()):
             return self.engine.execute(sql)
 
     def _demote_budget(self) -> int:

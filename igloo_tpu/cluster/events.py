@@ -2,7 +2,7 @@
 
 One structured, bounded, process-wide journal turning the fleet's
 counters into a NARRATIVE: worker join/evict/recover, fragment
-re-dispatch and busy-requeue, admission shed, demotion rungs,
+re-dispatch and busy-requeue, admission shed, demotions,
 deadline/cancel, snapshot retry, corruption quarantine, compile-cache
 push/pull, salting/broadcast flips, slow-query escalations. Every event
 carries a wall timestamp, a severity, and — where applicable — the

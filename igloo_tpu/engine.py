@@ -12,7 +12,6 @@ The built-in `capitalize` UDF mirrors the reference's
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -98,11 +97,11 @@ class QueryEngine:
         # single-device; or an explicit jax.sharding.Mesh
         self._mesh_setting = mesh
         self._mesh = None
-        # per-THREAD demotion overrides (serving degradation ladder,
+        # per-THREAD demotion override (serving degradation ladder,
         # docs/serving.md): a constrained chunk budget forces the chunked/
-        # GRACE tiers, force_host the numpy tier — thread-local because the
-        # coordinator runs concurrent queries through ONE engine and only
-        # the demoted query must execute constrained
+        # GRACE tiers — thread-local because the coordinator runs
+        # concurrent queries through ONE engine and only the demoted query
+        # must execute constrained
         self._demote_tls = threading.local()
         # HBM batch cache: scan results stay device-resident across queries
         # (the real version of the reference's unenforced CacheConfig, gap G7)
@@ -117,18 +116,6 @@ class QueryEngine:
         # XLA compile cache, so a fresh process compiles hinted programs first)
         from igloo_tpu.exec.hints import default_store
         self.hint_store = default_store()
-        # plans whose scanned sources total under this many bytes execute on
-        # the host when the default device is an accelerator: the numpy
-        # executor (exec/host.py) when it supports the plan; XLA:CPU is NOT
-        # used (on small hosts its sort kernels lose to numpy by ~3x and its
-        # AOT cache entries must not mix with the TPU cache). 0 disables the
-        # fast path. Whether a few-MB query is faster on the host than on the
-        # current chip is not measured; ROADMAP A4/C1 decide the default and
-        # the tier on that number.
-        self.host_route_bytes = int(os.environ.get(
-            "IGLOO_HOST_ROUTE_BYTES", str(64 << 20)))
-        # decoded-column cache for the host tier (plain RAM, not HBM)
-        self.host_cache = BatchCache()
         # reference parity: capitalize registered at construction (lib.rs:41-42)
         self.register_udf(UdfDef("capitalize", T.STRING))
         # SQL-queryable telemetry: SELECT * FROM system.metrics /
@@ -145,13 +132,11 @@ class QueryEngine:
         # a replaced provider's id() can be reused by the allocator, so identity
         # tokens alone cannot be trusted across re-registration — evict eagerly
         self.batch_cache.invalidate_table(name.lower())
-        self.host_cache.invalidate_table(name.lower())
         self.result_cache.invalidate_table(name)
 
     def deregister_table(self, name: str) -> None:
         self.catalog.deregister(name)
         self.batch_cache.invalidate_table(name.lower())
-        self.host_cache.invalidate_table(name.lower())
         self.result_cache.invalidate_table(name)
 
     def register_udf(self, udf: UdfDef) -> None:
@@ -369,23 +354,19 @@ class QueryEngine:
         tracing.counter("adaptive.observed", len(obs))
 
     @contextlib.contextmanager
-    def demoted(self, budget_bytes: Optional[int] = None,
-                force_host: bool = False):
+    def demoted(self, budget_bytes: Optional[int] = None):
         """Run the enclosed executions on this thread one rung down the
         degradation ladder (docs/serving.md): a constrained `budget_bytes`
         makes `_execute_plan` route over-budget plans to the chunked/GRACE
-        tiers at THAT budget, `force_host` routes supported plans to the
-        numpy host tier regardless of backend. The serving front door uses
-        this when a query hits RESOURCE_EXHAUSTED/MemoryError (or is
-        predicted past the whole HBM budget) instead of failing it."""
-        prev = (getattr(self._demote_tls, "budget", None),
-                getattr(self._demote_tls, "force_host", False))
+        tiers at THAT budget. The serving front door uses this when a query
+        hits RESOURCE_EXHAUSTED/MemoryError (or is predicted past the whole
+        HBM budget) instead of failing it."""
+        prev = getattr(self._demote_tls, "budget", None)
         self._demote_tls.budget = budget_bytes
-        self._demote_tls.force_host = force_host
         try:
             yield
         finally:
-            self._demote_tls.budget, self._demote_tls.force_host = prev
+            self._demote_tls.budget = prev
 
     def _derived_shares(self) -> tuple:
         if self._shares is None:
@@ -438,26 +419,14 @@ class QueryEngine:
         return Executor(self._jit_cache, use_jit=self._use_jit,
                         batch_cache=self.batch_cache, hints=self.hint_store)
 
-    def _host_route(self, plan: L.LogicalPlan) -> bool:
-        """True when every scanned source is sized and the total is under
-        host_route_bytes while the default backend is an accelerator."""
-        if self.host_route_bytes <= 0:
-            return False
-        import jax
-        if jax.default_backend() == "cpu":
-            return False
-        from igloo_tpu.plan.optimizer import _est_scan_bytes
-        total = _est_scan_bytes(plan, include_subqueries=True)
-        return total is not None and total <= self.host_route_bytes
-
     def _execute_plan(self, plan: L.LogicalPlan) -> pa.Table:
         """The full routing ladder shared by _run_select and EXPLAIN ANALYZE:
-        host tier (small sources, accelerator backend) -> chunked tier
+        sharded executor (a resolved multi-chip mesh) -> chunked tier
         (decomposable aggregates over scans whose columns are priced over
         `_scan_budget`) -> GRACE tier (join trees with a table over
-        `_chunk_budget`, exec/grace.py) -> normal executor. A resolved multi-chip
-        mesh takes precedence over single-device chunking / out-of-core: the
-        sharded executor already bounds per-chip memory by row-sharding, and
+        `_chunk_budget`, exec/grace.py) -> device executor. The mesh takes
+        precedence over single-device chunking / out-of-core: the sharded
+        executor already bounds per-chip memory by row-sharding, and
         silently chunking would discard the parallelism."""
         from igloo_tpu.exec.chunked import LocalChunkExecutor, \
             chunk_count, scan_prices
@@ -466,27 +435,6 @@ class QueryEngine:
         prices = scan_prices(plan)
         tracing.counter("engine.route_priced_bytes",
                         sum(v or 0 for v in prices.values()))
-        force_host = getattr(self._demote_tls, "force_host", False)
-        if force_host or self._host_route(plan):
-            from igloo_tpu.exec.host import HostExecutor, HostUnsupported
-            try:
-                with span("execute"):
-                    table = HostExecutor(
-                        self.catalog,
-                        scan_cache=self.host_cache).execute_to_arrow(plan)
-                tracing.counter("engine.host_route")
-                if qs is not None:
-                    qs.tier = "host"
-                return table
-            except HostUnsupported as e:
-                tracing.counter("engine.host_route_unsupported")
-                tracing.counter(
-                    f"engine.host_route_unsupported.{e.args[0] if e.args else ''}")
-            except MemoryError:
-                # a host-tier allocation blowup (e.g. a grouped cardinality
-                # the direct-slot guards missed) must degrade to the device
-                # tier, not fail the query
-                tracing.counter("engine.host_route_oom")
         mesh = self._resolve_mesh()
         chunks = 0 if mesh is not None else \
             chunk_count(plan, self._scan_budget(), prices)
@@ -539,7 +487,6 @@ class QueryEngine:
                 ex)
             if ex.table:
                 self.batch_cache.invalidate_table(ex.table)
-                self.host_cache.invalidate_table(ex.table)
                 self.result_cache.invalidate_table(ex.table)
             plan = rebind()
             with storage_snapshot.pinned_scope():

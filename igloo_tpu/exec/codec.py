@@ -428,7 +428,7 @@ def rle_encode(arr: np.ndarray):
 
 def rle_decode(run_values: np.ndarray, run_starts: np.ndarray,
                n: int) -> np.ndarray:
-    """Host-side inverse of `rle_encode` (tests / host-tier consumers)."""
+    """Host-side inverse of `rle_encode`."""
     idx = np.searchsorted(run_starts, np.arange(n), side="right") - 1
     return run_values[idx]
 

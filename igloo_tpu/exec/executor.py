@@ -1033,8 +1033,8 @@ class Executor:
         if cap <= self._SPECULATIVE_JOIN_BUDGET or not self._speculate \
                 or self._use_jit is False:
             return batch
-        from igloo_tpu.exec.host import HostExecutor
-        fp = HostExecutor._plan_fp(plan_node)
+        from igloo_tpu.exec.hints import plan_fp
+        fp = plan_fp(plan_node, exact=True)
         if fp is None:
             # no stable hint key for this subtree (subqueries/window/union...):
             # carry the padded lanes rather than pay a num_live() device->host

@@ -295,7 +295,7 @@ def _like_to_re2(pattern: str, case_insensitive: bool) -> str:
 def like_match(values, pattern: str, case_insensitive: bool) -> np.ndarray:
     """bool[len(values)]: each string's SQL LIKE verdict, matched by Arrow's
     RE2 over the whole array at once; a value that is not a string is
-    matched as its text. The one LIKE matcher of both tiers."""
+    matched as its text."""
     import pyarrow as pa
     import pyarrow.compute as pc
     try:

@@ -281,16 +281,16 @@ def plan_fp(plan, exact: bool = False):
     """Projection-INSENSITIVE structural fingerprint of a logical subtree:
     expressions by column NAME (not index), scans by (table, filters,
     partition). The same logical work keys the same entry whether observed
-    pre- or post-pruning, on the host tier, the device tier, or a cluster
-    fragment. Returns None for shapes with no stable key (subqueries,
-    windows, unions...).
+    pre- or post-pruning, on the device tier or a cluster fragment. Returns
+    None for shapes with no stable key (subqueries, windows, unions...).
 
     Expressions enter in their SHAPE form (`plan.expr.shape`: a literal's
     type and position, not its value), as in every program and hint key:
     what AdaptiveStats, the watchtower's baselines and the staged tier's
     `slive` hints learnt under one parameter set of a query is found under
-    the next. `exact=True` keeps the values: the key of a RESULT, for the
-    host tier's structural memo alone."""
+    the next. `exact=True` keeps the values: it keys the staged tier's
+    live-count hint for a join input (exec/executor.py), whose rows depend
+    on them."""
     from igloo_tpu.plan import expr as E
     from igloo_tpu.plan import logical as L
 

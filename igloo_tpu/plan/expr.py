@@ -381,8 +381,8 @@ def fingerprint(e, by_name: bool = False) -> str:
     node which gains a field cannot make two expressions collide; what it
     cannot read field by field (a subquery's AST) equals nothing, not even
     itself on a second call. Keys a RESULT (which aggregate lanes are one,
-    the host tier's memo): the value of every literal is in it. `by_name`
-    as in `shape`."""
+    the staged tier's live-count hints): the value of every literal is in
+    it. `by_name` as in `shape`."""
     out: list = []
     _spell(e, False, by_name, False, out)
     return "".join(out)

@@ -48,12 +48,9 @@ _SEMI_BUILD_MAX_BYTES = 64 << 20
 _SEMI_INPUT_MIN_BYTES = 64 << 20
 
 
-def _est_scan_bytes(p: L.LogicalPlan, include_subqueries: bool = False
-                    ) -> Optional[int]:
+def _est_scan_bytes(p: L.LogicalPlan) -> Optional[int]:
     """Total estimated source bytes under `p`; None when any scan is
-    unsized. With `include_subqueries`, plans embedded in expression
-    subqueries count too (the engine's host-routing cap uses this: a tiny
-    outer query over a subquery on a huge table must not land on the host)."""
+    unsized."""
     from igloo_tpu.exec.chunked import estimated_bytes
     total = 0
     for n in L.walk_plan(p):
@@ -64,19 +61,6 @@ def _est_scan_bytes(p: L.LogicalPlan, include_subqueries: bool = False
             if nb is None:
                 return None
             total += nb
-        if not include_subqueries:
-            continue
-        for e in _node_exprs(n):
-            stack = [e]
-            while stack:
-                x = stack.pop()
-                sub = getattr(x, "query", None)
-                if isinstance(sub, L.LogicalPlan):
-                    st = _est_scan_bytes(sub, include_subqueries=True)
-                    if st is None:
-                        return None
-                    total += st
-                stack.extend(x.children())
     return total
 
 

@@ -76,7 +76,7 @@ class QueryStats:
     sql: str = ""
     started_at: float = 0.0            # unix seconds
     elapsed_s: float = 0.0
-    tier: str = "device"               # host|chunked|grace|device|sharded|...
+    tier: str = "device"               # chunked|grace|device|sharded|...
     rows: Optional[int] = None
     compile_s: float = 0.0
     h2d_bytes: int = 0
@@ -89,9 +89,9 @@ class QueryStats:
     # (non-ok values come from the distributed tier's deadline/cancel paths)
     status: str = "ok"
     # serving-path fields (coordinator front door, docs/serving.md): how long
-    # the query waited in the admission queue, its priority tier, and how
-    # many rungs of the degradation ladder it was demoted down (0 = ran at
-    # its planned tier)
+    # the query waited in the admission queue, its priority tier, and
+    # whether the degradation ladder demoted it (0 = ran at its planned
+    # tier)
     queue_wait_s: float = 0.0
     priority: int = 1
     demoted: int = 0
@@ -100,7 +100,7 @@ class QueryStats:
     # ("" when the recorder was off)
     trace_id: str = ""
     # (fingerprint key, observed rows) pairs recorded where a row count was
-    # free or already paid for (host tier, detail-mode syncs, first-sight
+    # free or already paid for (detail-mode syncs, first-sight
     # adaptive-input syncs); the engine folds them into the process-wide
     # AdaptiveStats store at query end (exec/hints.py, docs/adaptive.md)
     observations: list = field(default_factory=list)

@@ -265,3 +265,126 @@ def test_random_query_vs_pandas(engine):
     np.testing.assert_allclose(out.column("sx").to_pylist(), want["sx"], rtol=1e-9)
     assert out.column("mn").to_pylist() == want["mn"].tolist()
     assert out.column("mx").to_pylist() == want["mx"].tolist()
+
+
+@pytest.fixture
+def corners():
+    e = QueryEngine()
+    e.register_table("t", pa.table({
+        "a": pa.array([1, 2, None, 4, 5], type=pa.int64()),
+        "b": pa.array([1.5, None, 2.5, 2.5, 0.0]),
+        "s": pa.array(["x", "y", None, "x", "z"]),
+    }))
+    e.register_table("u", pa.table({
+        "k": pa.array([1, 2, 2, 6], type=pa.int64()),
+        "v": pa.array(["p", "q", "r", "s"]),
+    }))
+    return e
+
+
+def assert_rows(got: pa.Table, names: list, rows: list, ordered: bool,
+                label: str = "") -> None:
+    """`got` holds exactly `rows` under `names`: in order where `ordered`,
+    else as a multiset; floats to rel=1e-9."""
+    assert got.column_names == names, label
+    grows = list(zip(*got.to_pydict().values()))
+    wrows = list(rows)
+    if not ordered:
+        grows, wrows = sorted(grows, key=repr), sorted(wrows, key=repr)
+    assert len(grows) == len(wrows), f"{label}: {grows} != {wrows}"
+    for i, (g, w) in enumerate(zip(grows, wrows)):
+        for gv, wv, name in zip(g, w, names):
+            if isinstance(wv, float) and gv is not None:
+                assert gv == pytest.approx(wv, rel=1e-9), \
+                    f"{label} row {i} col {name}: {gv} != {wv}"
+            else:
+                assert gv == wv, f"{label} row {i} col {name}: {gv} != {wv}"
+
+
+# t: (a, b, s) = (1, 1.5, x) (2, NULL, y) (NULL, 2.5, NULL) (4, 2.5, x)
+# (5, 0.0, z); u: (k, v) = (1, p) (2, q) (2, r) (6, s). Each answer is SQL's,
+# worked by hand: a NULL predicate keeps no row (three-valued logic), NULL
+# keys join nothing and group together, an outer join pads with NULLs, a
+# DESC key puts NULLs first and an ASC key last; x / 0 is NULL here (as in
+# MySQL and SQLite), not an error.
+SEMANTICS_CORNERS = [
+    ("SELECT a, b FROM t WHERE a > 1 AND b > 1.0",
+     ["a", "b"], [(4, 2.5)], False),
+    ("SELECT a FROM t WHERE NOT (b > 2.0)",                    # 3VL NOT
+     ["a"], [(1,), (5,)], False),
+    ("SELECT a FROM t WHERE b > 2.0 OR a > 3",                 # Kleene OR
+     ["a"], [(None,), (4,), (5,)], False),
+    ("SELECT a FROM t WHERE s IS NOT NULL",
+     ["a"], [(1,), (2,), (4,), (5,)], False),
+    ("SELECT a / 0 AS z, a % 2 AS m FROM t",                   # div by 0
+     ["z", "m"], [(None, 1), (None, 0), (None, None), (None, 0), (None, 1)],
+     False),
+    ("SELECT s, count(*) AS n, sum(a) AS sa FROM t GROUP BY s",
+     ["s", "n", "sa"],
+     [("x", 2, 5), ("y", 1, 2), (None, 1, None), ("z", 1, 5)], False),
+    ("SELECT count(DISTINCT s) AS d FROM t", ["d"], [(3,)], False),
+    ("SELECT min(b) AS mn, max(b) AS mx, avg(a) AS av FROM t",
+     ["mn", "mx", "av"], [(0.0, 2.5, 3.0)], False),
+    ("SELECT DISTINCT s FROM t",
+     ["s"], [("x",), ("y",), (None,), ("z",)], False),
+    ("SELECT a, s FROM t ORDER BY s DESC, a ASC",
+     ["a", "s"], [(None, None), (5, "z"), (2, "y"), (1, "x"), (4, "x")],
+     True),
+    ("SELECT a FROM t ORDER BY b NULLS FIRST, a NULLS FIRST",
+     ["a"], [(2,), (5,), (1,), (None,), (4,)], True),
+    ("SELECT t.a, u.v FROM t JOIN u ON t.a = u.k",
+     ["a", "v"], [(1, "p"), (2, "q"), (2, "r")], False),
+    ("SELECT t.a, u.v FROM t LEFT JOIN u ON t.a = u.k",
+     ["a", "v"], [(1, "p"), (2, "q"), (2, "r"), (None, None), (4, None),
+                  (5, None)], False),
+    ("SELECT u.k, t.a FROM t RIGHT JOIN u ON t.a = u.k",
+     ["k", "a"], [(1, 1), (2, 2), (2, 2), (6, None)], False),
+    ("SELECT t.a, u.v FROM t FULL JOIN u ON t.a = u.k",
+     ["a", "v"], [(1, "p"), (2, "q"), (2, "r"), (None, None), (4, None),
+                  (5, None), (None, "s")], False),
+    ("SELECT upper(s) AS us, length(s) AS ls FROM t",
+     ["us", "ls"], [("X", 1), ("Y", 1), (None, None), ("X", 1), ("Z", 1)],
+     False),
+    ("SELECT substr(s, 1, 1) AS c1 FROM t",
+     ["c1"], [("x",), ("y",), (None,), ("x",), ("z",)], False),
+    ("SELECT a FROM t WHERE s LIKE 'x%'", ["a"], [(1,), (4,)], False),
+    ("SELECT a FROM t WHERE s IN ('x', 'z')",
+     ["a"], [(1,), (4,), (5,)], False),
+    ("SELECT a FROM t WHERE a IN (1, 4)", ["a"], [(1,), (4,)], False),
+    ("SELECT CASE WHEN a > 2 THEN a ELSE 0 END AS c FROM t",
+     ["c"], [(0,), (0,), (0,), (4,), (5,)], False),
+    ("SELECT a FROM t WHERE a > (SELECT min(k) FROM u)",
+     ["a"], [(2,), (4,), (5,)], False),
+    ("SELECT capitalize(v) AS cv FROM u",
+     ["cv"], [("P",), ("Q",), ("R",), ("S",)], False),
+    ("SELECT a, b FROM t ORDER BY a LIMIT 2 OFFSET 1",
+     ["a", "b"], [(2, None), (4, 2.5)], True),
+    ("SELECT count(*) AS n FROM t WHERE a IS NULL", ["n"], [(1,)], False),
+]
+
+
+@pytest.mark.parametrize("route", ["fused", "staged"])
+@pytest.mark.parametrize("sql,names,rows,ordered", SEMANTICS_CORNERS,
+                         ids=[c[0] for c in SEMANTICS_CORNERS])
+def test_semantics_corners(corners, monkeypatch, route, sql, names, rows,
+                           ordered):
+    """SQL's corners on both single-chip device routes: the whole-plan
+    fused program and the staged executor."""
+    from igloo_tpu.exec.executor import Executor
+    if route == "staged":
+        monkeypatch.setattr(Executor, "_FUSE", False)
+    res = corners.query(sql)
+    assert res.stats.tier == "device"
+    assert_rows(res.table, names, rows, ordered, label=sql)
+
+
+def test_small_parquet_runs_on_the_device(tmp_path):
+    """A 100-row Parquet file — the smallest source there is — runs on the
+    device tier."""
+    p = tmp_path / "small.parquet"
+    pq.write_table(pa.table({"x": list(range(100))}), p)
+    eng = QueryEngine()
+    eng.register_table("small", ParquetTable(str(p)))
+    res = eng.query("SELECT sum(x) AS s FROM small WHERE x > 10")
+    assert res.table.column("s").to_pylist() == [sum(range(11, 100))]
+    assert res.stats.tier == "device"

@@ -414,7 +414,6 @@ def test_program_keys_of_the_bypass_queries_did_not_move(small_adaptive, q):
     from igloo_tpu.bench.tpch import QUERIES, gen_tables, register_all
     e = QueryEngine()
     register_all(e, gen_tables(sf=0.01))
-    e.host_route_bytes = 0
     for _ in range(3):                       # cold, hinted, steady
         _t, c = _run_counting(e, QUERIES[q])
     assert c.get("jit.hit") == 1 and not c.get("jit.miss")

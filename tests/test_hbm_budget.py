@@ -66,7 +66,6 @@ def test_the_cpu_backend_keeps_the_constants():
     assert cache.hbm_budgets() == (1 << 30, 2 << 30)
     eng = QueryEngine()
     assert eng.batch_cache.budget_bytes == 1 << 30
-    assert eng.host_cache.budget_bytes == 1 << 30
     assert eng.chunk_budget_bytes == 2 << 30
 
 
@@ -75,10 +74,8 @@ def test_engine_takes_the_shares_and_arguments_override(monkeypatch):
     eng = QueryEngine()
     assert eng.batch_cache.budget_bytes == 8 * GB
     assert eng.chunk_budget_bytes == 2 * GB
-    assert eng.host_cache.budget_bytes == 1 << 30       # host RAM, not HBM
     eng = QueryEngine(cache_budget_bytes=5 << 20, chunk_budget_bytes=7 << 20)
     assert eng.batch_cache.budget_bytes == 5 << 20
-    assert eng.host_cache.budget_bytes == 1 << 30       # the HBM budget only
     assert eng._chunk_budget() == 7 << 20
     eng = QueryEngine(chunk_budget_bytes=1 << 20)        # one override alone
     assert eng.batch_cache.budget_bytes == 8 * GB

@@ -1,10 +1,10 @@
-"""One LIKE matcher serves both tiers (`exec/expr_compile.py`): `like_match`
-over an array of strings (the host tier's plain lanes, as numpy unicode)
-and `like_lut`, a dictionary's table of verdicts, an entry each, matched by
-Arrow's RE2 over the whole dictionary and memoized on the DictInfo (the
-device tier's gather and the host tier's). It gives what SQL's LIKE gives —
-held here against a plain Python matcher, character by character — for
-wildcards, regex metacharacters, newlines, Unicode and ILIKE."""
+"""The LIKE matcher (`exec/expr_compile.py`): `like_match` over an array of
+strings (as numpy unicode) and `like_lut`, a dictionary's table of
+verdicts, an entry each, matched by Arrow's RE2 over the whole dictionary
+and memoized on the DictInfo (what the device tier gathers from). It gives
+what SQL's LIKE gives — held here against a plain Python matcher, character
+by character — for wildcards, regex metacharacters, newlines, Unicode and
+ILIKE."""
 import numpy as np
 import pytest
 

@@ -132,7 +132,6 @@ def _table_entries(cache) -> int:
 @pytest.fixture(scope="module")
 def engine(staged):
     e = QueryEngine()
-    e.host_route_bytes = 0
     for name in TABLES:
         e.register_table(name, ParquetTable(
             os.path.join(staged[0], f"{name}.parquet")))
@@ -178,7 +177,6 @@ def test_embedded_parameter_sets(engine, staged, oracle, q, sets):
 
 def test_q1_and_q6_hold_each_shared_column_once(staged):
     e = QueryEngine()
-    e.host_route_bytes = 0
     e.register_table("lineitem", ParquetTable(
         os.path.join(staged[0], "lineitem.parquet")))
     _, c6 = _run(e, _sql("q6", Q6_SETS[0]))
@@ -204,7 +202,6 @@ def test_a_result_is_never_shared_between_two_literal_sets(engine, staged,
 @pytest.fixture
 def facts():
     e = QueryEngine()
-    e.host_route_bytes = 0
     rng = np.random.default_rng(34)
     n = 3000
     df = pd.DataFrame({
@@ -296,7 +293,6 @@ def test_row_groups_that_prune_differently_are_two_entries(tmp_path):
                              "v": pa.array(np.arange(n) * 0.5)}),
                    path, row_group_size=1000)
     e = QueryEngine()
-    e.host_route_bytes = 0
     e.register_table("t", ParquetTable(path))
     sql = "SELECT sum(v) AS s, count(*) AS n FROM t WHERE d < {0}"
     got = {}
@@ -323,7 +319,6 @@ def test_a_hint_learnt_under_one_date_is_repaired_under_the_next(
     monkeypatch.setattr(F, "ADAPTIVE_CAPACITY", 1 << 10)
     monkeypatch.setattr(Executor, "_SPECULATIVE_JOIN_BUDGET", 1 << 10)
     e = QueryEngine()
-    e.host_route_bytes = 0
     for name in TABLES:
         e.register_table(name, ParquetTable(
             os.path.join(staged[0], f"{name}.parquet")))

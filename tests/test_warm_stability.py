@@ -23,9 +23,6 @@ def tpch_engine():
     from igloo_tpu.bench.tpch import gen_tables, register_all
     eng = QueryEngine()
     register_all(eng, gen_tables(sf=0.01))
-    # keep every query on the device tiers (the host tier has no jit cache
-    # and would make the counters vacuous)
-    eng.host_route_bytes = 0
     return eng
 
 
