@@ -40,6 +40,9 @@ from igloo_tpu import types as T
 from igloo_tpu.cluster import serde
 from igloo_tpu.plan import expr as E
 from igloo_tpu.plan import logical as L
+from igloo_tpu.plan.aggsplit import (
+    DECOMPOSABLE, decompose_aggregate, final_merge_plan, partial_aggregate_node,
+)
 from igloo_tpu.sql.ast import JoinType
 from igloo_tpu.utils import tracing
 
@@ -140,12 +143,6 @@ def _rewrap(nodes: list, inner: L.LogicalPlan) -> L.LogicalPlan:
     return inner
 
 
-def _col(i: int, dtype: T.DataType, name: str = "") -> E.Expr:
-    c = E.Column(name=name or f"c{i}", index=i)
-    c.dtype = dtype
-    return c
-
-
 def _is_local(p: L.LogicalPlan) -> bool:
     """True if the subtree is scan/filter/project/values only — safe to ship
     whole to a worker and, for scans, to stride by partition."""
@@ -179,10 +176,6 @@ def _with_partition(p: L.LogicalPlan, part: tuple[int, ...]) -> L.LogicalPlan:
         except Exception:
             sc.partition_token = None
     return n
-
-
-_DECOMPOSABLE = {E.AggFunc.SUM, E.AggFunc.MIN, E.AggFunc.MAX, E.AggFunc.COUNT,
-                 E.AggFunc.COUNT_STAR, E.AggFunc.AVG}
 
 
 class DistributedPlanner:
@@ -318,8 +311,7 @@ class DistributedPlanner:
         """Post-order: replace distributable subtrees with fragment scans;
         return the plan the root fragment executes."""
         if isinstance(p, L.Aggregate) and _is_local(p.input) and \
-                not any(a.distinct for a in p.aggs) and \
-                all(a.func in _DECOMPOSABLE for a in p.aggs):
+                all(a.func in DECOMPOSABLE for a in p.aggs):
             return self._split_aggregate(p, frags)
         # recurse into children; large local subtrees under joins become
         # their own (partitioned) fragments
@@ -783,132 +775,6 @@ class DistributedPlanner:
             merged = L.Union(inputs=children)
             merged.schema = partial_schema
         return final_merge_plan(agg, merged, final_plan)
-
-
-def decompose_aggregate(agg: L.Aggregate):
-    """Decompose a DECOMPOSABLE aggregate into per-chunk partials: returns
-    (partial_schema, partial_aggs, partial_names, final_plan) where
-    final_plan records how final_merge_plan recombines partial columns.
-    Shared by the distributed planner, the chunked executor, and the
-    out-of-core grace join (exec/grace.py)."""
-    k = len(agg.group_exprs)
-    partial_aggs: list[E.Aggregate] = []
-    partial_names: list[str] = []
-    final_plan: list[tuple] = []  # (kind, partial col index, orig agg)
-    pi = k
-    for a in agg.aggs:
-        if a.func in (E.AggFunc.COUNT, E.AggFunc.COUNT_STAR):
-            partial_aggs.append(a)
-            partial_names.append(f"p{pi}")
-            final_plan.append(("sum0", pi, a))
-            pi += 1
-        elif a.func is E.AggFunc.AVG:
-            s = E.Aggregate(func=E.AggFunc.SUM, arg=a.arg)
-            s.dtype = T.FLOAT64
-            c = E.Aggregate(func=E.AggFunc.COUNT, arg=a.arg)
-            c.dtype = T.INT64
-            partial_aggs.extend([s, c])
-            partial_names.extend([f"p{pi}", f"p{pi + 1}"])
-            final_plan.append(("avg", pi, a))
-            pi += 2
-        else:  # SUM / MIN / MAX: associative
-            partial_aggs.append(a)
-            partial_names.append(f"p{pi}")
-            final_plan.append(("assoc", pi, a))
-            pi += 1
-
-    partial_fields = [T.Field(n, g.dtype, True)
-                      for n, g in zip(agg.group_names, agg.group_exprs)]
-    partial_fields += [T.Field(n, a.dtype, True)
-                       for n, a in zip(partial_names, partial_aggs)]
-    return T.Schema(partial_fields), partial_aggs, partial_names, final_plan
-
-
-def partial_aggregate_node(agg: L.Aggregate, inp: L.LogicalPlan,
-                           partial_schema, partial_aggs,
-                           partial_names) -> L.Aggregate:
-    node = L.Aggregate(input=inp,
-                       group_exprs=[g for g in agg.group_exprs],
-                       group_names=list(agg.group_names),
-                       aggs=list(partial_aggs),
-                       agg_names=list(partial_names))
-    node.schema = partial_schema
-    return node
-
-
-def final_merge_plan(agg: L.Aggregate, merged: L.LogicalPlan,
-                     final_plan: list[tuple]) -> L.LogicalPlan:
-    """Final re-aggregation of partial rows + projection back to the
-    aggregate's declared output schema."""
-    k = len(agg.group_exprs)
-    # final merge: re-aggregate partials by the group columns
-    final_groups = [_col(i, g.dtype, agg.group_names[i])
-                    for i, g in enumerate(agg.group_exprs)]
-    final_aggs: list[E.Aggregate] = []
-    final_names: list[str] = []
-    for kind, pi_, a in final_plan:
-        if kind == "avg":
-            for j, dt in ((pi_, T.FLOAT64), (pi_ + 1, T.INT64)):
-                fa = E.Aggregate(func=E.AggFunc.SUM, arg=_col(j, dt))
-                fa.dtype = dt
-                final_aggs.append(fa)
-                final_names.append(f"f{j}")
-        else:
-            fn = E.AggFunc.SUM if kind == "sum0" else a.func
-            fa = E.Aggregate(func=fn, arg=_col(pi_, a.dtype))
-            fa.dtype = a.dtype
-            final_aggs.append(fa)
-            final_names.append(f"f{pi_}")
-    merge = L.Aggregate(input=merged, group_exprs=final_groups,
-                        group_names=list(agg.group_names),
-                        aggs=final_aggs, agg_names=final_names)
-    merge.schema = T.Schema(
-        [T.Field(n, g.dtype, True)
-         for n, g in zip(agg.group_names, final_groups)] +
-        [T.Field(n, a.dtype, True)
-         for n, a in zip(final_names, final_aggs)])
-
-    # project back to the aggregate's declared output (AVG division,
-    # COUNT null->0 on empty-side sums)
-    out_exprs: list[E.Expr] = [
-        _col(i, g.dtype, agg.group_names[i])
-        for i, g in enumerate(agg.group_exprs)]
-    fi = k
-    for kind, _pi, a in final_plan:
-        if kind == "avg":
-            s = _col(fi, T.FLOAT64)
-            c = _col(fi + 1, T.INT64)
-            zero = E.Literal(value=0)
-            zero.dtype = T.INT64
-            cast = E.Cast(operand=c, to=T.FLOAT64)
-            cast.dtype = T.FLOAT64
-            div = E.Binary(op=E.BinOp.DIV, left=s, right=cast)
-            div.dtype = T.FLOAT64
-            isz = E.Binary(op=E.BinOp.EQ, left=c, right=zero)
-            isz.dtype = T.BOOL
-            nul = E.Literal(value=None, literal_type=T.FLOAT64)
-            nul.dtype = T.FLOAT64
-            case = E.Case(whens=[(isz, nul)], else_=div)
-            case.dtype = T.FLOAT64
-            out_exprs.append(case)
-            fi += 2
-        elif kind == "sum0":
-            s = _col(fi, T.INT64)
-            zero = E.Literal(value=0)
-            zero.dtype = T.INT64
-            isn = E.IsNull(operand=s)
-            isn.dtype = T.BOOL
-            case = E.Case(whens=[(isn, zero)], else_=s)
-            case.dtype = T.INT64
-            out_exprs.append(case)
-            fi += 1
-        else:
-            out_exprs.append(_col(fi, a.dtype))
-            fi += 1
-    proj = L.Project(input=merge, exprs=out_exprs,
-                     names=list(agg.schema.names))
-    proj.schema = agg.schema
-    return proj
 
 
 def _frag_refs(plan_json: dict) -> list[dict]:

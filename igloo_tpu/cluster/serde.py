@@ -180,6 +180,8 @@ def plan_to_json(p: L.LogicalPlan) -> dict:
                  groups=[expr_to_json(e) for e in p.group_exprs],
                  group_names=p.group_names,
                  aggs=[expr_to_json(a) for a in p.aggs], agg_names=p.agg_names)
+        if p.distinct_stage:
+            d.update(distinct_stage=p.distinct_stage)
     elif isinstance(p, L.Join):
         d.update(left=plan_to_json(p.left), right=plan_to_json(p.right),
                  join_type=p.join_type.value,
@@ -245,7 +247,8 @@ def plan_from_json(d: dict, catalog) -> L.LogicalPlan:
                         group_exprs=[_rx(e, catalog) for e in d["groups"]],
                         group_names=list(d["group_names"]),
                         aggs=[_rx(a, catalog) for a in d["aggs"]],
-                        agg_names=list(d["agg_names"]))
+                        agg_names=list(d["agg_names"]),
+                        distinct_stage=d.get("distinct_stage", 0))
     elif t == "Join":
         p = L.Join(left=plan_from_json(d["left"], catalog),
                    right=plan_from_json(d["right"], catalog),

@@ -16,6 +16,7 @@ after the sort, so no collision handling is needed.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Optional
 
@@ -485,6 +486,16 @@ def uncompacted_filter(plan: L.Aggregate) -> Optional[L.Filter]:
     while isinstance(node, L.Project):
         node = node.input
     return node if isinstance(node, L.Filter) else None
+
+
+def stage_scope(plan: L.Aggregate):
+    """The named scope of a DISTINCT aggregate's stage (plan/aggsplit.py
+    split_distinct): `distinct_stage1` / `distinct_stage2`, so that a dump
+    of the program names the stage's ops; no scope for any other
+    aggregate."""
+    if plan.distinct_stage:
+        return jax.named_scope(f"distinct_stage{plan.distinct_stage}")
+    return contextlib.nullcontext()
 
 
 def groups_in_place(seg_dims: Optional[tuple]) -> bool:

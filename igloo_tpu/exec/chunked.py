@@ -11,9 +11,11 @@ the base table at a time.
 
 Ceiling (documented per the build plan): only decomposable-aggregate-over-scan
 pipelines (Q1/Q6 shape) actually stream chunk-at-a-time — `chunk_count` routes
-ONLY those here. Plans whose over-budget scan feeds anything else (a bare
-sort/limit, a join side, a DISTINCT aggregate) would union all chunks back into
-one device batch, so they take the normal path unchanged; over-budget JOIN
+ONLY those here (a DISTINCT aggregate among them: the optimizer splits it into
+two grouped aggregates, plan/aggsplit.py, and its first streams). Plans whose
+over-budget scan feeds anything else (a bare sort/limit, a join side) would
+union all chunks back into one device batch, so they take the normal path
+unchanged; over-budget JOIN
 trees route through the partitioned GRACE tier instead (exec/grace.py — see
 docs/out_of_core.md for the full fallback ladder).
 
@@ -138,12 +140,12 @@ def chunk_count(plan: L.LogicalPlan, budget_bytes: int,
     i.e. feeding a DECOMPOSABLE aggregate through scan/filter/project nodes —
     count: chunking anything else just unions the chunks back into one batch
     and pays fragment overhead for no memory bound (see module docstring)."""
-    from igloo_tpu.cluster.fragment import _DECOMPOSABLE, _is_local
+    from igloo_tpu.cluster.fragment import _is_local
+    from igloo_tpu.plan.aggsplit import DECOMPOSABLE
     want = 0
     for node in L.walk_plan(plan):
         if not (isinstance(node, L.Aggregate) and _is_local(node.input) and
-                not any(a.distinct for a in node.aggs) and
-                all(a.func in _DECOMPOSABLE for a in node.aggs)):
+                all(a.func in DECOMPOSABLE for a in node.aggs)):
             continue
         for sc in L.walk_plan(node.input):
             if isinstance(sc, L.Scan) and sc.provider is not None and \

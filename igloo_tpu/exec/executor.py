@@ -31,7 +31,7 @@ from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
     groups_in_place, minmax_order_arg, pair_sums_for, seg_dims_for,
-    uncompacted_filter,
+    stage_scope, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     DeviceBatch, DeviceColumn, DictInfo, device_columns, from_arrow,
@@ -827,10 +827,11 @@ class Executor:
         batch = self._exec(plan.input)
         if uncompacted_filter(plan) is None:
             batch = self._adaptive_input(batch, plan.input)
-        distinct_aggs = [a for a in plan.aggs if a.distinct]
-        if distinct_aggs:
-            return self._exec_distinct_aggregate(plan, batch)
-        return self._aggregate(batch, plan.group_exprs, plan.aggs, plan.schema)
+        if plan.distinct_stage == 1:
+            tracing.counter("agg.distinct")
+        with stage_scope(plan):
+            return self._aggregate(batch, plan.group_exprs, plan.aggs,
+                                   plan.schema)
 
     def _aggregate(self, batch, group_exprs, aggs, out_schema) -> DeviceBatch:
         comp = ExprCompiler([c.dictionary for c in batch.columns],
@@ -885,128 +886,6 @@ class Executor:
                            [None] * len(groups) +
                            agg_out_bounds(aggs, batch.capacity))
         return self._maybe_shrink(out)
-
-    def _exec_distinct_aggregate(self, plan: L.Aggregate,
-                                 batch: DeviceBatch) -> DeviceBatch:
-        """agg(DISTINCT x) mixed with arbitrary plain aggregates: stage 1
-        groups by (keys..., x), carrying per-combination PARTIALS of every
-        plain aggregate (COUNT_STAR -> row count, SUM -> partial sum, AVG ->
-        partial sum + count, MIN/MAX pass through); stage 2 re-groups by the
-        keys, applying the distinct aggregates to the deduped x column and
-        merging the plain partials. Only multiple DISTINCT arguments remain
-        unsupported (they would need a null-safe join of per-arg results)."""
-        args = {E.fingerprint(a.arg) for a in plan.aggs if a.distinct}
-        if len(args) > 1:
-            raise NotSupportedError(
-                "multiple distinct aggregate arguments are not supported yet")
-        d_arg = next(a.arg for a in plan.aggs if a.distinct)
-        k = len(plan.group_exprs)
-        # stage 1: group by (keys..., arg); one row per distinct combination
-        stage1_groups = list(plan.group_exprs) + [d_arg]
-        names = [f"g{i}" for i in range(k)] + ["__arg"]
-        s1_fields = [T.Field(n, g.dtype, True)
-                     for n, g in zip(names, stage1_groups)]
-        s1_aggs: list[E.Aggregate] = []
-        # per original plain agg: list of stage-1 column indices it reads
-        plain_slots: dict[int, tuple] = {}
-        si = k + 1  # stage-1 output: keys..., __arg, partial cols...
-
-        def s1_agg(func, arg, dtype):
-            nonlocal si
-            a2 = E.Aggregate(func=func, arg=arg, distinct=False)
-            a2.dtype = dtype
-            s1_aggs.append(a2)
-            s1_fields.append(T.Field(f"p{si}", dtype, True))
-            si += 1
-            return si - 1
-
-        for j, a in enumerate(plan.aggs):
-            if a.distinct:
-                continue
-            if a.func is E.AggFunc.COUNT_STAR:
-                plain_slots[j] = ("sum", s1_agg(E.AggFunc.COUNT_STAR, None,
-                                                T.INT64))
-            elif a.func is E.AggFunc.COUNT:
-                plain_slots[j] = ("sum", s1_agg(E.AggFunc.COUNT, a.arg,
-                                                T.INT64))
-            elif a.func is E.AggFunc.SUM:
-                plain_slots[j] = ("sum", s1_agg(E.AggFunc.SUM, a.arg, a.dtype))
-            elif a.func in (E.AggFunc.MIN, E.AggFunc.MAX):
-                plain_slots[j] = ("assoc", s1_agg(a.func, a.arg, a.dtype))
-            elif a.func is E.AggFunc.AVG:
-                plain_slots[j] = ("avg",
-                                  s1_agg(E.AggFunc.SUM, a.arg, T.FLOAT64),
-                                  s1_agg(E.AggFunc.COUNT, a.arg, T.INT64))
-            else:  # pragma: no cover - AggFunc is closed
-                raise NotSupportedError(f"distinct mix with {a.func}")
-        s1_schema = T.Schema(s1_fields)
-        deduped = self._aggregate(batch, stage1_groups, s1_aggs, s1_schema)
-
-        # stage 2: group by keys over the deduped rows
-        def rebased_col(i, dtype, name=None):
-            c = E.Column(name or f"c{i}", index=i)
-            c.dtype = dtype
-            return c
-        g2 = [rebased_col(i, g.dtype, names[i])
-              for i, g in enumerate(plan.group_exprs)]
-        arg2 = rebased_col(k, d_arg.dtype, "__arg")
-        aggs2: list[E.Aggregate] = []
-        s2_fields = [T.Field(names[i], g.dtype, True)
-                     for i, g in enumerate(plan.group_exprs)]
-        # per original agg: stage-2 output column index (or (sum, cnt) pair)
-        out_slots: list = []
-        oi = k
-
-        def s2_agg(func, arg, dtype):
-            nonlocal oi
-            a2 = E.Aggregate(func=func, arg=arg, distinct=False)
-            a2.dtype = dtype
-            aggs2.append(a2)
-            s2_fields.append(T.Field(f"o{oi}", dtype, True))
-            oi += 1
-            return oi - 1
-
-        for j, a in enumerate(plan.aggs):
-            if a.distinct:
-                out_slots.append(("direct", s2_agg(a.func, arg2, a.dtype)))
-                continue
-            kind = plain_slots[j][0]
-            if kind == "sum":
-                col = rebased_col(plain_slots[j][1],
-                                  s1_schema.fields[plain_slots[j][1]].dtype)
-                out_slots.append(("zero_null" if a.func in (
-                    E.AggFunc.COUNT, E.AggFunc.COUNT_STAR) else "direct",
-                    s2_agg(E.AggFunc.SUM, col, a.dtype)))
-            elif kind == "assoc":
-                col = rebased_col(plain_slots[j][1], a.dtype)
-                out_slots.append(("direct", s2_agg(a.func, col, a.dtype)))
-            else:  # avg: SUM(partial sums) / SUM(partial counts)
-                scol = rebased_col(plain_slots[j][1], T.FLOAT64)
-                ccol = rebased_col(plain_slots[j][2], T.INT64)
-                out_slots.append(("avg", s2_agg(E.AggFunc.SUM, scol, T.FLOAT64),
-                                  s2_agg(E.AggFunc.SUM, ccol, T.INT64)))
-        s2_schema = T.Schema(s2_fields)
-        merged = self._aggregate(deduped, g2, aggs2, s2_schema)
-
-        # final: pick/compute the plan's declared output columns
-        cols = list(merged.columns[:k])
-        for slot, a in zip(out_slots, plan.aggs):
-            if slot[0] == "avg":
-                s, c = merged.columns[slot[1]], merged.columns[slot[2]]
-                cnt_v = jnp.where(c.nulls, 0, c.values) if c.nulls is not None \
-                    else c.values
-                denom = jnp.where(cnt_v == 0, 1, cnt_v).astype(jnp.float64)
-                cols.append(DeviceColumn(
-                    T.FLOAT64, s.values.astype(jnp.float64) / denom,
-                    cnt_v == 0, None))
-            elif slot[0] == "zero_null":
-                c = merged.columns[slot[1]]
-                vals = jnp.where(c.nulls, 0, c.values) if c.nulls is not None \
-                    else c.values
-                cols.append(DeviceColumn(T.INT64, vals, None, None))
-            else:
-                cols.append(merged.columns[slot[1]])
-        return DeviceBatch(plan.schema, cols, merged.live)
 
     def _exec_distinct(self, plan: L.Distinct) -> DeviceBatch:
         batch = self._adaptive_input(self._exec(plan.input), plan.input)

@@ -47,8 +47,9 @@ speculative join capacity overflow, compaction overflow) come back in the same
 single fetch; only a raised flag or an oversized result costs extra round trips.
 
 Raises FusionUnsupported for shapes that need host decisions (non-speculative
-joins past the capacity budget, distinct aggregates, set ops, unions); the
-caller falls back to the staged executor.
+joins past the capacity budget, set ops, unions); the caller falls back to the
+staged executor. A DISTINCT aggregate arrives split into two grouped
+aggregates (plan/aggsplit.py) and fuses as they do.
 """
 from __future__ import annotations
 
@@ -62,7 +63,7 @@ from igloo_tpu.exec import kernels as K
 from igloo_tpu.exec.aggregate import (
     AggSpec, agg_out_bounds, aggregate_batch, distinct_batch,
     groups_in_place, minmax_order_arg, pair_sums_for, seg_dims_for,
-    uncompacted_filter,
+    stage_scope, uncompacted_filter,
 )
 from igloo_tpu.exec.batch import (
     MIN_CAPACITY, DeviceBatch, DeviceColumn, round_capacity,
@@ -586,8 +587,6 @@ class FusedCompiler:
     # --- aggregates -------------------------------------------------------
 
     def _c_aggregate(self, plan: L.Aggregate):
-        if any(a.distinct for a in plan.aggs):
-            raise FusionUnsupported("distinct aggregate")
         keep = uncompacted_filter(plan)
         if keep is not None:
             self.uncompacted.append(keep)
@@ -622,6 +621,8 @@ class FusedCompiler:
         pair_sums = pair_sums_for(seg_dims, specs)
         if groups_in_place(seg_dims):
             tracing.counter("agg.groups_in_place")
+        if plan.distinct_stage == 1:
+            tracing.counter("agg.distinct")
         fp = ("agg", E.shape(gres + ares),
               tuple((a.func, a.dtype) for a in plan.aggs),
               plan.schema, seg_dims, pack_spec) + \
@@ -630,9 +631,11 @@ class FusedCompiler:
         out_schema = plan.schema
 
         def fn(leaves, consts, ctx):
-            return aggregate_batch(cfn(leaves, consts, ctx), groups, specs,
-                                   out_schema, consts, seg_dims=seg_dims,
-                                   pack_spec=pack_spec, pair_sums=pair_sums)
+            b = cfn(leaves, consts, ctx)
+            with stage_scope(plan):
+                return aggregate_batch(b, groups, specs, out_schema, consts,
+                                       seg_dims=seg_dims, pack_spec=pack_spec,
+                                       pair_sums=pair_sums)
         if not groups:
             cap = MIN_CAPACITY
         elif seg_dims is not None:

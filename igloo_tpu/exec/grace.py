@@ -191,7 +191,7 @@ def _trace_leaf_col(node: L.LogicalPlan, idx: int, leaf_ids: dict):
 def find_grace_join(plan: L.LogicalPlan, budget_bytes: int):
     """Locate a GRACE-v2-eligible over-budget join tree. Returns a GracePlan
     or None when the plan does not qualify (caller takes the normal path)."""
-    from igloo_tpu.cluster.fragment import _DECOMPOSABLE
+    from igloo_tpu.plan.aggsplit import DECOMPOSABLE
     from igloo_tpu.exec.chunked import table_lane_bytes
     path: list[L.LogicalPlan] = []
     node = plan
@@ -201,8 +201,7 @@ def find_grace_join(plan: L.LogicalPlan, budget_bytes: int):
             path.append(node)
             node = node.input
         elif isinstance(node, L.Aggregate) and agg is None and \
-                not any(a.distinct for a in node.aggs) and \
-                all(a.func in _DECOMPOSABLE for a in node.aggs):
+                all(a.func in DECOMPOSABLE for a in node.aggs):
             agg = node
             path.append(node)
             node = node.input
@@ -514,7 +513,7 @@ class GraceJoinExecutor:
     def _execute(self, plan: L.LogicalPlan, found: GracePlan,
                  depth: int, gnode) -> pa.Table:
         from igloo_tpu.catalog import MemTable
-        from igloo_tpu.cluster.fragment import (
+        from igloo_tpu.plan.aggsplit import (
             decompose_aggregate, final_merge_plan, partial_aggregate_node,
         )
         gp = found

@@ -96,12 +96,18 @@ class Aggregate(LogicalPlan):
     group_names: list[str] = field(default_factory=list)
     aggs: list[E.Aggregate] = field(default_factory=list)
     agg_names: list[str] = field(default_factory=list)
+    # 1 or 2: a stage of a DISTINCT aggregate split by plan/aggsplit.py
+    # split_distinct (it names the stage's ops and counts the split); 0 else
+    distinct_stage: int = 0
 
     def children(self):
         return [self.input]
 
     def node_name(self):
-        return f"Aggregate(by=[{', '.join(self.group_names)}], aggs=[{', '.join(self.agg_names)}])"
+        stage = f", distinct stage {self.distinct_stage}" \
+            if self.distinct_stage else ""
+        return (f"Aggregate(by=[{', '.join(self.group_names)}], "
+                f"aggs=[{', '.join(self.agg_names)}]{stage})")
 
 
 @dataclass

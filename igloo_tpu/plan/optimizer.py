@@ -10,6 +10,8 @@ path. We implement the passes that matter for the TPU execution model:
   bytes ever move toward HBM)
 - projection pruning (Scan.projection — decode only needed Parquet columns; on
   device this is the difference between shipping 16 lanes and 4)
+- DISTINCT aggregates split into two grouped aggregates (plan/aggsplit.py), so
+  every tier below runs them as plain ones
 
 All passes preserve bound-ness: Column.index stays consistent with each node's
 input schema (pruning rewrites indices via child mappings).
@@ -22,6 +24,7 @@ from typing import Optional
 from igloo_tpu import types as T
 from igloo_tpu.plan import expr as E
 from igloo_tpu.plan import logical as L
+from igloo_tpu.plan.aggsplit import split_distinct
 from igloo_tpu.plan.binder import (
     _and_all, _extract_equi_key, _split_conjuncts, coerce_key_pair,
 )
@@ -36,6 +39,22 @@ def optimize(plan: L.LogicalPlan) -> L.LogicalPlan:
     plan = semi_join_reduction(plan)
     plan = reorder_adaptive_joins(plan)
     plan = prune_projections(plan)
+    return split_distinct_aggregates(plan)
+
+
+def split_distinct_aggregates(plan: L.LogicalPlan) -> L.LogicalPlan:
+    """Every aggregate with DISTINCT arguments as the two grouped aggregates
+    of plan/aggsplit.py split_distinct, so that no tier below the optimizer
+    meets a DISTINCT aggregate. Last: the split keeps the aggregate's output
+    schema, and the passes above have pruned and pushed into its input."""
+    for name in ("input", "left", "right"):
+        child = getattr(plan, name, None)
+        if isinstance(child, L.LogicalPlan):
+            setattr(plan, name, split_distinct_aggregates(child))
+    if isinstance(plan, L.Union):
+        plan.inputs = [split_distinct_aggregates(c) for c in plan.inputs]
+    if isinstance(plan, L.Aggregate) and any(a.distinct for a in plan.aggs):
+        return split_distinct(plan)
     return plan
 
 

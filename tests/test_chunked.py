@@ -54,9 +54,11 @@ def test_chunking_triggers(big):
     # a non-streamable plan (bare sort) must NOT route to the chunked path
     plan2 = eng.plan("SELECT k, v FROM t ORDER BY v LIMIT 5")
     assert chunk_count(plan2, eng.chunk_budget_bytes) == 0
-    # nor a distinct aggregate (union-back would unbound memory anyway)
-    plan3 = eng.plan("SELECT COUNT(DISTINCT k) AS d FROM t")
-    assert chunk_count(plan3, eng.chunk_budget_bytes) == 0
+    # a distinct aggregate streams: the optimizer splits it, and its first
+    # stage (grouped by k) is a decomposable aggregate over the scan
+    plan3 = eng.plan("SELECT s, COUNT(DISTINCT k) AS d, SUM(v) AS sv "
+                     "FROM t GROUP BY s")
+    assert chunk_count(plan3, eng.chunk_budget_bytes) >= 4
 
 
 @pytest.mark.parametrize("sql", [
@@ -64,6 +66,9 @@ def test_chunking_triggers(big):
     "MAX(q) AS mx FROM t GROUP BY s ORDER BY s",
     "SELECT COUNT(*) AS c, SUM(v * q) AS sv FROM t WHERE v > 0.5",
     "SELECT k, COUNT(*) AS c FROM t WHERE s <> 'cat0' GROUP BY k ORDER BY k",
+    # a DISTINCT aggregate: its split's first stage streams
+    "SELECT s, COUNT(DISTINCT k) AS dk, SUM(v) AS sv FROM t GROUP BY s "
+    "ORDER BY s",
     # bare sort/limit: routing sends this down the NORMAL path (chunking a
     # non-aggregate pipeline would union everything back — module docstring)
     "SELECT k, v FROM t ORDER BY v DESC LIMIT 9",

@@ -163,7 +163,7 @@ CONSUMERS = {
                  lambda p: p[["n"]].drop_duplicates(), 1, True),
     "count_distinct": (f"SELECT COUNT(DISTINCT n) AS c FROM {G}",
                        lambda p: pd.DataFrame({"c": [p.n.nunique()]}), 2,
-                       False),
+                       True),
     "union_all": (f"SELECT n FROM {G} UNION ALL SELECT x AS n FROM u",
                   lambda p: pd.concat([p[["n"]], _u()[["x"]].rename(
                       columns={"x": "n"})]), 1, False),
